@@ -1,8 +1,9 @@
 """Distributed write-path benchmark: routed vs host-loop sharded writes.
 
-Standalone on purpose: the forced host device count must be exported before
-jax initializes, so ``filter_bench.distributed_rows`` runs this file in a
-subprocess and merges the JSON printed on the last stdout line.
+Needs four devices.  On CPU the forced host device count must be exported
+before jax initializes, so ``filter_bench.distributed_rows`` runs this file
+in a subprocess and merges the JSON printed on the last stdout line; on an
+accelerator it calls ``run()`` in its own process.
 
 Two arms per op, timed interleaved from the same preloaded ~0.8-load base
 state (both run the identical per-shard kernel, so the delta is pure
@@ -42,6 +43,7 @@ from filter_bench import _interleaved_times  # noqa: E402
 from repro.core import distributed as dist  # noqa: E402
 from repro.core import hashing  # noqa: E402
 from repro.core.filter_ops import FilterOps  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 
 N_SHARDS = 4
 N_BUCKETS = 1024                     # per shard -> 16384 slots total
@@ -58,8 +60,8 @@ def _pair(rng, n):
     return hi, lo
 
 
-def main():
-    mesh = jax.make_mesh((N_SHARDS,), ("data",))
+def run() -> dict:
+    mesh = make_mesh((N_SHARDS,), ("data",))
     rng = np.random.RandomState(42)
     phi, plo = _pair(rng, PRELOAD)
     bhi, blo = _pair(rng, BATCH)
@@ -134,8 +136,8 @@ def main():
     for name, t in best.items():
         results[f"distributed_{name}_keys_per_s"] = int(BATCH / t)
         results[f"distributed_{name}_us_per_key"] = round(t / BATCH * 1e6, 3)
-    print(json.dumps(results))
+    return results
 
 
 if __name__ == "__main__":
-    main()
+    print(json.dumps(run()))

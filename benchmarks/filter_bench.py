@@ -17,6 +17,7 @@ Run directly (``PYTHONPATH=src python benchmarks/filter_bench.py``) or via
 from __future__ import annotations
 
 import functools
+import importlib
 import json
 import os
 import subprocess
@@ -36,6 +37,7 @@ from repro.core.ocf import OCF, OcfConfig
 from repro.core.scheduling import wave_count
 from repro.kernels import ops as kops
 from repro.kernels.stash import make_stash, stash_occupancy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.streaming import GenerationConfig, GenerationalFilter
 
 # Anchored to the repo root so run.py writes the same trajectory file no
@@ -463,53 +465,64 @@ def ocf_insert_rows(rng, *, n=KEYSTORE_BATCH):
                   "ocf_insert_burst_keys_per_s": kps}
 
 
-def distributed_rows():
-    """Routed vs host-loop sharded writes (PR 6) — run in a subprocess.
+def _four_device_bench(name: str):
+    """Results of ``benchmarks/<name>.py`` (a 4-device bench) -> dict, or
+    None when it was not run.
 
-    ``distributed_bench.py`` forces a 4-device host platform, which must
-    happen before jax initializes; this process already holds a 1-device
-    jax, so the benchmark runs out-of-process and hands back its JSON
-    (last stdout line).  The routed/hostloop pairing is the PR-6
-    acceptance comparison: same per-shard kernels, different dispatch
-    architecture — ``scripts/bench_gate.py`` enforces routed >= hostloop
-    on the insert row in addition to the usual regression threshold.
+    On CPU it runs in a child process whose host platform is forced to four
+    devices (that flag must precede jax init, and this process already holds
+    a 1-device jax); the child prints its JSON on the last stdout line.  On
+    an accelerator a child cannot get the devices this process holds, so
+    the bench runs here on the devices present, and with fewer than four it
+    is reported as not run.
     """
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "distributed_bench.py")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    out = subprocess.run([sys.executable, script], capture_output=True,
-                         text=True, timeout=1200, env=env)
-    if out.returncode != 0:
-        raise RuntimeError(
-            f"distributed_bench failed:\n{out.stderr[-3000:]}")
-    results = json.loads(out.stdout.strip().splitlines()[-1])
+    here = os.path.dirname(os.path.abspath(__file__))
+    if jax.default_backend() == "cpu":
+        env = dict(os.environ)
+        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        out = subprocess.run([sys.executable, os.path.join(here, f"{name}.py")],
+                             capture_output=True, text=True, timeout=1200,
+                             env=env)
+        if out.returncode != 0:
+            raise RuntimeError(f"{name} failed:\n{out.stderr[-3000:]}")
+        return json.loads(out.stdout.strip().splitlines()[-1])
+    if len(jax.devices()) < 4:
+        print(f"{name}: not run — needs 4 devices, "
+              f"{len(jax.devices())} present", file=sys.stderr)
+        return None
+    sys.path.insert(0, here)
+    return importlib.import_module(name).run()
+
+
+def distributed_rows():
+    """Routed vs host-loop sharded writes on four devices.
+
+    The routed/hostloop pairing compares the same per-shard kernels under
+    two dispatch architectures —
+    ``scripts/bench_gate.py`` enforces routed >= hostloop on the insert row
+    in addition to the usual regression threshold.
+    """
+    results = _four_device_bench("distributed_bench")
+    if results is None:
+        return [], {}
     rows = [(k, results.get(k.replace("_keys_per_s", "_us_per_key"), 0.0), v)
             for k, v in results.items() if k.endswith("_keys_per_s")]
     return rows, results
 
 
 def elastic_rows():
-    """Elastic resharding + recovery rows (ISSUE 10) — subprocess.
+    """Elastic resharding + recovery rows on four devices.
 
-    ``elastic_bench.py`` forces a 4-device host platform (same constraint
-    as ``distributed_bench.py``: must precede jax init) and measures the
-    full cutover protocol — live 2->4 split with a parked concurrent
-    stream, 4->2 merge, shard-loss recovery from a durable snapshot.
-    ``scripts/bench_gate.py`` enforces the recovery rows structurally:
-    zero false negatives in every phase, migration failures == 0, the
-    deferred backlog drained to exactly 0, and time-to-recover present
-    and positive.
+    ``elastic_bench.py`` measures the full cutover protocol — live 2->4
+    split with a parked concurrent stream, 4->2 merge, shard-loss recovery
+    from a durable snapshot.  ``scripts/bench_gate.py`` enforces the
+    recovery rows structurally: zero false negatives in every phase,
+    migration failures == 0, the deferred backlog drained to exactly 0, and
+    time-to-recover present and positive.
     """
-    script = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                          "elastic_bench.py")
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    out = subprocess.run([sys.executable, script], capture_output=True,
-                         text=True, timeout=1200, env=env)
-    if out.returncode != 0:
-        raise RuntimeError(f"elastic_bench failed:\n{out.stderr[-3000:]}")
-    results = json.loads(out.stdout.strip().splitlines()[-1])
+    results = _four_device_bench("elastic_bench")
+    if results is None:
+        return [], {}
     rows = [(k, 0.0, v) for k, v in sorted(results.items())
             if k.endswith("_keys_per_s") or k.endswith("_s")]
     return rows, results
@@ -535,6 +548,10 @@ def slo_rows(*, seed=0):
 
 def run(json_path: str | None = JSON_PATH):
     rng = np.random.RandomState(0)
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}",
+          file=sys.stderr)
     rows, results = [], {"backend_default": jax.default_backend()}
     for fn in (backend_rows, residue_rows, stash_rows, generational_rows,
                adaptive_rows, telemetry_rows, keystore_rows, ocf_insert_rows):

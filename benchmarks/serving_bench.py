@@ -30,6 +30,7 @@ import time
 import numpy as np
 
 from repro.core import OCF, OcfConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.kvcache import PrefixCacheIndex
 from repro.serving.slo import (BENCH_SCENARIOS, bench_scenarios,
                                run_scenario, run_scenario_telemetry)
@@ -120,6 +121,7 @@ def main() -> None:
     ap.add_argument("--telemetry-dir", default=".",
                     help="directory for --telemetry artifacts")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.scenario == "all":
         if args.telemetry:

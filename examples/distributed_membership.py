@@ -18,14 +18,11 @@ import numpy as np
 
 from repro.core import distributed as dist
 from repro.core import hashing
+from repro.launch.mesh import make_mesh
 
 N_SHARDS, N_BUCKETS = 8, 4096
 
-try:
-    mesh = jax.make_mesh((N_SHARDS,), ("data",),
-                         axis_types=(jax.sharding.AxisType.Auto,))
-except AttributeError:  # jax 0.4.x: no AxisType; Auto is the default
-    mesh = jax.make_mesh((N_SHARDS,), ("data",))
+mesh = make_mesh((N_SHARDS,), ("data",))
 rng = np.random.RandomState(0)
 keys = rng.randint(0, 2 ** 63, size=32768, dtype=np.int64).astype(np.uint64)
 hi, lo = hashing.key_to_u32_pair_np(keys)

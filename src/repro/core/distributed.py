@@ -47,24 +47,17 @@ from repro.core.filter_ops import FilterOps
 from repro.core.scheduling import conflict_waves
 from repro.kernels.stash import DEFAULT_STASH_SLOTS
 
-try:                                  # jax >= 0.6 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:                # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
 def _shard_map_for(backend: str, fn, *, mesh, in_specs, out_specs):
     """shard_map wrapper that disables the replication check for kernels.
 
     shard_map's replication checker has no rule for ``pallas_call`` (the
     reason the Pallas shard probe used to be impossible — ROADMAP item);
     with fully explicit out_specs the check is advisory here, so it is
-    dropped exactly when the FilterOps dispatch may lower a kernel.  The
-    kwarg was renamed ``check_rep`` -> ``check_vma`` across jax versions.
+    dropped exactly when the FilterOps dispatch may lower a kernel.
     """
     if backend == "jnp":
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs)
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs)
     return _shard_map_unchecked(fn, mesh=mesh, in_specs=in_specs,
                                 out_specs=out_specs)
 
@@ -76,12 +69,8 @@ def _shard_map_unchecked(fn, *, mesh, in_specs, out_specs):
     lowers to ``lax.while``, which the checker has no rule for either.
     Out_specs are fully explicit, so the check is advisory here too.
     """
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:                 # newer jax: check_vma
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 class ShardedFilterState(NamedTuple):
@@ -185,36 +174,14 @@ def _local_probe(table, hi, lo, fp_bits: int, backend: str = "auto"):
         table, hi, lo)
 
 
-def distributed_lookup(mesh: Mesh, axis: str, state: ShardedFilterState,
-                       hi: jax.Array, lo: jax.Array, *, fp_bits: int,
-                       capacity_factor: float = 2.0, backend: str = "auto",
-                       route: str = "key"):
-    """Batched membership across filter shards.
-
-    ``hi``/``lo``: uint32[n_shards * per_shard] keys, sharded over ``axis``.
-    Returns (hits bool[N], overflow int32[n_shards] per-shard overflow
-    count).  Overflowed keys answer True ("maybe") — conservative for
-    dedup/caching, and the overflow count is the congestion signal for the
-    EOF policy.  States carrying per-shard stashes answer spilled keys in
-    the same fused probe pass.
-
-    ``backend`` selects the local-probe data plane ("jnp" | "pallas" |
-    "auto") inside ``shard_map`` — the same FilterOps dispatch as the
-    single-node hot path.  "auto" resolves per-host: the fused probe kernel
-    on TPU meshes whose shard tables fit the VMEM budget, jnp elsewhere
-    (CPU hosts trace the jnp path unless "pallas" is forced, which runs the
-    kernel in interpret mode — how the parity tests pin it).
-
-    ``route`` must match the routing the state was written with ("key" |
-    "pair" — see ``_route``); probing a pair-routed elastic state with key
-    routing sends keys to the wrong shard and silently false-negatives.
-    """
-    n_shards = mesh.shape[axis]
-    per_shard = hi.shape[0] // n_shards
-    cap = int(per_shard * capacity_factor / n_shards + 1)  # slots per (src,dst)
-    has_stash = state.stashes is not None
-    nb = state.n_buckets
-    route_nb = nb if nb is not None else state.tables.shape[1]
+# Bounded: elastic split/merge and recovery build new meshes, and a cache
+# keyed on the Mesh keeps every retired geometry's executable alive.
+@functools.lru_cache(maxsize=16)
+def _lookup_fn(mesh: Mesh, axis: str, n_shards: int, cap: int, fp_bits: int,
+               backend: str, n_buckets: Optional[int], has_stash: bool,
+               route: str, route_nb: int):
+    """Build (and cache) the jitted routed-lookup executable — one compile
+    per static configuration, not one per call."""
     fops = FilterOps(fp_bits=fp_bits, backend=backend)
 
     def shard_fn(tables, stashes, hi, lo):
@@ -231,7 +198,7 @@ def distributed_lookup(mesh: Mesh, axis: str, state: ShardedFilterState,
         r_lo = jax.lax.all_to_all(buf_lo, axis, 0, 0, tiled=False)
         r_valid = jax.lax.all_to_all(valid, axis, 0, 0, tiled=False)
         hit = fops.probe_table(table, r_hi.reshape(-1), r_lo.reshape(-1),
-                               n_buckets=nb, stash=stash
+                               n_buckets=n_buckets, stash=stash
                                ).reshape(n_shards, cap)
         hit = jnp.where(r_valid, hit, False)
         # Route answers back; overflowed lanes answer "maybe present".
@@ -240,15 +207,49 @@ def distributed_lookup(mesh: Mesh, axis: str, state: ShardedFilterState,
         return ans, overflow[None]
 
     if has_stash:
-        fn = _shard_map_for(
+        return jax.jit(_shard_map_for(
             backend, shard_fn, mesh=mesh,
             in_specs=(P(axis), P(axis), P(axis), P(axis)),
-            out_specs=(P(axis), P(axis)))
-        return fn(state.tables, state.stashes, hi, lo)
-    fn = _shard_map_for(
+            out_specs=(P(axis), P(axis))))
+    return jax.jit(_shard_map_for(
         backend, lambda t, h, l: shard_fn(t, None, h, l), mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=(P(axis), P(axis)))
+        out_specs=(P(axis), P(axis))))
+
+
+def distributed_lookup(mesh: Mesh, axis: str, state: ShardedFilterState,
+                       hi: jax.Array, lo: jax.Array, *, fp_bits: int,
+                       capacity_factor: float = 2.0, backend: str = "auto",
+                       route: str = "key"):
+    """Batched membership across filter shards.
+
+    ``hi``/``lo``: uint32[n_shards * per_shard] keys, sharded over ``axis``.
+    Returns (hits bool[N], overflow int32[n_shards] per-shard overflow
+    count).  Overflowed keys answer True ("maybe") — conservative for
+    dedup/caching, and the overflow count is the congestion signal for the
+    EOF policy.  States carrying per-shard stashes answer spilled keys in
+    the same fused probe pass.
+
+    ``backend`` selects the local-probe data plane ("jnp" | "pallas" |
+    "auto") inside ``shard_map`` — the same FilterOps dispatch as the
+    single-node hot path: "auto" is the kernel arm on TPU and jnp
+    elsewhere ("pallas" forces the kernel arm, its XLA emulation off TPU —
+    how the parity tests pin it).
+
+    ``route`` must match the routing the state was written with ("key" |
+    "pair" — see ``_route``); probing a pair-routed elastic state with key
+    routing sends keys to the wrong shard and silently false-negatives.
+    """
+    n_shards = mesh.shape[axis]
+    per_shard = hi.shape[0] // n_shards
+    cap = int(per_shard * capacity_factor / n_shards + 1)  # slots per (src,dst)
+    has_stash = state.stashes is not None
+    nb = state.n_buckets
+    route_nb = nb if nb is not None else state.tables.shape[1]
+    fn = _lookup_fn(mesh, axis, n_shards, cap, fp_bits, backend, nb,
+                    has_stash, route, route_nb)
+    if has_stash:
+        return fn(state.tables, state.stashes, hi, lo)
     return fn(state.tables, hi, lo)
 
 

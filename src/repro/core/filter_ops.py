@@ -8,18 +8,19 @@ One ``backend`` flag flips the whole stack:
   * ``"jnp"``    — the pure-jnp jitted bulk ops (``core.filter``): XLA
                    gather/scatter lookups, optimistic parallel insert round
                    with a mask-driven lax.scan eviction fallback.
-  * ``"pallas"`` — the fused TPU kernels (``kernels.probe`` for lookups,
-                   ``kernels.insert`` for inserts, ``kernels.delete`` for
-                   deletes): hash and probe fused so each key is read from
-                   HBM once, table VMEM-resident, active capacity as an SMEM
-                   scalar.  Since PR 3 the WHOLE insert stays on-device —
+  * ``"pallas"`` — the fused kernel data plane (``kernels.probe`` for
+                   lookups, ``kernels.insert`` for inserts,
+                   ``kernels.delete`` for deletes), each op in the form
+                   ``kernels.ops.LOWERING`` gives it on a TPU (Mosaic, or
+                   the kernel body compiled by XLA), with hash and probe
+                   fused so each key is read once and the active capacity
+                   a scalar operand.  Since PR 3 the WHOLE insert stays
+                   on-device —
                    the contended residue is resolved by bounded eviction
                    rounds inside the insert kernel (``evict_rounds``), and
                    deletes run through the fused first-match-slot kernel;
                    nothing on this backend touches the lax.scan path.
-  * ``"auto"``   — pallas on TPU when the table fits the kernel VMEM budget,
-                   jnp otherwise (CPU hosts interpret Pallas, which is only
-                   worth it for validation, not throughput).
+  * ``"auto"``   — pallas on TPU for every table size, jnp off TPU.
 
 All ops speak (hi, lo) uint32 key pairs and the dynamic-capacity
 ``FilterState`` (active ``n_buckets`` inside a preallocated pow2 buffer), so
@@ -113,44 +114,29 @@ class FilterOps:
 
     # -------------------------------------------------------- dispatch --
 
-    def resolve(self, table: jax.Array, *, stash_slots: int = 0) -> str:
-        """Concrete backend for this table ('auto' -> hardware decision)."""
-        return self.resolve_bytes(table.size * 4, stash_slots=stash_slots)
+    def resolve(self) -> str:
+        """Concrete backend ('auto' -> hardware decision).
 
-    def resolve_bytes(self, table_bytes: int, *, stash_slots: int = 0) -> str:
-        """Concrete backend for a table of this size ('auto' -> hardware
-        decision).
-
-        Budgets against the insert kernel's footprint — the most demanding
-        of the three (aliased table + dirty bitmap + eviction history, plus
-        the stash match/spill working set when the caller attaches one) —
-        so one FilterOps never splits a workload across backends
-        mid-stream.  The stash-aware entry points pass ``stash_slots``;
-        an explicit 'pallas'/'jnp' backend skips the budget (caller's
-        call, same as ``use_pallas='always'``).
+        On TPU 'auto' is the kernel data plane ("pallas") for every table
+        size: each op runs in the form ``kops.LOWERING`` gives it, and the
+        XLA emulation is not bound by VMEM, while the jnp arm's writes do
+        not fit a deployment-sized table in HBM.  Off TPU 'auto' is "jnp".
+        An explicit 'pallas'/'jnp' backend is returned as given.
         """
         if self.backend != "auto":
             return self.backend
-        # Budget with the block the kernel would actually run at (the
-        # autotuner only returns budget-fitting candidates), not a fixed
-        # 1024 — otherwise 'auto' rejects mid-size tables whose [B, B]
-        # rank term the autotuned block was chosen to shrink.
-        block = kops.autotune_block("insert", table_bytes=table_bytes,
-                                    evict_rounds=self.evict_rounds,
-                                    stash_slots=stash_slots)
-        if kops._on_tpu() and kops.kernel_vmem_bytes(
-                "insert", table_bytes=table_bytes, block=block,
-                evict_rounds=self.evict_rounds,
-                stash_slots=stash_slots) <= kops.VMEM_TABLE_BUDGET:
-            return "pallas"
-        return "jnp"
+        return "pallas" if kops._on_tpu() else "jnp"
+
+    def _use_pallas(self) -> str:
+        """The ``kernels.ops`` ``use_pallas`` arm this backend takes."""
+        return "always" if self.resolve() == "pallas" else "never"
 
     # ------------------------------------------------------------- ops --
 
     def lookup(self, state: jfilter.FilterState, hi: jax.Array,
                lo: jax.Array) -> jax.Array:
         """Membership for a batch -> bool[N]."""
-        if self.resolve(state.table) == "pallas":
+        if self.resolve() == "pallas":
             return kops.probe_dispatch(state.table, hi, lo,
                                        fp_bits=self.fp_bits,
                                        n_buckets=state.n_buckets)
@@ -167,7 +153,7 @@ class FilterOps:
         eviction-chain-scan path.  Either way a key that exhausts its
         budget reports False with the table rolled back (never corrupted).
         """
-        if self.resolve(state.table) == "pallas":
+        if self.resolve() == "pallas":
             table, ok = kops.filter_insert(
                 state.table, hi, lo, fp_bits=self.fp_bits,
                 n_buckets=state.n_buckets, valid=valid,
@@ -193,8 +179,7 @@ class FilterOps:
         pallas: the probe kernel checks the stash in the same fused pass.
         jnp: table probe OR'd with the jnp stash match — identical answers.
         """
-        if self.resolve(state.table,
-                        stash_slots=stash.shape[1]) == "pallas":
+        if self.resolve() == "pallas":
             return kops.probe_dispatch(state.table, hi, lo,
                                        fp_bits=self.fp_bits,
                                        n_buckets=state.n_buckets,
@@ -217,14 +202,11 @@ class FilterOps:
         ``kops.stash_occupancy`` so occupancy math stays honest.
         """
         spilled_before = kops.stash_occupancy(stash)
-        up = ("always" if self.resolve(state.table,
-                                       stash_slots=stash.shape[1])
-              == "pallas" else "never")
         table, new_stash, ok = kops.filter_insert(
             state.table, hi, lo, fp_bits=self.fp_bits,
             n_buckets=state.n_buckets, valid=valid,
             evict_rounds=self.evict_rounds, stash=stash,
-            max_disp=self.max_disp, use_pallas=up,
+            max_disp=self.max_disp, use_pallas=self._use_pallas(),
             schedule=self.schedule, donate=self.donate)
         newly_stashed = kops.stash_occupancy(new_stash) - spilled_before
         count = state.count + jnp.sum(ok, dtype=jnp.int32) - newly_stashed
@@ -240,7 +222,7 @@ class FilterOps:
         jnp: the sequential lax.scan path.  Both rank duplicate keys so the
         k-th duplicate clears the k-th resident copy; callers pre-verify
         membership against the keystore (the OCF control plane does)."""
-        if self.resolve(state.table) == "pallas":
+        if self.resolve() == "pallas":
             table, ok = kops.filter_delete(
                 state.table, hi, lo, fp_bits=self.fp_bits,
                 n_buckets=state.n_buckets, valid=valid, use_pallas="always",
@@ -275,12 +257,9 @@ class FilterOps:
         batch's chunks (per-chunk re-derivation costs ~15% of a chunk on
         the serving hot path).
         """
-        per_bytes = (tables.size // tables.shape[0]) * 4
-        up = ("always" if self.resolve_bytes(
-            per_bytes, stash_slots=stashes.shape[2]) == "pallas" else "never")
         return kops.multi_prober(tables, fp_bits=self.fp_bits,
                                  n_buckets=n_buckets, stashes=stashes,
-                                 use_pallas=up)
+                                 use_pallas=self._use_pallas())
 
     # ---------------------------------------------------- adaptive ops --
     #
@@ -292,11 +271,6 @@ class FilterOps:
     # the XLA grid emulation of the same kernel body is the non-pallas arm,
     # so both backends are bit-for-bit by construction.
 
-    def _adaptive_up(self, state, *, stash_slots: int = 0) -> str:
-        bytes_ = 3 * state.table.size * 4 + state.table.shape[0] * 4
-        return ("always" if self.resolve_bytes(
-            bytes_, stash_slots=stash_slots) == "pallas" else "never")
-
     def lookup_adaptive(self, state, hi: jax.Array, lo: jax.Array,
                         stash: Optional[jax.Array] = None) -> jax.Array:
         """Selector-aware membership -> bool[N].
@@ -305,11 +279,10 @@ class FilterOps:
         hits the reported query; stash entries are selector-0 and are
         checked in the same pass when attached.
         """
-        slots = 0 if stash is None else stash.shape[1]
         return kops.adaptive_lookup(
             state.table, state.sels, hi, lo, fp_bits=self.fp_bits,
             n_buckets=state.n_buckets, stash=stash,
-            use_pallas=self._adaptive_up(state, stash_slots=slots))
+            use_pallas=self._use_pallas())
 
     def insert_adaptive(self, state, hi: jax.Array, lo: jax.Array,
                         valid: Optional[jax.Array] = None,
@@ -322,14 +295,13 @@ class FilterOps:
         price of movement — the standard adaptive-cuckoo trade) and
         rollback restores all four planes verbatim.
         """
-        slots = 0 if stash is None else stash.shape[1]
         if stash is not None:
             spilled_before = kops.stash_occupancy(stash)
         out = kops.adaptive_insert(
             state.table, state.sels, state.khi, state.klo, hi, lo,
             fp_bits=self.fp_bits, n_buckets=state.n_buckets, valid=valid,
             evict_rounds=self.evict_rounds, stash=stash,
-            use_pallas=self._adaptive_up(state, stash_slots=slots),
+            use_pallas=self._use_pallas(),
             schedule=self.schedule, donate=self.donate)
         ok = out[-1]
         count = state.count + jnp.sum(ok, dtype=jnp.int32)
@@ -355,7 +327,7 @@ class FilterOps:
         out = kops.adaptive_delete(
             state.table, state.sels, state.khi, state.klo, hi, lo,
             fp_bits=self.fp_bits, n_buckets=state.n_buckets, valid=valid,
-            stash=stash, use_pallas=self._adaptive_up(state),
+            stash=stash, use_pallas=self._use_pallas(),
             donate=self.donate)
         ok = out[-1]
         if stash is None:
@@ -410,8 +382,7 @@ class FilterOps:
         ``stash`` the shard's overflow entries answer in the same pass
         (fused on the kernel arm), so routed lookups see spilled keys.
         """
-        slots = 0 if stash is None else stash.shape[1]
-        if self.resolve(table, stash_slots=slots) == "pallas":
+        if self.resolve() == "pallas":
             return kops.filter_lookup(table, hi, lo, fp_bits=self.fp_bits,
                                       n_buckets=n_buckets, stash=stash,
                                       use_pallas="always")
@@ -433,14 +404,12 @@ class FilterOps:
         ``insert_spill`` minus the FilterState bookkeeping (shards count
         occupancy from the table itself).
         """
-        slots = 0 if stash is None else stash.shape[1]
-        up = ("always" if self.resolve(table, stash_slots=slots) == "pallas"
-              else "never")
         return kops.filter_insert(table, hi, lo, fp_bits=self.fp_bits,
                                   n_buckets=n_buckets, valid=valid,
                                   evict_rounds=self.evict_rounds,
                                   stash=stash, max_disp=self.max_disp,
-                                  use_pallas=up, schedule=self.schedule)
+                                  use_pallas=self._use_pallas(),
+                                  schedule=self.schedule)
 
     def delete_table(self, table: jax.Array, hi: jax.Array, lo: jax.Array, *,
                      n_buckets=None, valid: Optional[jax.Array] = None,
@@ -453,10 +422,9 @@ class FilterOps:
         sequential order), so a burst-parked key is deletable like any
         other.
         """
-        up = "always" if self.resolve(table) == "pallas" else "never"
         return kops.filter_delete(table, hi, lo, fp_bits=self.fp_bits,
                                   n_buckets=n_buckets, valid=valid,
-                                  stash=stash, use_pallas=up)
+                                  stash=stash, use_pallas=self._use_pallas())
 
     # --------------------------------------------------- telemetry twins --
     #

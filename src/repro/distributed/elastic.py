@@ -4,9 +4,8 @@ Two layers live here:
 
 **Mesh elasticity** (``largest_mesh`` / ``reshard_state``): when a pod drops
 out, pick the largest grid the survivors support and re-derive shardings
-from the logical specs — unchanged from the original module, now
-feature-detecting ``jax.sharding.AxisType`` (absent on the 0.4.x line the
-repo compat-shims elsewhere).
+from the logical specs; meshes come from the one helper,
+``launch.mesh.make_mesh``.
 
 **Filter elasticity** (``split_state`` / ``merge_state`` / the round
 machinery): grow or shrink a live ``ShardedFilterState`` between pow2 shard
@@ -59,23 +58,10 @@ from repro.core.distributed import ShardedFilterState, _shard_map_unchecked
 from repro.core.scheduling import conflict_waves
 from repro.distributed.sharding import ParallelConfig, make_shardings
 from repro.kernels import stash as kstash
+from repro.launch.mesh import make_mesh
 
 
 # ------------------------------------------------------- mesh elasticity --
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` kwarg for ``jax.make_mesh``, or {} where unsupported.
-
-    ``jax.sharding.AxisType`` only exists on newer jax; the 0.4.x line this
-    repo still runs on has neither the enum nor the kwarg, and passing it
-    raises ``AttributeError`` before ``make_mesh`` even sees the call.  The
-    default axis type there is Auto anyway, so omitting it is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
 
 
 def largest_mesh(devices: Optional[Sequence] = None, *, model_parallel: int,
@@ -92,8 +78,7 @@ def largest_mesh(devices: Optional[Sequence] = None, *, model_parallel: int,
         raise RuntimeError(
             f"{n} devices cannot host model_parallel={model_parallel}")
     use = devices[: data * model_parallel]
-    return jax.make_mesh((data, model_parallel), axis_names, devices=use,
-                         **_axis_type_kwargs(2))
+    return make_mesh((data, model_parallel), axis_names, devices=use)
 
 
 def filter_mesh(n_shards: int, axis_name: str = "data",
@@ -103,11 +88,7 @@ def filter_mesh(n_shards: int, axis_name: str = "data",
     The elastic controller builds the pre- and post-cutover meshes with
     this so a 2->4 split and its 4->2 inverse agree on device order.
     """
-    devices = list(devices if devices is not None else jax.devices())
-    if len(devices) < n_shards:
-        raise RuntimeError(
-            f"{len(devices)} devices cannot host {n_shards} filter shards")
-    return Mesh(np.array(devices[:n_shards]), (axis_name,))
+    return make_mesh((n_shards,), (axis_name,), devices=devices)
 
 
 def reshard_state(state_tree, specs_tree, new_mesh: Mesh,

@@ -43,9 +43,14 @@ Schedule:
 Eviction-round invariants (why rollback is conflict-free):
   * one kick per bucket per round (rank-0 lane wins; later lanes retry next
     round), so two lanes never kick the same slot in the same round;
-  * a kicked slot is marked **dirty** and never kicked again this
-    invocation, so across rounds every table slot is written by at most one
-    lane — rollback scatters of failed lanes touch only slots they own;
+  * a kicked slot is **dirty** — never kicked again — while the lane that
+    kicked it is still carrying, so every slot a chain may have to restore
+    is written by that lane alone — rollback scatters of failed lanes touch
+    only slots they own.  A table-shaped **owner map** records, per slot,
+    the token of the last lane that kicked it (unique within one call), so
+    a slot is dirty exactly while its token names a lane still carrying:
+    each round costs one row gather and one per-lane scatter, and the map
+    is zeroed once per call, not per block;
   * a lane's preferred kick slot rotates ``steps % bucket_size`` exactly
     like the sequential chain (``pyfilter`` / ``core.filter._insert_one``),
     so a single-lane residue walks the identical chain and produces the
@@ -91,9 +96,11 @@ def _place_round(table, target, active, fp):
     buf, _bucket_size = table.shape
     rank = rank_among_earlier(target, active)
     tgt_c = jnp.clip(target, 0, buf - 1)
-    free = jnp.sum(table == 0, axis=1).astype(jnp.int32)  # empties per bucket
-    fits = active & (rank < free[tgt_c])
     row = table[tgt_c]                                    # [n, bucket_size]
+    # Empties of each lane's own bucket, counted on the gathered row: a
+    # per-bucket count over the whole table would read all of it per round.
+    free = jnp.sum(row == 0, axis=1).astype(jnp.int32)
+    fits = active & (rank < free)
     empty_pos = jnp.cumsum((row == 0).astype(jnp.int32), axis=1) - 1
     is_dest = (row == 0) & (empty_pos == rank[:, None])
     slot = jnp.argmax(is_dest, axis=1)
@@ -102,8 +109,24 @@ def _place_round(table, target, active, fp):
     return table, fits
 
 
-def _evict_rounds(table, fp, start_bucket, residue, n_buckets, rounds: int,
-                  stash=None, want_stats: bool = False):
+def _dirty_slots(owner, b_c, active, base):
+    """bool[n, bucket_size]: slots of buckets ``b_c`` kicked by a lane of
+    this block that is still carrying.
+
+    ``owner`` holds ``base + lane + 1`` of the last lane that kicked each
+    slot (0: none); tokens of earlier blocks fall below ``base + 1`` and
+    read as clean, since every lane of a finished block has landed,
+    spilled or rolled back.  A lane that lands stops being ``active``,
+    which releases all of its kicks at once.
+    """
+    n = active.shape[0]
+    lane = owner[b_c] - (base + 1)                        # [n, bucket_size]
+    mine = (lane >= 0) & (lane < n)
+    return mine & active[jnp.clip(lane, 0, n - 1)]
+
+
+def _evict_rounds(table, owner, base, fp, start_bucket, residue, n_buckets,
+                  rounds: int, stash=None, want_stats: bool = False):
     """Bounded device-side eviction rounds for the contended residue.
 
     Each residual lane carries a fingerprint (initially its own; after a
@@ -125,49 +148,45 @@ def _evict_rounds(table, fp, start_bucket, residue, n_buckets, rounds: int,
     ``stash_match`` probes against.  Lanes that miss the stash too (or when
     ``stash is None``) roll their kicks back in reverse — restoring every
     victim to its original slot — and report failure.
-    Returns (table, completed bool[N]) or (table, stash, completed).
+
+    ``owner``/``base``: the slot-owner map and this block's token base
+    (see ``_dirty_slots``).
+    Returns (table, owner, stash, completed bool[N], stats); ``stash`` is
+    None without one, ``stats`` None unless ``want_stats``.
     """
     buf, bucket_size = table.shape
     n = fp.shape[0]
     slot_iota = jax.lax.broadcasted_iota(jnp.int32, (n, bucket_size), 1)
+    step_iota = jax.lax.broadcasted_iota(jnp.int32, (n, rounds), 1)
+    token = base + 1 + jax.lax.iota(jnp.int32, n)
 
     def round_body(carry):
-        (r, table, dirty, carried, bucket, active, steps, hb, hs, hw) = carry
+        (r, table, owner, carried, bucket, active, steps, hb, hs, hw) = carry
         # phase A: carried fp into an empty slot of the current bucket.
         table, placed = _place_round(table, bucket, active, carried)
+        # A completed lane never rolls back, so its kicks need no
+        # protection and free up for later kicks (without that, long
+        # chains starve on fully-dirty hot buckets).
         active = active & ~placed
-
-        # A completed lane will never roll back, so its kicked slots no
-        # longer need rollback protection — release them for later kicks
-        # (without this, long chains starve on fully-dirty hot buckets).
-        def release(t, dirty):
-            has = placed & (t < steps)
-            upd_i = jnp.where(has, hb[:, t], buf)
-            return dirty.at[upd_i, hs[:, t]].set(False, mode="drop")
-
-        dirty = jax.lax.cond(
-            jnp.any(placed & (steps > 0)),
-            lambda d: jax.lax.fori_loop(0, r + 1, release, d),
-            lambda d: d, dirty)
         # phase B: one kick per bucket — earliest active lane wins the round.
         first = active & (rank_among_earlier(bucket, active) == 0)
         b_c = jnp.clip(bucket, 0, buf - 1)
         # First non-dirty slot, rotating from the sequential chain's
         # preferred slot (steps % bucket_size) — dirty slots hold another
         # lane's kick and are off-limits (rollback exclusivity).
+        dirty = _dirty_slots(owner, b_c, active, base)
         pos = (slot_iota + (steps % bucket_size)[:, None]) % bucket_size
-        cand_free = ~jnp.take_along_axis(dirty[b_c], pos, axis=1)
+        cand_free = ~jnp.take_along_axis(dirty, pos, axis=1)
         kick = first & jnp.any(cand_free, axis=1)
         k = jnp.argmax(cand_free, axis=1)
         slot = jnp.take_along_axis(pos, k[:, None], axis=1)[:, 0]
         victim = table[b_c, slot]
         upd_i = jnp.where(kick, bucket, buf)              # OOB -> dropped
         table = table.at[upd_i, slot].set(carried, mode="drop")
-        dirty = dirty.at[upd_i, slot].set(True, mode="drop")
+        owner = owner.at[upd_i, slot].set(token, mode="drop")
         # Per-lane chain history (bucket, slot, written value) at column
         # ``steps`` — what rollback needs to unwind a failed chain.
-        onehot = (jax.lax.broadcasted_iota(jnp.int32, (n, rounds), 1)
-                  == steps[:, None]) & kick[:, None]
+        onehot = (step_iota == steps[:, None]) & kick[:, None]
         hb = jnp.where(onehot, bucket[:, None], hb)
         hs = jnp.where(onehot, slot[:, None], hs)
         hw = jnp.where(onehot, carried[:, None], hw)
@@ -175,19 +194,19 @@ def _evict_rounds(table, fp, start_bucket, residue, n_buckets, rounds: int,
         carried = jnp.where(kick, victim, carried)
         bucket = jnp.where(kick, nxt, bucket)
         steps = steps + kick.astype(jnp.int32)
-        return (r + 1, table, dirty, carried, bucket, active, steps, hb, hs,
+        return (r + 1, table, owner, carried, bucket, active, steps, hb, hs,
                 hw)
 
     def round_cond(carry):
-        r, _t, _d, _c, _b, active, *_ = carry
+        r, _t, _o, _c, _b, active, *_ = carry
         return (r < rounds) & jnp.any(active)
 
-    init = (jnp.int32(0), table, jnp.zeros(table.shape, jnp.bool_),
-            fp, start_bucket, residue, jnp.zeros((n,), jnp.int32),
+    init = (jnp.int32(0), table, owner, fp, start_bucket, residue,
+            jnp.zeros((n,), jnp.int32),
             jnp.zeros((n, rounds), jnp.int32),
             jnp.zeros((n, rounds), jnp.int32),
             jnp.zeros((n, rounds), jnp.uint32))
-    (_r, table, _dirty, carried, bucket, active, steps, hb, hs,
+    (_r, table, owner, carried, bucket, active, steps, hb, hs,
      hw) = jax.lax.while_loop(round_cond, round_body, init)
 
     # Spill: exhausted lanes park their carried fp in the stash (chain kicks
@@ -223,19 +242,27 @@ def _evict_rounds(table, fp, start_bucket, residue, n_buckets, rounds: int,
     # Telemetry-twin extras: per-lane chain length + spill/rollback masks
     # (the raw material the dispatch layer folds into FilterTelemetry).
     stats = (steps, spilled, failed) if want_stats else None
-    if stash is not None:
-        if want_stats:
-            return table, stash, residue & ~failed, stats
-        return table, stash, residue & ~failed
-    if want_stats:
-        return table, residue & ~failed, stats
-    return table, residue & ~failed
+    return table, owner, stash, residue & ~failed, stats
 
 
-def _insert_body(table, stash, hi, lo, valid, n_buckets, *, fp_bits: int,
-                 evict_rounds: int, want_stats: bool = False):
-    """Optimistic rounds + eviction rounds (+ stash spill) on loaded values.
+def _new_owner(table, evict_rounds: int):
+    """A zeroed slot-owner map for one call (None when no eviction runs)."""
+    return jnp.zeros(table.shape, jnp.int32) if evict_rounds > 0 else None
 
+
+def _block_bases(g: int, block: int):
+    """Token base of each of ``g`` blocks: unique owner tokens per call."""
+    return jax.lax.iota(jnp.int32, g) * block
+
+
+def _insert_body(table, stash, owner, base, hi, lo, valid, n_buckets, *,
+                 fp_bits: int, evict_rounds: int, want_stats: bool = False):
+    """Optimistic rounds + eviction rounds (+ stash spill) on loaded values
+    -> (table, stash, owner, ok[, tm]).
+
+    ``owner``/``base`` are the eviction rounds' slot-owner map and this
+    block's token base (``_dirty_slots``); ``owner`` is None when
+    ``evict_rounds`` is 0.
     ``want_stats`` (trace-time bool) additionally returns a
     ``FilterTelemetry`` for the block: kick-depth histogram over every
     valid lane (optimistic placements count as depth 0), spill / rollback
@@ -254,23 +281,12 @@ def _insert_body(table, stash, hi, lo, valid, n_buckets, *, fp_bits: int,
     failed = jnp.zeros((n,), jnp.bool_)
     if evict_rounds > 0:
         # Chains start at the alternate bucket, matching the sequential path.
-        if stash is None:
-            if want_stats:
-                table, completed, (steps, spilled, failed) = _evict_rounds(
-                    table, fp, i2, valid & ~ok, n_buckets, evict_rounds,
-                    want_stats=True)
-            else:
-                table, completed = _evict_rounds(table, fp, i2, valid & ~ok,
-                                                 n_buckets, evict_rounds)
-        elif want_stats:
-            table, stash, completed, (steps, spilled, failed) = _evict_rounds(
-                table, fp, i2, valid & ~ok, n_buckets, evict_rounds,
-                stash=stash, want_stats=True)
-        else:
-            table, stash, completed = _evict_rounds(
-                table, fp, i2, valid & ~ok, n_buckets, evict_rounds,
-                stash=stash)
+        table, owner, stash, completed, stats = _evict_rounds(
+            table, owner, base, fp, i2, valid & ~ok, n_buckets, evict_rounds,
+            stash=stash, want_stats=want_stats)
         ok = ok | completed
+        if want_stats:
+            steps, spilled, failed = stats
     elif stash is not None:
         # No eviction budget at all: the optimistic residue spills straight
         # to the stash (bound for its alternate bucket, where a chain would
@@ -279,22 +295,25 @@ def _insert_body(table, stash, hi, lo, valid, n_buckets, *, fp_bits: int,
         ok = ok | spilled0
         spilled = spilled0
     if not want_stats:
-        return table, stash, ok
+        return table, stash, owner, ok
     tm = empty_telemetry()._replace(
         kick_hist=kick_histogram(steps, valid),
         stash_spills=jnp.sum(spilled).astype(jnp.uint32),
         rollback_lanes=jnp.sum(failed).astype(jnp.uint32),
         stash_fill_hw=(stash_occupancy(stash).astype(jnp.uint32)
                        if stash is not None else jnp.zeros((), jnp.uint32)))
-    return table, stash, ok, tm
+    return table, stash, owner, ok, tm
 
 
 def _insert_kernel(n_ref, table_in_ref, hi_ref, lo_ref, valid_ref, table_ref,
                    ok_ref, *, fp_bits: int, evict_rounds: int):
     del table_in_ref  # aliased to table_ref (the output) — read/write there
-    table, _stash, ok = _insert_body(
-        table_ref[...], None, hi_ref[...], lo_ref[...], valid_ref[...],
-        n_ref[0, 0], fp_bits=fp_bits, evict_rounds=evict_rounds)
+    # A fresh owner map per grid step reads the same dirty slots as the
+    # emulation's per-call map: earlier blocks' tokens are clean there too.
+    table, _stash, _owner, ok = _insert_body(
+        table_ref[...], None, _new_owner(table_ref, evict_rounds), 0,
+        hi_ref[...], lo_ref[...], valid_ref[...], n_ref[0, 0],
+        fp_bits=fp_bits, evict_rounds=evict_rounds)
     table_ref[...] = table
     ok_ref[...] = ok
 
@@ -303,10 +322,10 @@ def _insert_stash_kernel(n_ref, table_in_ref, stash_in_ref, hi_ref, lo_ref,
                          valid_ref, table_ref, stash_ref, ok_ref, *,
                          fp_bits: int, evict_rounds: int):
     del table_in_ref, stash_in_ref  # aliased to the outputs — read/write there
-    table, stash, ok = _insert_body(
-        table_ref[...], stash_ref[...], hi_ref[...], lo_ref[...],
-        valid_ref[...], n_ref[0, 0], fp_bits=fp_bits,
-        evict_rounds=evict_rounds)
+    table, stash, _owner, ok = _insert_body(
+        table_ref[...], stash_ref[...], _new_owner(table_ref, evict_rounds),
+        0, hi_ref[...], lo_ref[...], valid_ref[...], n_ref[0, 0],
+        fp_bits=fp_bits, evict_rounds=evict_rounds)
     table_ref[...] = table
     stash_ref[...] = stash
     ok_ref[...] = ok
@@ -324,55 +343,31 @@ def _emulated_insert(table, stash, hi, lo, valid, n_buckets, *,
     results; this is what the off-TPU dispatch runs so the "pallas" backend
     is a *throughput* configuration on CPU hosts too, not just a
     correctness one (the interpreter re-dispatches every primitive per
-    grid step, which is ~100x slower than the compiled scan).
+    grid step, which is ~100x slower than the compiled scan).  The slot-
+    owner map rides in the carry as well, zeroed once per call.
 
     ``want_stats`` rides the per-block ``FilterTelemetry`` in the scan
     carry (fixed shape) and merges it across blocks — returns an extra tm.
     """
     g = hi.shape[0] // block
-    if want_stats:
-        if g == 1:
-            return _insert_body(table, stash, hi, lo, valid, n_buckets,
-                                fp_bits=fp_bits, evict_rounds=evict_rounds,
-                                want_stats=True)
-        xs = (hi.reshape(g, block), lo.reshape(g, block),
-              valid.reshape(g, block))
-
-        def step(carry, x):
-            tbl, st, tm = carry
-            tbl, st, ok, tm_b = _insert_body(
-                tbl, st, *x, n_buckets, fp_bits=fp_bits,
-                evict_rounds=evict_rounds, want_stats=True)
-            return (tbl, st, tm_merge(tm, tm_b)), ok
-
-        (table, stash, tm), ok = jax.lax.scan(
-            step, (table, stash, empty_telemetry()), xs)
-        return table, stash, ok.reshape(-1), tm
-    if g == 1:
-        table, stash, ok = _insert_body(table, stash, hi, lo, valid,
-                                        n_buckets, fp_bits=fp_bits,
-                                        evict_rounds=evict_rounds)
-        return table, stash, ok
     xs = (hi.reshape(g, block), lo.reshape(g, block),
-          valid.reshape(g, block))
-
-    if stash is None:
-        def step(tbl, x):
-            tbl, _stash, ok = _insert_body(tbl, None, *x, n_buckets,
-                                           fp_bits=fp_bits,
-                                           evict_rounds=evict_rounds)
-            return tbl, ok
-
-        table, ok = jax.lax.scan(step, table, xs)
-        return table, None, ok.reshape(-1)
+          valid.reshape(g, block), _block_bases(g, block))
 
     def step(carry, x):
-        tbl, st = carry
-        tbl, st, ok = _insert_body(tbl, st, *x, n_buckets, fp_bits=fp_bits,
-                                   evict_rounds=evict_rounds)
-        return (tbl, st), ok
+        tbl, st, own, tm = carry
+        *keys, base = x
+        out = _insert_body(tbl, st, own, base, *keys, n_buckets,
+                           fp_bits=fp_bits, evict_rounds=evict_rounds,
+                           want_stats=want_stats)
+        if want_stats:
+            tm = tm_merge(tm, out[4])
+        return (*out[:3], tm), out[3]
 
-    (table, stash), ok = jax.lax.scan(step, (table, stash), xs)
+    tm0 = empty_telemetry() if want_stats else None
+    (table, stash, _owner, tm), ok = jax.lax.scan(
+        step, (table, stash, _new_owner(table, evict_rounds), tm0), xs)
+    if want_stats:
+        return table, stash, ok.reshape(-1), tm
     return table, stash, ok.reshape(-1)
 
 
@@ -407,11 +402,12 @@ def _insert_bulk_impl(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
         hi, lo, valid = hi[perm], lo[perm], valid[perm]
     if telemetry:
         # Telemetry twin: always the XLA-emulation arm (same bits as the
-        # kernel by the PR-5 parity contract; on TPU this trades the
-        # pallas_call for a compiled scan — a perf configuration, never a
-        # correctness one).  The per-lane stats are permutation-invariant
-        # sums/histograms, so the schedule pre-pass needs no inverse
-        # scatter on the telemetry, only on ``ok``.
+        # kernel by the PR-5 parity contract).  ``ops.LOWERING`` gives every
+        # insert the "xla" form, so on a TPU the twin and the telemetry-off
+        # path run the same body; a port of the insert to Mosaic has to
+        # bring this counter plane along.  The per-lane stats are
+        # permutation-invariant sums/histograms, so the schedule pre-pass
+        # needs no inverse scatter on the telemetry, only on ``ok``.
         new_table, new_stash, ok, tm = _emulated_insert(
             table, stash, hi, lo, valid, n_buckets, fp_bits=fp_bits,
             evict_rounds=evict_rounds, block=block, want_stats=True)
@@ -579,9 +575,9 @@ def _place_round_adaptive(planes, target, active, fp, khi, klo):
     buf, _bucket_size = table.shape
     rank = rank_among_earlier(target, active)
     tgt_c = jnp.clip(target, 0, buf - 1)
-    free = jnp.sum(table == 0, axis=1).astype(jnp.int32)
-    fits = active & (rank < free[tgt_c])
     row = table[tgt_c]
+    free = jnp.sum(row == 0, axis=1).astype(jnp.int32)    # see _place_round
+    fits = active & (rank < free)
     empty_pos = jnp.cumsum((row == 0).astype(jnp.int32), axis=1) - 1
     is_dest = (row == 0) & (empty_pos == rank[:, None])
     slot = jnp.argmax(is_dest, axis=1)
@@ -593,9 +589,9 @@ def _place_round_adaptive(planes, target, active, fp, khi, klo):
     return (table, sel_tbl, khi_t, klo_t), fits
 
 
-def _evict_rounds_adaptive(planes, hi, lo, start_bucket, residue, n_buckets,
-                           rounds: int, *, fp_bits: int, stash=None,
-                           want_stats: bool = False):
+def _evict_rounds_adaptive(planes, owner, base, hi, lo, start_bucket, residue,
+                           n_buckets, rounds: int, *, fp_bits: int,
+                           stash=None, want_stats: bool = False):
     """Bounded eviction rounds over the four adaptive planes.
 
     Lanes carry the KEY (hi, lo) — the carried fingerprint is always its
@@ -606,34 +602,28 @@ def _evict_rounds_adaptive(planes, hi, lo, start_bucket, residue, n_buckets,
     its kicked slots, restoring originals is exactly the static kernel's
     newest-first unwind (which reconstructs the same values chain-step by
     chain-step), including an adapted victim's original selector.
+    Dirty slots come from the same slot-owner map as the static rounds.
+    Returns (planes, owner, stash, completed, stats) like ``_evict_rounds``.
     """
     table, sel_tbl, khi_t, klo_t = planes
     buf, bucket_size = table.shape
     n = hi.shape[0]
     slot_iota = jax.lax.broadcasted_iota(jnp.int32, (n, bucket_size), 1)
+    token = base + 1 + jax.lax.iota(jnp.int32, n)
 
     def round_body(carry):
-        (r, planes, dirty, chi, clo, bucket, active, steps, hist) = carry
+        (r, planes, owner, chi, clo, bucket, active, steps, hist) = carry
         cfp = hashing.fingerprint(chi, clo, fp_bits)
         planes, placed = _place_round_adaptive(planes, bucket, active, cfp,
                                                chi, clo)
         active = active & ~placed
         table, sel_tbl, khi_t, klo_t = planes
         hb, hs, hfp, hsel, hhi, hlo = hist
-
-        def release(t, dirty):
-            has = placed & (t < steps)
-            upd_i = jnp.where(has, hb[:, t], buf)
-            return dirty.at[upd_i, hs[:, t]].set(False, mode="drop")
-
-        dirty = jax.lax.cond(
-            jnp.any(placed & (steps > 0)),
-            lambda d: jax.lax.fori_loop(0, r + 1, release, d),
-            lambda d: d, dirty)
         first = active & (rank_among_earlier(bucket, active) == 0)
         b_c = jnp.clip(bucket, 0, buf - 1)
         pos = (slot_iota + (steps % bucket_size)[:, None]) % bucket_size
-        cand_free = ~jnp.take_along_axis(dirty[b_c], pos, axis=1)
+        dirty = _dirty_slots(owner, b_c, active, base)
+        cand_free = ~jnp.take_along_axis(dirty, pos, axis=1)
         kick = first & jnp.any(cand_free, axis=1)
         k = jnp.argmax(cand_free, axis=1)
         slot = jnp.take_along_axis(pos, k[:, None], axis=1)[:, 0]
@@ -648,7 +638,7 @@ def _evict_rounds_adaptive(planes, hi, lo, start_bucket, residue, n_buckets,
         sel_tbl = sel_tbl.at[upd_i, slot].set(jnp.uint32(0), mode="drop")
         khi_t = khi_t.at[upd_i, slot].set(chi, mode="drop")
         klo_t = klo_t.at[upd_i, slot].set(clo, mode="drop")
-        dirty = dirty.at[upd_i, slot].set(True, mode="drop")
+        owner = owner.at[upd_i, slot].set(token, mode="drop")
         onehot = (jax.lax.broadcasted_iota(jnp.int32, (n, rounds), 1)
                   == steps[:, None]) & kick[:, None]
         hb = jnp.where(onehot, bucket[:, None], hb)
@@ -665,11 +655,11 @@ def _evict_rounds_adaptive(planes, hi, lo, start_bucket, residue, n_buckets,
         clo = jnp.where(kick, vlo, clo)
         bucket = jnp.where(kick, nxt, bucket)
         steps = steps + kick.astype(jnp.int32)
-        return (r + 1, (table, sel_tbl, khi_t, klo_t), dirty, chi, clo,
+        return (r + 1, (table, sel_tbl, khi_t, klo_t), owner, chi, clo,
                 bucket, active, steps, (hb, hs, hfp, hsel, hhi, hlo))
 
     def round_cond(carry):
-        r, _p, _d, _chi, _clo, _b, active, *_ = carry
+        r, _p, _o, _chi, _clo, _b, active, *_ = carry
         return (r < rounds) & jnp.any(active)
 
     hist0 = (jnp.zeros((n, rounds), jnp.int32),
@@ -678,9 +668,9 @@ def _evict_rounds_adaptive(planes, hi, lo, start_bucket, residue, n_buckets,
              jnp.zeros((n, rounds), jnp.uint32),
              jnp.zeros((n, rounds), jnp.uint32),
              jnp.zeros((n, rounds), jnp.uint32))
-    init = (jnp.int32(0), planes, jnp.zeros(table.shape, jnp.bool_),
-            hi, lo, start_bucket, residue, jnp.zeros((n,), jnp.int32), hist0)
-    (_r, planes, _dirty, chi, clo, bucket, active, steps,
+    init = (jnp.int32(0), planes, owner, hi, lo, start_bucket, residue,
+            jnp.zeros((n,), jnp.int32), hist0)
+    (_r, planes, owner, chi, clo, bucket, active, steps,
      hist) = jax.lax.while_loop(round_cond, round_body, init)
     table, sel_tbl, khi_t, klo_t = planes
     hb, hs, hfp, hsel, hhi, hlo = hist
@@ -717,23 +707,18 @@ def _evict_rounds_adaptive(planes, hi, lo, start_bucket, residue, n_buckets,
         lambda p: jax.lax.fori_loop(0, rounds, rb_body, p),
         lambda p: p, (table, sel_tbl, khi_t, klo_t))
     stats = (steps, spilled, failed) if want_stats else None
-    if stash is not None:
-        if want_stats:
-            return planes, stash, residue & ~failed, stats
-        return planes, stash, residue & ~failed
-    if want_stats:
-        return planes, residue & ~failed, stats
-    return planes, residue & ~failed
+    return planes, owner, stash, residue & ~failed, stats
 
 
-def _insert_adaptive_body(table, sels, khi_t, klo_t, stash, hi, lo, valid,
-                          n_buckets, *, fp_bits: int, evict_rounds: int,
-                          want_stats: bool = False):
-    """Optimistic + eviction rounds over the four adaptive planes.
+def _insert_adaptive_body(table, sels, khi_t, klo_t, stash, owner, base, hi,
+                          lo, valid, n_buckets, *, fp_bits: int,
+                          evict_rounds: int, want_stats: bool = False):
+    """Optimistic + eviction rounds over the four adaptive planes
+    -> (table, sels, khi, klo, stash, owner, ok[, tm]).
 
     ``sels`` is the PACKED plane; pack∘unpack is the identity, so per-block
     repacking keeps the pallas grid and the emulation scan bit-for-bit.
-    ``want_stats`` mirrors the static body's telemetry extras.
+    ``owner``/``base`` and ``want_stats`` as in the static ``_insert_body``.
     """
     n = hi.shape[0]
     bucket_size = table.shape[-1]
@@ -749,40 +734,26 @@ def _insert_adaptive_body(table, sels, khi_t, klo_t, stash, hi, lo, valid,
     spilled = jnp.zeros((n,), jnp.bool_)
     failed = jnp.zeros((n,), jnp.bool_)
     if evict_rounds > 0:
-        if stash is None:
-            if want_stats:
-                planes, completed, (steps, spilled, failed) = (
-                    _evict_rounds_adaptive(
-                        planes, hi, lo, i2, valid & ~ok, n_buckets,
-                        evict_rounds, fp_bits=fp_bits, want_stats=True))
-            else:
-                planes, completed = _evict_rounds_adaptive(
-                    planes, hi, lo, i2, valid & ~ok, n_buckets, evict_rounds,
-                    fp_bits=fp_bits)
-        elif want_stats:
-            planes, stash, completed, (steps, spilled, failed) = (
-                _evict_rounds_adaptive(
-                    planes, hi, lo, i2, valid & ~ok, n_buckets, evict_rounds,
-                    fp_bits=fp_bits, stash=stash, want_stats=True))
-        else:
-            planes, stash, completed = _evict_rounds_adaptive(
-                planes, hi, lo, i2, valid & ~ok, n_buckets, evict_rounds,
-                fp_bits=fp_bits, stash=stash)
+        planes, owner, stash, completed, stats = _evict_rounds_adaptive(
+            planes, owner, base, hi, lo, i2, valid & ~ok, n_buckets,
+            evict_rounds, fp_bits=fp_bits, stash=stash, want_stats=want_stats)
         ok = ok | completed
+        if want_stats:
+            steps, spilled, failed = stats
     elif stash is not None:
         stash, spilled0 = stash_spill(stash, fp, i2, valid & ~ok)
         ok = ok | spilled0
         spilled = spilled0
     table, sel_tbl, khi_t, klo_t = planes
     if not want_stats:
-        return table, sel_pack(sel_tbl), khi_t, klo_t, stash, ok
+        return table, sel_pack(sel_tbl), khi_t, klo_t, stash, owner, ok
     tm = empty_telemetry()._replace(
         kick_hist=kick_histogram(steps, valid),
         stash_spills=jnp.sum(spilled).astype(jnp.uint32),
         rollback_lanes=jnp.sum(failed).astype(jnp.uint32),
         stash_fill_hw=(stash_occupancy(stash).astype(jnp.uint32)
                        if stash is not None else jnp.zeros((), jnp.uint32)))
-    return table, sel_pack(sel_tbl), khi_t, klo_t, stash, ok, tm
+    return table, sel_pack(sel_tbl), khi_t, klo_t, stash, owner, ok, tm
 
 
 def _insert_adaptive_kernel(n_ref, table_in, sels_in, khi_in, klo_in, hi_ref,
@@ -790,10 +761,11 @@ def _insert_adaptive_kernel(n_ref, table_in, sels_in, khi_in, klo_in, hi_ref,
                             klo_ref, ok_ref, *, fp_bits: int,
                             evict_rounds: int):
     del table_in, sels_in, khi_in, klo_in      # aliased to the outputs
-    table, sels, khi_t, klo_t, _stash, ok = _insert_adaptive_body(
+    table, sels, khi_t, klo_t, _stash, _owner, ok = _insert_adaptive_body(
         table_ref[...], sels_ref[...], khi_ref[...], klo_ref[...], None,
-        hi_ref[...], lo_ref[...], valid_ref[...], n_ref[0, 0],
-        fp_bits=fp_bits, evict_rounds=evict_rounds)
+        _new_owner(table_ref, evict_rounds), 0, hi_ref[...], lo_ref[...],
+        valid_ref[...], n_ref[0, 0], fp_bits=fp_bits,
+        evict_rounds=evict_rounds)
     table_ref[...] = table
     sels_ref[...] = sels
     khi_ref[...] = khi_t
@@ -807,10 +779,11 @@ def _insert_adaptive_stash_kernel(n_ref, table_in, sels_in, khi_in, klo_in,
                                   stash_ref, ok_ref, *, fp_bits: int,
                                   evict_rounds: int):
     del table_in, sels_in, khi_in, klo_in, stash_in    # aliased to outputs
-    table, sels, khi_t, klo_t, stash, ok = _insert_adaptive_body(
+    table, sels, khi_t, klo_t, stash, _owner, ok = _insert_adaptive_body(
         table_ref[...], sels_ref[...], khi_ref[...], klo_ref[...],
-        stash_ref[...], hi_ref[...], lo_ref[...], valid_ref[...], n_ref[0, 0],
-        fp_bits=fp_bits, evict_rounds=evict_rounds)
+        stash_ref[...], _new_owner(table_ref, evict_rounds), 0, hi_ref[...],
+        lo_ref[...], valid_ref[...], n_ref[0, 0], fp_bits=fp_bits,
+        evict_rounds=evict_rounds)
     table_ref[...] = table
     sels_ref[...] = sels
     khi_ref[...] = khi_t
@@ -823,53 +796,29 @@ def _emulated_insert_adaptive(table, sels, khi_t, klo_t, stash, hi, lo, valid,
                               n_buckets, *, fp_bits: int, evict_rounds: int,
                               block: int, want_stats: bool = False):
     """The adaptive kernel schedule as a compiled XLA scan (the off-TPU
-    path) — same ``_insert_adaptive_body`` per block, planes carried."""
+    path) — same ``_insert_adaptive_body`` per block, planes and slot-owner
+    map carried (see ``_emulated_insert``)."""
     g = hi.shape[0] // block
-    if want_stats:
-        if g == 1:
-            return _insert_adaptive_body(
-                table, sels, khi_t, klo_t, stash, hi, lo, valid, n_buckets,
-                fp_bits=fp_bits, evict_rounds=evict_rounds, want_stats=True)
-        xs = (hi.reshape(g, block), lo.reshape(g, block),
-              valid.reshape(g, block))
-
-        def step(carry, x):
-            t, s, kh, kl, st, tm = carry
-            t, s, kh, kl, st, ok, tm_b = _insert_adaptive_body(
-                t, s, kh, kl, st, *x, n_buckets, fp_bits=fp_bits,
-                evict_rounds=evict_rounds, want_stats=True)
-            return (t, s, kh, kl, st, tm_merge(tm, tm_b)), ok
-
-        (table, sels, khi_t, klo_t, stash, tm), ok = jax.lax.scan(
-            step, (table, sels, khi_t, klo_t, stash, empty_telemetry()), xs)
-        return table, sels, khi_t, klo_t, stash, ok.reshape(-1), tm
-    if g == 1:
-        return _insert_adaptive_body(table, sels, khi_t, klo_t, stash, hi,
-                                     lo, valid, n_buckets, fp_bits=fp_bits,
-                                     evict_rounds=evict_rounds)
-    xs = (hi.reshape(g, block), lo.reshape(g, block), valid.reshape(g, block))
-
-    if stash is None:
-        def step(carry, x):
-            t, s, kh, kl = carry
-            t, s, kh, kl, _stash, ok = _insert_adaptive_body(
-                t, s, kh, kl, None, *x, n_buckets, fp_bits=fp_bits,
-                evict_rounds=evict_rounds)
-            return (t, s, kh, kl), ok
-
-        (table, sels, khi_t, klo_t), ok = jax.lax.scan(
-            step, (table, sels, khi_t, klo_t), xs)
-        return table, sels, khi_t, klo_t, None, ok.reshape(-1)
+    xs = (hi.reshape(g, block), lo.reshape(g, block),
+          valid.reshape(g, block), _block_bases(g, block))
 
     def step(carry, x):
-        t, s, kh, kl, st = carry
-        t, s, kh, kl, st, ok = _insert_adaptive_body(
-            t, s, kh, kl, st, *x, n_buckets, fp_bits=fp_bits,
-            evict_rounds=evict_rounds)
-        return (t, s, kh, kl, st), ok
+        *planes, st, own, tm = carry
+        *keys, base = x
+        out = _insert_adaptive_body(*planes, st, own, base, *keys, n_buckets,
+                                    fp_bits=fp_bits,
+                                    evict_rounds=evict_rounds,
+                                    want_stats=want_stats)
+        if want_stats:
+            tm = tm_merge(tm, out[7])
+        return (*out[:6], tm), out[6]
 
-    (table, sels, khi_t, klo_t, stash), ok = jax.lax.scan(
-        step, (table, sels, khi_t, klo_t, stash), xs)
+    tm0 = empty_telemetry() if want_stats else None
+    (table, sels, khi_t, klo_t, stash, _owner, tm), ok = jax.lax.scan(
+        step, (table, sels, khi_t, klo_t, stash,
+               _new_owner(table, evict_rounds), tm0), xs)
+    if want_stats:
+        return table, sels, khi_t, klo_t, stash, ok.reshape(-1), tm
     return table, sels, khi_t, klo_t, stash, ok.reshape(-1)
 
 

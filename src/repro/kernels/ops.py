@@ -1,14 +1,14 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Backend dispatch: Pallas-TPU lowers only on TPU.  Off TPU each kernel runs
-its **XLA grid emulation** — the identical kernel body compiled as a
-``lax.scan`` over the grid (``emulate=True`` on every kernel entry point) —
-so the "pallas" backend is a throughput configuration on CPU hosts too; the
-Pallas interpreter (``interpret=True`` without ``emulate``) remains
-available for kernel-fidelity debugging and is parity-tested bit-for-bit
-against the emulation.  Large-shape ``auto`` callers still fall back to the
-pure-jnp oracle (``ref.py``), which is what the dry-run compiles.
-``use_pallas='auto'|'always'|'never'`` controls the arms.
+Form: ``LOWERING`` below is the one statement of how each table op runs on
+a TPU — as its ``pallas_call`` lowered by Mosaic, or as its **XLA grid
+emulation** (the identical kernel body compiled as a ``lax.scan`` over the
+grid, ``emulate=True`` on every kernel entry point).  Off TPU every op runs
+its emulation, which the CPU parity tests pin bit-for-bit against the
+Pallas interpreter.  ``use_pallas='auto'|'always'|'never'`` picks between
+that kernel arm and the pure-jnp oracle (``ref.py``): on TPU 'auto' always
+takes the kernel arm; off TPU it takes it for batches and tables the VMEM
+model admits.
 
 Per-op BLOCK sizes come from ``autotune_block`` — the same VMEM footprint
 model ``kernel_vmem_bytes`` gives the 'auto' dispatch, inverted: pick the
@@ -58,6 +58,35 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+# How each table op runs on a TPU, and the only place that decides it:
+#   "mosaic" — its pallas_call, lowered by Mosaic (the TPU Pallas compiler);
+#   "xla"    — its emulate=True body, compiled by XLA.
+# Mosaic refuses every kernel that gathers from or scatters into the table:
+# the probes' row gather on a ref ("Cannot do int indexing on TPU" /
+# "Shape mismatch in input, indices and output"), the insert rounds' 2-D
+# scatter ("Only 2D gather is supported") and the delete clear round.  Only
+# the per-lane hash kernels lower.  tests/test_tpu_compile.py compiles every
+# entry for a v5e and fails when an entry stops saying what Mosaic does.
+LOWERING = {
+    "fingerprint": "mosaic",
+    "fingerprint_family": "mosaic",
+    "probe": "xla",
+    "probe_multi": "xla",
+    "insert": "xla",
+    "insert_stash": "xla",
+    "delete": "xla",
+    "probe_adaptive": "xla",
+    "insert_adaptive": "xla",
+    "delete_adaptive": "xla",
+}
+
+
+def lowering(op: str) -> str:
+    """The form ``op`` runs in here: ``LOWERING[op]`` on a TPU, and "xla"
+    (the emulation) everywhere else."""
+    return LOWERING[op] if _on_tpu() else "xla"
+
+
 # Budgeted bytes/element for the [block, block] broadcast-compare rank
 # (kernels/rank.py).  Bounds: ~1 B/elem if Mosaic streams the iota/compare/
 # reduce tiles (the common lowering), ~9 B/elem if the two int32 iotas and
@@ -71,14 +100,18 @@ def kernel_vmem_bytes(op: str, *, table_bytes: int, block: int,
                       evict_rounds: int = 0, stash_slots: int = 0) -> int:
     """Estimated peak VMEM footprint of one filter-kernel program.
 
-    Used by 'auto' dispatch so budgeting reflects what each kernel actually
-    pins, not just the table:
+    The model counts a ``(buckets, 4)`` table block at 4 B per slot, but
+    VMEM pads the lane dimension to 128, so a resident table block really
+    costs 32x that.  The model is kept as it is until a kernel that touches
+    the table lowers to Mosaic (see ``LOWERING``).  On a TPU it only feeds
+    ``autotune_block``; off TPU it also steers the 'auto' arm, so that
+    budgeting reflects what each kernel pins, not just the table:
       * probe  — the table plus two gathered bucket rows per lane;
       * delete — the table plus the [block, block] broadcast-compare rank
         working set (``RANK_BYTES_PER_ELEM``);
-      * insert — the table twice over (the dirty bitmap rides at table
-        shape), the rank working set, and the 3 per-lane eviction-history
-        arrays of width ``evict_rounds``.
+      * insert — the table twice over (the eviction rounds' slot-owner
+        map is table-shaped), the rank working set, and the 3 per-lane
+        eviction-history arrays of width ``evict_rounds``.
     ``stash_slots`` adds the overflow stash's footprint: the aliased
     uint32[2, S] block plus the [block, S] broadcast-compare mask the match
     (probe) / spill (insert) step materializes.
@@ -124,6 +157,8 @@ def autotune_block(op: str, *, table_bytes: int, evict_rounds: int = 0,
     Candidates are pow2 and must keep the op's ``kernel_vmem_bytes`` inside
     ``VMEM_TABLE_BUDGET`` — the same model 'auto' dispatch budgets with, so
     autotuned blocks can never pick a footprint dispatch would reject.
+    That model counts the table at 4 B per slot, not at VMEM's lane-padded
+    size (see ``kernel_vmem_bytes``); redoing both waits for a kernel port.
     """
     fits = [b for b in _BLOCK_CANDIDATES
             if kernel_vmem_bytes(op, table_bytes=table_bytes, block=b,
@@ -141,30 +176,22 @@ def autotune_block(op: str, *, table_bytes: int, evict_rounds: int = 0,
     return fits[0]
 
 
-def _emulate() -> bool:
-    """Off TPU, run kernels as their compiled XLA grid emulation (bit-for-
-    bit the pallas_call; ~100x the interpreter's throughput)."""
-    return not _on_tpu()
-
-
 def _use_kernel(use_pallas: str, *, vmem_bytes: int, n_keys: int) -> bool:
-    """True when the Pallas kernel should run (vs the pure-jnp ref path).
+    """True when the kernel arm should run (vs the pure-jnp ref path).
 
-    'always' -> kernel, unconditionally (interpret mode off-TPU).
+    'always' -> kernel arm, unconditionally.
     'never'  -> ref path, unconditionally.
-    'auto'   -> kernel iff the op's estimated VMEM footprint (see
-                ``kernel_vmem_bytes``) fits the budget AND, off-TPU, the
-                batch is small enough for interpret mode to be sensible.
+    'auto'   -> kernel arm on TPU for every size (its form comes from
+                ``LOWERING``; the emulation is not bound by VMEM).  Off TPU,
+                kernel arm iff the op's estimated VMEM footprint (see
+                ``kernel_vmem_bytes``) fits the budget and the batch has at
+                most 65,536 keys.
     """
     if use_pallas == "never":
         return False
-    if use_pallas == "always":
+    if use_pallas == "always" or _on_tpu():
         return True
-    if vmem_bytes > VMEM_TABLE_BUDGET:
-        return False
-    if not _on_tpu() and n_keys > 65536:
-        return False
-    return True
+    return vmem_bytes <= VMEM_TABLE_BUDGET and n_keys <= 65536
 
 
 def _pad_to(x: jax.Array, mult: int):
@@ -192,8 +219,8 @@ def hash_keys(hi: jax.Array, lo: jax.Array, *, fp_bits: int, n_buckets: int,
     lo_p, _ = _pad_to(lo, block)
     fp, i1, i2 = fingerprint_hash(hi_p, lo_p, fp_bits=fp_bits,
                                   n_buckets=n_buckets, block=block,
-                                  interpret=not _on_tpu(),
-                                  emulate=_emulate())
+                                  interpret=False,
+                                  emulate=lowering("fingerprint") == "xla")
     return _unpad(fp, n), _unpad(i1, n), _unpad(i2, n)
 
 
@@ -228,8 +255,8 @@ def filter_lookup(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
     hi_p, n = _pad_to(hi, block)
     lo_p, _ = _pad_to(lo, block)
     hit = probe(table, hi_p, lo_p, fp_bits=fp_bits, n_buckets=n_buckets,
-                stash=stash, block=block, interpret=not _on_tpu(),
-                emulate=_emulate())
+                stash=stash, block=block, interpret=False,
+                emulate=lowering("probe") == "xla")
     return _unpad(hit, n)
 
 
@@ -269,7 +296,8 @@ def filter_lookup_multi(tables: jax.Array, hi: jax.Array, lo: jax.Array, *,
     lo_p, _ = _pad_to(lo, block)
     hit = probe_multi(tables, hi_p, lo_p, fp_bits=fp_bits,
                       n_buckets=n_buckets, stashes=stashes, block=block,
-                      interpret=not _on_tpu(), emulate=_emulate())
+                      interpret=False,
+                      emulate=lowering("probe_multi") == "xla")
     return _unpad(hit, n)
 
 
@@ -280,7 +308,7 @@ def _probe_plan(fp_bits: int, table_shape: tuple, stash_slots: int):
     table_bytes = table_shape[0] * table_shape[1] * 4
     block = autotune_block("probe", table_bytes=table_bytes,
                            stash_slots=stash_slots)
-    return block, _emulate()
+    return block, lowering("probe") == "xla"
 
 
 def probe_dispatch(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
@@ -301,8 +329,6 @@ def probe_dispatch(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
     b = min(block, hi.shape[0])
     hi_p, n = _pad_to(hi, b)
     lo_p, _ = _pad_to(lo, b)
-    # not emul => on TPU (emulation is exactly the off-TPU arm), so the
-    # pallas_call compiles natively.
     hit = probe(table, hi_p, lo_p, fp_bits=fp_bits, n_buckets=n_buckets,
                 stash=stash, block=b, interpret=False)
     return _unpad(hit, n)
@@ -354,8 +380,7 @@ def multi_prober(tables: jax.Array, *, fp_bits: int, n_buckets=None,
                                        n_buckets=n_buckets, stashes=stashes,
                                        use_pallas="never")
         return ref_probe
-    interp = not _on_tpu()
-    emul = _emulate()
+    emul = lowering("probe_multi") == "xla"
 
     def kernel_probe(hi, lo):
         if hi.shape[0] == 0:
@@ -365,7 +390,7 @@ def multi_prober(tables: jax.Array, *, fp_bits: int, n_buckets=None,
         lo_p, _ = _pad_to(lo, b)
         hit = probe_multi(tables, hi_p, lo_p, fp_bits=fp_bits,
                           n_buckets=n_buckets, stashes=stashes, block=b,
-                          interpret=interp, emulate=emul)
+                          interpret=False, emulate=emul)
         return _unpad(hit, n)
 
     return kernel_probe
@@ -435,15 +460,15 @@ def filter_insert(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
         new_table, ok = insert_bulk(table, hi_p, lo_p, fp_bits=fp_bits,
                                     n_buckets=n_buckets, valid=valid_p,
                                     evict_rounds=evict_rounds,
-                                    block=block, interpret=not _on_tpu(),
-                                    emulate=_emulate(), schedule=schedule,
-                                    donate=donate)
+                                    block=block, interpret=False,
+                                    emulate=lowering("insert") == "xla",
+                                    schedule=schedule, donate=donate)
         return new_table, _unpad(ok, n)
     new_table, new_stash, ok = insert_bulk(
         table, hi_p, lo_p, fp_bits=fp_bits, n_buckets=n_buckets,
         valid=valid_p, evict_rounds=evict_rounds, stash=stash, block=block,
-        interpret=not _on_tpu(), emulate=_emulate(), schedule=schedule,
-        donate=donate)
+        interpret=False, emulate=lowering("insert_stash") == "xla",
+        schedule=schedule, donate=donate)
     return new_table, new_stash, _unpad(ok, n)
 
 
@@ -485,8 +510,9 @@ def filter_delete(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
         valid_p, _ = _pad_to(valid, block)   # pads False: never touches table
         new_table, ok = delete_bulk(table, hi_p, lo_p, fp_bits=fp_bits,
                                     n_buckets=n_buckets, valid=valid_p,
-                                    block=block, interpret=not _on_tpu(),
-                                    emulate=_emulate(), donate=donate)
+                                    block=block, interpret=False,
+                                    emulate=lowering("delete") == "xla",
+                                    donate=donate)
         ok = _unpad(ok, n)
     if stash is None:
         return new_table, ok
@@ -560,8 +586,9 @@ def filter_delete_tm(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
     valid_p, _ = _pad_to(valid, block)   # pads False: never touches table
     new_table, ok = delete_bulk(table, hi_p, lo_p, fp_bits=fp_bits,
                                 n_buckets=n_buckets, valid=valid_p,
-                                block=block, interpret=not _on_tpu(),
-                                emulate=_emulate(), donate=donate)
+                                block=block, interpret=False,
+                                emulate=lowering("delete") == "xla",
+                                donate=donate)
     ok = _unpad(ok, n)
     if stash is None:
         return new_table, ok, _delete_tm_plane(ok)
@@ -626,7 +653,7 @@ def adaptive_lookup(table: jax.Array, sels: jax.Array, hi: jax.Array,
                              "probe", table_bytes=table_bytes, block=block,
                              stash_slots=stash_slots),
                          n_keys=hi.shape[0])
-    if not kernel or _emulate():
+    if not kernel or lowering("probe_adaptive") == "xla":
         if n_buckets is None:
             n_buckets = table.shape[0]
         return probe_adaptive_emulated(table, sels, hi.astype(jnp.uint32),
@@ -675,7 +702,7 @@ def adaptive_insert(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
                              evict_rounds=2 * evict_rounds,
                              stash_slots=stash_slots),
                          n_keys=hi.shape[0])
-    emul = (not kernel) or _emulate()
+    emul = (not kernel) or lowering("insert_adaptive") == "xla"
     hi_p, n = _pad_to(hi, block)
     lo_p, _ = _pad_to(lo, block)
     valid_p, _ = _pad_to(valid, block)   # pads False: never touches planes
@@ -683,7 +710,7 @@ def adaptive_insert(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
                                fp_bits=fp_bits, n_buckets=n_buckets,
                                valid=valid_p, evict_rounds=evict_rounds,
                                stash=stash, block=block,
-                               interpret=not _on_tpu(), emulate=emul,
+                               interpret=False, emulate=emul,
                                schedule=schedule, donate=donate)
     return (*out[:-1], _unpad(out[-1], n))
 
@@ -713,14 +740,14 @@ def adaptive_delete(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
                          vmem_bytes=kernel_vmem_bytes(
                              "delete", table_bytes=table_bytes, block=block),
                          n_keys=hi.shape[0])
-    emul = (not kernel) or _emulate()
+    emul = (not kernel) or lowering("delete_adaptive") == "xla"
     hi_p, n = _pad_to(hi, block)
     lo_p, _ = _pad_to(lo, block)
     valid_p, _ = _pad_to(valid, block)   # pads False: never touches planes
     table, sels, khi_t, klo_t, ok = delete_bulk_adaptive(
         table, sels, khi_t, klo_t, hi_p, lo_p, fp_bits=fp_bits,
         n_buckets=n_buckets, valid=valid_p, block=block,
-        interpret=not _on_tpu(), emulate=emul, donate=donate)
+        interpret=False, emulate=emul, donate=donate)
     ok = _unpad(ok, n)
     if stash is None:
         return table, sels, khi_t, klo_t, ok
@@ -813,7 +840,8 @@ def adaptive_delete_tm(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
     table, sels, khi_t, klo_t, ok = delete_bulk_adaptive(
         table, sels, khi_t, klo_t, hi_p, lo_p, fp_bits=fp_bits,
         n_buckets=n_buckets, valid=valid_p, block=block,
-        interpret=not _on_tpu(), emulate=True, donate=donate)
+        interpret=False, emulate=lowering("delete_adaptive") == "xla",
+        donate=donate)
     ok = _unpad(ok, n)
     tm = empty_telemetry()._replace(
         table_deletes=jnp.sum(ok).astype(jnp.uint32))
@@ -872,6 +900,7 @@ __all__ = ["hash_keys", "filter_lookup", "filter_lookup_multi",
            "fingerprint_hash_family", "probe", "probe_multi", "insert_once",
            "insert_bulk", "delete_bulk", "flash_attention",
            "kernel_vmem_bytes", "autotune_block", "VMEM_TABLE_BUDGET",
+           "LOWERING", "lowering",
            "DEFAULT_EVICT_ROUNDS", "DEFAULT_STASH_SLOTS", "make_stash",
            "stash_occupancy", "adaptive_lookup", "adaptive_insert",
            "adaptive_delete", "adaptive_report", "make_sel_plane",

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def health_snapshot(metrics, *, index, requests_served: int) -> dict:
     """Liveness + registry snapshot in one dict — what a /healthz +
@@ -125,6 +127,7 @@ def main():
     ap.add_argument("--telemetry-dir", default=".",
                     help="directory for --telemetry artifacts")
     args = ap.parse_args()
+    enable_compile_cache()
     metrics = tracer = None
     if args.telemetry:
         from repro.obs import MetricsRegistry, TraceRecorder

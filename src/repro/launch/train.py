@@ -51,12 +51,11 @@ def train(arch: str, *, steps: int, batch: int, seq: int, smoke: bool = True,
     from repro.data.pipeline import DedupPipeline, SyntheticDocs
     from repro.distributed.fault import StragglerWatchdog
     from repro.distributed.sharding import ParallelConfig
+    from repro.launch.mesh import make_mesh
     from repro.train.step import make_train_step
 
     if mesh is None:
-        dev = jax.devices()[0]
-        mesh = jax.make_mesh((1, 1), ("data", "model"), devices=[dev],
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+        mesh = make_mesh((1, 1), ("data", "model"))
     parallel = parallel or ParallelConfig()
     cfg, model, tx, params, opt_state, shardings, specs = build_state(
         arch, smoke=smoke, mesh=mesh, parallel=parallel)

@@ -23,10 +23,7 @@ import time
 from contextlib import contextmanager
 from typing import Any, Dict, Iterator, List, Optional
 
-try:  # optional: annotate XLA profiles when jax.profiler is importable
-    from jax.profiler import TraceAnnotation as _JaxAnnotation
-except Exception:  # pragma: no cover - jax always present in this repo
-    _JaxAnnotation = None
+from jax.profiler import TraceAnnotation as _JaxAnnotation
 
 
 class TraceRecorder:
@@ -39,7 +36,7 @@ class TraceRecorder:
         self._clock = clock
         self._t0 = clock()
         self._pid = os.getpid()
-        self._jax = bool(jax_profiler) and _JaxAnnotation is not None
+        self._jax = bool(jax_profiler)
         self._events.append({
             "name": "process_name", "ph": "M", "pid": self._pid, "tid": 0,
             "args": {"name": process_name}})

@@ -413,9 +413,7 @@ class GenerationalFilter:
         # generation (keys hashed once).  jnp: the unrolled per-generation
         # probe loop.  Either way every chunk's hits queue on device and
         # come back in one stacked transfer.
-        fused = (self.ops.resolve_bytes(
-            states[0].table.size * 4,
-            stash_slots=self.config.stash_slots) == "pallas")
+        fused = self.ops.resolve() == "pallas"
         if fused:
             prober = self._fanout_prober(states, stashes)
         hits, ns = [], []

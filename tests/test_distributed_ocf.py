@@ -15,11 +15,8 @@ SCRIPT = textwrap.dedent("""
     from repro.core import distributed as dist, hashing
     from repro.core import filter as jf
 
-    try:
-        mesh = jax.make_mesh((8,), ("data",),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    except AttributeError:  # jax 0.4.x: no AxisType; Auto is the default
-        mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     n_shards, n_buckets = 8, 512
     rng = np.random.RandomState(1)
     keys = rng.randint(0, 2**63, size=4096, dtype=np.int64).astype(np.uint64)

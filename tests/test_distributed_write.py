@@ -63,7 +63,8 @@ ORACLE_SCRIPT = textwrap.dedent("""
     from repro.core import distributed as dist, hashing
     from repro.streaming.oracle import PyStashFilter
 
-    mesh = jax.make_mesh((2,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2,), ("data",))
     NB, BS, FP, ER, SS = 16, 4, 16, 8, 8
     state = dist.make_sharded_state(2, NB, BS, stash_slots=SS)
     oracle = [PyStashFilter(n_buckets=NB, bucket_size=BS, fp_bits=FP,
@@ -154,7 +155,8 @@ CONTENDED_SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.core import distributed as dist, hashing
 
-    mesh = jax.make_mesh((2,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2,), ("data",))
     NB, BS, FP = 256, 4, 16            # 2048 slots total
     N = 1800                           # -> 0.879 load when fully placed
     rng = np.random.RandomState(11)
@@ -221,7 +223,8 @@ OVERFLOW_SCRIPT = textwrap.dedent("""
     import numpy as np
     from repro.core import distributed as dist, hashing
 
-    mesh = jax.make_mesh((2,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2,), ("data",))
     NB, BS, FP = 128, 4, 16
     rng = np.random.RandomState(3)
     keys = rng.randint(1, 2**63, size=256, dtype=np.int64).astype(np.uint64)
@@ -335,7 +338,8 @@ PUMP_SCRIPT = textwrap.dedent("""
     from repro.core import distributed as dist, hashing
     from repro.serving.scheduler import DeferredWritePump
 
-    mesh = jax.make_mesh((2,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2,), ("data",))
     NB, BS, FP = 256, 4, 16
     rng = np.random.RandomState(7)
     keys = np.unique(rng.randint(1, 2**63, size=1024, dtype=np.int64)

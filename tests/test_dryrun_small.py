@@ -20,6 +20,7 @@ SCRIPT = textwrap.dedent("""
     from repro.configs.registry import get_smoke_config
     from repro.distributed.sharding import (ParallelConfig, batch_pspec,
                                             cache_pspec, make_shardings)
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import abstract_cache, abstract_init
     from repro.models.transformer import Transformer
     from repro.optim.adamw import AdamW, AdamWState
@@ -28,8 +29,7 @@ SCRIPT = textwrap.dedent("""
     from repro.roofline.analysis import parse_collectives
 
     arch = %r
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     parallel = ParallelConfig(pod_axis="pod", remat="dots",
                               compress_grads=True)
     cfg = get_smoke_config(arch)

@@ -90,19 +90,18 @@ def test_owner_pair_pow2_hierarchy():
 
 
 def test_largest_mesh_compat():
-    """Satellite regression: largest_mesh must work on jax lines WITHOUT
-    jax.sharding.AxisType (0.4.x) as well as with it — the axis_types
-    kwarg is feature-detected, not assumed."""
+    """Every mesh comes from the one helper, ``launch.mesh.make_mesh``:
+    all axes Auto (jax.make_mesh's default is Explicit, under which the
+    routed ops' gathers fail) and devices in the order given."""
     import jax
+    from jax.sharding import AxisType
     mesh = elastic.largest_mesh(model_parallel=1)
     assert mesh.shape["model"] == 1
     assert mesh.shape["data"] == len(jax.devices())
-    # the helper itself: {} exactly when the enum is absent
-    kw = elastic._axis_type_kwargs(2)
-    if getattr(jax.sharding, "AxisType", None) is None:
-        assert kw == {}
-    else:
-        assert len(kw["axis_types"]) == 2
+    assert mesh.axis_types == (AxisType.Auto,) * 2
+    fm = elastic.filter_mesh(1)
+    assert fm.axis_types == (AxisType.Auto,)
+    assert list(fm.devices.flat) == jax.devices()[:1]
 
 
 # ----------------------------------------------- live split/merge -------

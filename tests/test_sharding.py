@@ -10,12 +10,12 @@ from jax.sharding import PartitionSpec as P
 
 from repro.distributed.sharding import (ParallelConfig, batch_pspec,
                                         cache_pspec, spec_to_pspec)
+from repro.launch.mesh import make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1],
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_mlp_weight_spec(mesh):
@@ -66,8 +66,8 @@ MULTIAXIS = textwrap.dedent("""
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import (ParallelConfig, batch_pspec,
                                             spec_to_pspec)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     pc = ParallelConfig(pod_axis="pod")
     out = {}
     out["w"] = str(spec_to_pspec(("embed", "mlp"), (64, 128), mesh, pc))

@@ -336,7 +336,8 @@ def test_distributed_backend_flag_pallas_parity(rng):
     bit-for-bit.  Single-device mesh — the 8-device routing test lives in
     test_distributed_ocf.py."""
     from repro.core import distributed as dist
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     keys = random_keys(rng, 1024)
     hi, lo = _pair(keys)
     st = jf.make_state(256, 4)
