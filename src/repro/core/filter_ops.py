@@ -492,6 +492,14 @@ class FilterOps:
             table, state.count - jnp.sum(ok, dtype=jnp.int32),
             state.n_buckets), ok, tm
 
+    def delete_table_tm(self, table: jax.Array, hi: jax.Array,
+                        lo: jax.Array, *, n_buckets=None,
+                        valid: Optional[jax.Array] = None, stash=None):
+        """``delete_table`` + telemetry -> (..., ok[N], FilterTelemetry)."""
+        return kops.filter_delete_tm(table, hi, lo, fp_bits=self.fp_bits,
+                                     n_buckets=n_buckets, valid=valid,
+                                     stash=stash)
+
     def lookup_adaptive_tm(self, state, hi: jax.Array, lo: jax.Array,
                            stash: Optional[jax.Array] = None):
         """``lookup_adaptive`` + telemetry -> (hit[N], FilterTelemetry)."""

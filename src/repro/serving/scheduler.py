@@ -463,6 +463,8 @@ class DeferredWritePump:
 LATENCY_BUCKETS_US = (50.0, 100.0, 200.0, 500.0, 1_000.0, 2_000.0,
                       5_000.0, 10_000.0, 25_000.0, 50_000.0, 100_000.0)
 
+_NO_SPAN = contextlib.nullcontext()   # every span of a batcher without tracer
+
 
 @dataclasses.dataclass
 class OpWave:
@@ -474,6 +476,7 @@ class OpWave:
     kind: str
     n: int
     submit_s: float
+    seq: int = 0                  # the batcher's wave sequence number
     done_s: float = 0.0
     deferred_ticks: int = 0       # submit ticks spent parked by admission
     results: Optional[np.ndarray] = None
@@ -594,7 +597,8 @@ class FilterOpBatcher:
         Parked insert waves are retried (FIFO) before the new wave, so
         admission never reorders writes relative to each other."""
         keys = np.ascontiguousarray(np.asarray(keys, np.uint64))
-        wave = OpWave(kind=kind, n=int(keys.size), submit_s=self._clock())
+        wave = OpWave(kind=kind, n=int(keys.size), submit_s=self._clock(),
+                      seq=self.stats.waves)
         self.stats.waves += 1
         self.stats.ops += wave.n
         self._retry_deferred()
@@ -656,19 +660,19 @@ class FilterOpBatcher:
             wave, keys = self._deferred.popleft()
             self._launch(wave, keys)
 
-    def _span(self, name: str, **args):
-        """Trace span (or no-op) — host-side only, never a device sync."""
+    def _span(self, name: str, wave: OpWave):
+        """Trace span of one step of ``wave``, host-side only and never a
+        device sync.  With no tracer: the shared no-op, with no clock read
+        and no arguments built."""
         if self.tracer is None:
-            return contextlib.nullcontext()
-        return self.tracer.span(name, **args)
+            return _NO_SPAN
+        return self.tracer.span(name, wave=wave.seq, kind=wave.kind,
+                                n=wave.n)
 
     def _launch(self, wave: OpWave, keys: np.ndarray) -> None:
         prev = self._inflight
-        with self._span("wave_dispatch", kind=wave.kind, n=wave.n):
-            if self.telemetry:
-                self._dispatch_tm(wave, keys)
-            else:
-                self._dispatch(wave, keys)  # overlaps prev's device exec
+        with self._span("wave_dispatch", wave):
+            self._dispatch(wave, keys)  # overlaps prev's device exec
         self._inflight = wave
         if prev is not None:
             self._harvest(prev)
@@ -677,163 +681,121 @@ class FilterOpBatcher:
 
     def _prepare(self, wave: OpWave, keys: np.ndarray):
         """Host-side wave prep: dedup (lookups), pad, hash split, upload."""
-        if wave.kind == "lookup" and self.dedupe_lookups:
-            keys, wave._inverse = dedupe_keys(keys)
-            if wave._inverse is not None:
-                self.stats.deduped_lanes += wave.n - keys.size
-        n = keys.size
-        assert n <= self.wave_slots, (n, self.wave_slots)
-        wave._n_probe = n
-        padded = np.zeros(self.wave_slots, np.uint64)
-        padded[:n] = keys
-        hi, lo = hashing.key_to_u32_pair_np(padded)
-        valid = np.zeros(self.wave_slots, bool)
-        valid[:n] = True
-        return jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(valid)
+        with self._span("wave_prepare", wave):
+            if wave.kind == "lookup" and self.dedupe_lookups:
+                keys, wave._inverse = dedupe_keys(keys)
+                if wave._inverse is not None:
+                    self.stats.deduped_lanes += wave.n - keys.size
+            n = keys.size
+            assert n <= self.wave_slots, (n, self.wave_slots)
+            wave._n_probe = n
+            padded = np.zeros(self.wave_slots, np.uint64)
+            padded[:n] = keys
+            hi, lo = hashing.key_to_u32_pair_np(padded)
+            valid = np.zeros(self.wave_slots, bool)
+            valid[:n] = True
+        with self._span("wave_upload", wave):
+            return jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(valid)
+
+    def _op(self, wave: OpWave, entry: str, *args, **kwargs):
+        """One ``FilterOps`` call: ``entry``, or its ``*_tm`` twin when
+        telemetry is on -> (the entry's outputs, ``FilterTelemetry`` or
+        None).  The twin's telemetry rides ``wave._device`` so the harvest
+        materializes counters and results in the SAME single
+        ``block_until_ready`` — telemetry adds no extra sync points."""
+        with self._span("filterops." + entry, wave):
+            if not self.telemetry:
+                return getattr(self.ops, entry)(*args, **kwargs), None
+            *out, tm = getattr(self.ops, entry + "_tm")(*args, **kwargs)
+        return (out[0] if len(out) == 1 else tuple(out)), tm
 
     def _dispatch(self, wave: OpWave, keys: np.ndarray) -> None:
-        """Queue the wave's device work; grab (results, count, occupancy)
-        refs for the harvest.  No host sync on this path."""
+        """Queue the wave's device work; grab (results, count, occupancy
+        [, telemetry]) refs for the harvest.  No host sync on this path."""
         hi, lo, valid = self._prepare(wave, keys)
-        ops, state, stash = self.ops, self.state, self.stash
+        state, stash = self.state, self.stash
+        table_delete = False
         if wave.kind == "lookup":
             if self._adaptive:
-                res = ops.lookup_adaptive(state, hi, lo, stash=stash)
+                res, tm = self._op(wave, "lookup_adaptive", state, hi, lo,
+                                   stash=stash)
             elif stash is not None:
-                res = ops.lookup_with_stash(state, stash, hi, lo)
+                res, tm = self._op(wave, "lookup_with_stash", state, stash,
+                                   hi, lo)
             else:
-                res = ops.lookup(state, hi, lo)
+                res, tm = self._op(wave, "lookup", state, hi, lo)
         elif wave.kind == "insert":
             if self._adaptive and stash is not None:
-                self.state, self.stash, res = ops.insert_adaptive(
-                    state, hi, lo, valid=valid, stash=stash)
+                (self.state, self.stash, res), tm = self._op(
+                    wave, "insert_adaptive", state, hi, lo, valid=valid,
+                    stash=stash)
             elif self._adaptive:
-                self.state, res = ops.insert_adaptive(state, hi, lo,
-                                                      valid=valid)
+                (self.state, res), tm = self._op(
+                    wave, "insert_adaptive", state, hi, lo, valid=valid)
             elif stash is not None:
-                self.state, self.stash, res = ops.insert_spill(
-                    state, stash, hi, lo, valid=valid)
+                (self.state, self.stash, res), tm = self._op(
+                    wave, "insert_spill", state, stash, hi, lo, valid=valid)
             else:
-                self.state, res = ops.insert(state, hi, lo, valid=valid)
+                (self.state, res), tm = self._op(wave, "insert", state, hi,
+                                                 lo, valid=valid)
         elif wave.kind == "delete":
             if self._adaptive:
-                out = ops.delete_adaptive(state, hi, lo, valid=valid,
-                                          stash=stash)
+                out, tm = self._op(wave, "delete_adaptive", state, hi, lo,
+                                   valid=valid, stash=stash)
                 if stash is not None:
                     self.state, self.stash, res = out
                 else:
                     self.state, res = out
             elif stash is not None:
-                table, new_stash, res = ops.delete_table(
-                    state.table, hi, lo, n_buckets=state.n_buckets,
-                    valid=valid, stash=stash)
+                (table, self.stash, res), tm = self._op(
+                    wave, "delete_table", state.table, hi, lo,
+                    n_buckets=state.n_buckets, valid=valid, stash=stash)
+                self.state = state._replace(table=table)
+                table_delete = True
+            else:
+                (self.state, res), tm = self._op(wave, "delete", state, hi,
+                                                 lo, valid=valid)
+        elif wave.kind == "report":
+            if not self._adaptive:
+                raise ValueError("'report' waves need an AdaptiveState")
+            (self.state, res, _resident), tm = self._op(
+                wave, "report_false_positive", state, hi, lo, valid=valid)
+        else:
+            raise ValueError(f"unknown wave kind {wave.kind!r}")
+        with self._span("wave_occupancy", wave):
+            if table_delete:
                 # ok counts table AND stash clears; count tracks the table
                 stash_cleared = (kops.stash_occupancy(stash)
-                                 - kops.stash_occupancy(new_stash))
-                count = (state.count - jnp.sum(res, dtype=jnp.int32)
-                         + stash_cleared)
-                self.state = jfilter.FilterState(table, count,
-                                                 state.n_buckets)
-                self.stash = new_stash
-            else:
-                self.state, res = ops.delete(state, hi, lo, valid=valid)
-        elif wave.kind == "report":
-            if not self._adaptive:
-                raise ValueError("'report' waves need an AdaptiveState")
-            self.state, adapted, _resident = ops.report_false_positive(
-                state, hi, lo, valid=valid)
-            res = adapted
-        else:
-            raise ValueError(f"unknown wave kind {wave.kind!r}")
-        occ = (kops.stash_occupancy(self.stash)
-               if self.stash is not None else jnp.int32(0))
-        wave._device = (res, self.state.count, occ)
-
-    def _dispatch_tm(self, wave: OpWave, keys: np.ndarray) -> None:
-        """Telemetry twin of ``_dispatch``: the same wave semantics through
-        the ``FilterOps`` ``*_tm`` entry points.  The per-wave
-        ``FilterTelemetry`` rides ``wave._device`` so the harvest
-        materializes counters and results in the SAME single
-        ``block_until_ready`` — telemetry adds no extra sync points."""
-        hi, lo, valid = self._prepare(wave, keys)
-        ops, state, stash = self.ops, self.state, self.stash
-        if wave.kind == "lookup":
-            if self._adaptive:
-                res, tm = ops.lookup_adaptive_tm(state, hi, lo, stash=stash)
-            elif stash is not None:
-                res, tm = ops.lookup_with_stash_tm(state, stash, hi, lo)
-            else:
-                res, tm = ops.lookup_tm(state, hi, lo)
-        elif wave.kind == "insert":
-            if self._adaptive and stash is not None:
-                self.state, self.stash, res, tm = ops.insert_adaptive_tm(
-                    state, hi, lo, valid=valid, stash=stash)
-            elif self._adaptive:
-                self.state, res, tm = ops.insert_adaptive_tm(
-                    state, hi, lo, valid=valid)
-            elif stash is not None:
-                self.state, self.stash, res, tm = ops.insert_spill_tm(
-                    state, stash, hi, lo, valid=valid)
-            else:
-                self.state, res, tm = ops.insert_tm(state, hi, lo,
-                                                    valid=valid)
-        elif wave.kind == "delete":
-            if self._adaptive:
-                out = ops.delete_adaptive_tm(state, hi, lo, valid=valid,
-                                             stash=stash)
-                if stash is not None:
-                    self.state, self.stash, res, tm = out
-                else:
-                    self.state, res, tm = out
-            elif stash is not None:
-                table, new_stash, res, tm = kops.filter_delete_tm(
-                    state.table, hi, lo, fp_bits=ops.fp_bits,
-                    n_buckets=state.n_buckets, valid=valid, stash=stash)
-                # same count convention as the telemetry-off arm: ok counts
-                # table AND stash clears; count tracks the table
-                stash_cleared = (kops.stash_occupancy(stash)
-                                 - kops.stash_occupancy(new_stash))
-                count = (state.count - jnp.sum(res, dtype=jnp.int32)
-                         + stash_cleared)
-                self.state = jfilter.FilterState(table, count,
-                                                 state.n_buckets)
-                self.stash = new_stash
-            else:
-                self.state, res, tm = ops.delete_tm(state, hi, lo,
-                                                    valid=valid)
-        elif wave.kind == "report":
-            if not self._adaptive:
-                raise ValueError("'report' waves need an AdaptiveState")
-            self.state, adapted, _resident, tm = \
-                ops.report_false_positive_tm(state, hi, lo, valid=valid)
-            res = adapted
-        else:
-            raise ValueError(f"unknown wave kind {wave.kind!r}")
-        occ = (kops.stash_occupancy(self.stash)
-               if self.stash is not None else jnp.int32(0))
-        wave._device = (res, self.state.count, occ, tm)
+                                 - kops.stash_occupancy(self.stash))
+                self.state = self.state._replace(
+                    count=state.count - jnp.sum(res, dtype=jnp.int32)
+                    + stash_cleared)
+            occ = (kops.stash_occupancy(self.stash)
+                   if self.stash is not None else jnp.int32(0))
+        wave._device = (res, self.state.count, occ) + (
+            (tm,) if self.telemetry else ())
 
     def _harvest(self, wave: OpWave) -> None:
         """The ONLY sync point: materialize one wave's device refs."""
-        with self._span("wave_harvest", kind=wave.kind, n=wave.n):
-            dev = jax.block_until_ready(wave._device)
-        if len(dev) == 4:
-            res, count, occ, tm = dev
-        else:
-            (res, count, occ), tm = dev, None
-        out = np.asarray(res)[:wave._n_probe]
-        wave.results = out[wave._inverse] if wave._inverse is not None \
-            else out
+        with self._span("wave_harvest", wave):
+            with self._span("harvest_wait", wave):
+                dev = jax.block_until_ready(wave._device)
+            with self._span("harvest_fetch", wave):
+                res, count, occ, *tm = dev
+                out = np.asarray(res)[:wave._n_probe]
+                wave.results = out[wave._inverse] \
+                    if wave._inverse is not None else out
+                self._fill_snapshot = (
+                    float(count) / max(1, self.capacity),
+                    float(occ) / self.stash_slots if self.stash_slots
+                    else 0.0)
         wave._device = ()
         wave.done_s = self._clock()
-        self._fill_snapshot = (
-            float(count) / max(1, self.capacity),
-            float(occ) / self.stash_slots if self.stash_slots else 0.0)
         self.stats.harvests += 1
         if wave is self._inflight:
             self._inflight = None
         if self.metrics is not None:
-            self._record_wave(wave, tm)
+            self._record_wave(wave, tm[0] if tm else None)
 
     # ------------------------------------------------------ observability --
 

@@ -353,7 +353,6 @@ def test_trace_recorder_perfetto_shape(tmp_path):
         with tr.span("inner"):
             pass
     tr.instant("mark")
-    tr.counter("fill", table=0.5)
     path = tmp_path / "trace.json"
     tr.save(str(path))
     doc = json.loads(path.read_text())
@@ -367,6 +366,63 @@ def test_trace_recorder_perfetto_shape(tmp_path):
     assert outer["ts"] <= inner["ts"]
     assert outer["ts"] + outer["dur"] >= inner["ts"] + inner["dur"]
     assert outer["args"]["kind"] == "insert"
+
+
+def _profiled(tmp_path, body):
+    """Run ``body`` under the JAX profiler -> {event name: [stats]} of the
+    host events recorded."""
+    import glob
+
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    seen = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                for e in line.events:
+                    seen.setdefault(e.name, []).append(dict(e.stats))
+    return seen
+
+
+@pytest.mark.tier1
+def test_trace_recorder_profiler_mode_keeps_no_events(tmp_path):
+    """In profiler mode a span is one annotation, named plainly, with its
+    arguments as stats; the recorder itself keeps nothing."""
+    tr = TraceRecorder(jax_profiler=True)
+
+    def body():
+        with tr.span("wave_dispatch", wave=3, kind="lookup", n=1):
+            with tr.span("wave_upload", wave=3, kind="lookup", n=1):
+                pass
+        tr.instant("recovered", event="shard")
+
+    seen = _profiled(tmp_path, body)
+    assert tr.events == []
+    with pytest.raises(ValueError):
+        tr.save(str(tmp_path / "trace.json"))
+    assert {"wave_dispatch", "wave_upload", "recovered"} <= set(seen)
+    stats = seen["wave_dispatch"][0]
+    assert (stats["wave"], stats["kind"], stats["n"]) == (3, "lookup", 1)
+
+
+@pytest.mark.tier1
+def test_trace_recorder_records_gc_pauses(tmp_path):
+    import gc
+
+    TraceRecorder(jax_profiler=True)
+    TraceRecorder(jax_profiler=True)
+    from repro.obs.trace import _gc_span
+    assert gc.callbacks.count(_gc_span) == 1     # hooked once per process
+    seen = _profiled(tmp_path, lambda: gc.collect(2))
+    assert {"generation": 2} in seen["gc_pause"]
 
 
 # ------------------------------------------------- merge properties -----
