@@ -466,6 +466,24 @@ LATENCY_BUCKETS_US = (50.0, 100.0, 200.0, 500.0, 1_000.0, 2_000.0,
 _NO_SPAN = contextlib.nullcontext()   # every span of a batcher without tracer
 
 
+@jax.jit
+def _fill_read(count, stash):
+    """A fill snapshot, (count, stash occupancy), packed as int32[2]: one
+    program to compute it and one copy to bring it to the host."""
+    occ = jnp.int32(0) if stash is None else kops.stash_occupancy(stash)
+    return jnp.stack([count, occ])
+
+
+@jax.jit
+def _table_delete_fill(count, stash_before, stash, ok):
+    """A table delete's count and its fill snapshot, in one program.
+    ``ok`` counts table AND stash clears; count tracks the table."""
+    occ = kops.stash_occupancy(stash)
+    count = (count - jnp.sum(ok, dtype=jnp.int32)
+             + (kops.stash_occupancy(stash_before) - occ))
+    return count, jnp.stack([count, occ])
+
+
 @dataclasses.dataclass
 class OpWave:
     """One submitted wave and its timing: the recorder's unit of sample.
@@ -500,6 +518,7 @@ class BatcherStats:
     held_ticks: int = 0           # drain attempts the gate held the queue
     shed_ops: int = 0             # lanes still parked when drain gave up
     deduped_lanes: int = 0        # lookup lanes collapsed by dedup
+    fill_reads: int = 0           # waves that read a new fill snapshot
 
 
 class FilterOpBatcher:
@@ -527,9 +546,12 @@ class FilterOpBatcher:
     tripped inserts park in a deferred queue that retries on later submits
     / ``drain()``.  Deletes and lookups bypass the gate (deletes *relieve*
     congestion; probes don't add occupancy).  ``fills()`` reports the
-    occupancy snapshot taken at the last harvest — polling it costs no
+    occupancy snapshot as of the last harvest — polling it costs no
     device sync, so the controller can gate every wave without stalling
-    the pipeline.
+    the pipeline.  The snapshot is read only on waves whose op replaced
+    the state's count or the stash (one program, one copy at harvest);
+    any other wave (every lookup) left both as they were, so the snapshot
+    the last such wave read still holds and the wave reads none.
 
     ``double_buffer="auto"`` (the default) resolves per host: overlap
     only pays when device work and host prep run on different silicon, so
@@ -578,8 +600,7 @@ class FilterOpBatcher:
         self._adaptive = hasattr(state, "sels")
         self.capacity = int(state.n_buckets) * state.table.shape[1]
         self.stash_slots = 0 if stash is None else int(stash.shape[1])
-        self._fill_snapshot = (
-            float(jax.device_get(state.count)) / max(1, self.capacity), 0.0)
+        self._take_fills(_fill_read(state.count, stash))
         if admission is not None and not hasattr(admission, "admit"):
             from repro.streaming.admission import AdmissionController
             admission = AdmissionController(filt=self, config=admission,
@@ -649,6 +670,13 @@ class FilterOpBatcher:
         ``GenerationalFilter.fills()`` duck, sync-free by construction."""
         return self._fill_snapshot
 
+    def _take_fills(self, fill) -> None:
+        """Set the snapshot from a packed (count, stash occupancy)."""
+        count, occ = np.asarray(fill).tolist()
+        self._fill_snapshot = (
+            float(count) / max(1, self.capacity),
+            float(occ) / self.stash_slots if self.stash_slots else 0.0)
+
     # --------------------------------------------------------- pipeline --
 
     def _retry_deferred(self) -> None:
@@ -710,8 +738,9 @@ class FilterOpBatcher:
         return (out[0] if len(out) == 1 else tuple(out)), tm
 
     def _dispatch(self, wave: OpWave, keys: np.ndarray) -> None:
-        """Queue the wave's device work; grab (results, count, occupancy
-        [, telemetry]) refs for the harvest.  No host sync on this path."""
+        """Queue the wave's device work; grab (results, fill snapshot or
+        None [, telemetry]) refs for the harvest.  No host sync on this
+        path."""
         hi, lo, valid = self._prepare(wave, keys)
         state, stash = self.state, self.stash
         table_delete = False
@@ -763,17 +792,20 @@ class FilterOpBatcher:
         else:
             raise ValueError(f"unknown wave kind {wave.kind!r}")
         with self._span("wave_occupancy", wave):
+            fill = None
             if table_delete:
-                # ok counts table AND stash clears; count tracks the table
-                stash_cleared = (kops.stash_occupancy(stash)
-                                 - kops.stash_occupancy(self.stash))
-                self.state = self.state._replace(
-                    count=state.count - jnp.sum(res, dtype=jnp.int32)
-                    + stash_cleared)
-            occ = (kops.stash_occupancy(self.stash)
-                   if self.stash is not None else jnp.int32(0))
-        wave._device = (res, self.state.count, occ) + (
-            (tm,) if self.telemetry else ())
+                count, fill = _table_delete_fill(state.count, stash,
+                                                 self.stash, res)
+                self.state = self.state._replace(count=count)
+            elif (self.state.count is not state.count
+                  or self.stash is not stash):
+                fill = _fill_read(self.state.count, self.stash)
+            if fill is not None:
+                self.stats.fill_reads += 1
+                if self.metrics is not None:
+                    self.metrics.counter("filter_fill_reads").inc(
+                        kind=wave.kind)
+        wave._device = (res, fill) + ((tm,) if self.telemetry else ())
 
     def _harvest(self, wave: OpWave) -> None:
         """The ONLY sync point: materialize one wave's device refs."""
@@ -781,14 +813,12 @@ class FilterOpBatcher:
             with self._span("harvest_wait", wave):
                 dev = jax.block_until_ready(wave._device)
             with self._span("harvest_fetch", wave):
-                res, count, occ, *tm = dev
+                res, fill, *tm = dev
                 out = np.asarray(res)[:wave._n_probe]
                 wave.results = out[wave._inverse] \
                     if wave._inverse is not None else out
-                self._fill_snapshot = (
-                    float(count) / max(1, self.capacity),
-                    float(occ) / self.stash_slots if self.stash_slots
-                    else 0.0)
+                if fill is not None:
+                    self._take_fills(fill)
         wave._device = ()
         wave.done_s = self._clock()
         self.stats.harvests += 1

@@ -178,6 +178,61 @@ def test_adaptive_telemetry_parity():
 
 
 @pytest.mark.tier1
+@pytest.mark.parametrize("adaptive", [False, True])
+def test_lookup_waves_read_no_fill_snapshot(monkeypatch, adaptive):
+    """A run of lookup waves computes no fill snapshot (no occupancy
+    program, no eager ``stash_occupancy``) and fetches none at harvest;
+    ``fill_reads`` and its registry counter count the mutating waves."""
+    from repro.adaptive.state import make_adaptive_state
+    from repro.serving import scheduler
+
+    calls = []
+
+    def spy(name, orig):
+        def wrapped(*a, **k):
+            calls.append(name)
+            return orig(*a, **k)
+        return wrapped
+
+    for name in ("_fill_read", "_table_delete_fill"):
+        monkeypatch.setattr(scheduler, name,
+                            spy(name, getattr(scheduler, name)))
+    monkeypatch.setattr(kops_mod, "stash_occupancy",
+                        spy("stash_occupancy", kops_mod.stash_occupancy))
+    monkeypatch.setattr(FilterOpBatcher, "_take_fills",
+                        spy("_take_fills", FilterOpBatcher._take_fills))
+
+    m = MetricsRegistry()
+    state = make_adaptive_state(256) if adaptive else jfilter.make_state(256)
+    b = FilterOpBatcher(FilterOps(backend="pallas", evict_rounds=16), state,
+                        stash=kops.make_stash(16), wave_slots=WS,
+                        double_buffer=True, metrics=m)
+    rng = np.random.RandomState(2)
+    keys = rng.randint(1, 2 ** 62, size=WS, dtype=np.int64).astype(np.uint64)
+    b.submit("insert", keys)
+    b.flush()
+    calls.clear()
+    for i in range(5):
+        b.submit("lookup", np.roll(keys, i))
+    b.flush()
+    assert calls == []
+    assert b.stats.fill_reads == 1
+
+    b.submit("delete", keys[:WS // 2])
+    b.submit("lookup", keys)
+    b.submit("insert", keys[:WS // 2])
+    b.flush()
+    read = "_fill_read" if adaptive else "_table_delete_fill"
+    assert [c for c in calls if c != "stash_occupancy"] == [
+        read, "_take_fills", "_fill_read", "_take_fills"]
+    assert b.stats.fill_reads == 3 and b.stats.waves == 9
+    snap = m.snapshot()
+    assert snap['filter_fill_reads{kind="insert"}'] == 2
+    assert snap['filter_fill_reads{kind="delete"}'] == 1
+    assert 'filter_fill_reads{kind="lookup"}' not in snap
+
+
+@pytest.mark.tier1
 def test_backpressure_trip_shed_readmit_sequence():
     """The engine's admit -> defer -> shed -> admit walk over registry
     metrics, exactly as the admission arm publishes them."""

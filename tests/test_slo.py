@@ -277,6 +277,63 @@ def test_double_buffer_auto_resolves_per_host(monkeypatch):
     assert not mk(double_buffer=False).double_buffer
 
 
+# ------------------------------------------------- fill snapshot --------
+
+
+def _small_stack(family):
+    """A 16 x 4 filter with an 8-slot stash, static or adaptive."""
+    ops = FilterOps(fp_bits=12, backend="pallas", evict_rounds=8)
+    state = (make_adaptive_state(16, 4) if family == "adaptive"
+             else jfilter.make_state(16, 4))
+    return ops, state, kops.make_stash(8)
+
+
+def _device_fills(state, stash):
+    """(table fill, stash fill) read straight from the device arrays."""
+    capacity = int(state.n_buckets) * state.table.shape[1]
+    return (int(state.count) / capacity,
+            int(kops.stash_occupancy(stash)) / stash.shape[1])
+
+
+@pytest.mark.parametrize("family", ["static", "adaptive"])
+@pytest.mark.parametrize("double_buffer", [True, False])
+def test_fills_after_every_harvest_match_the_device(family, double_buffer):
+    """``fills()`` is the device's (count, stash occupancy) as of the last
+    harvested wave, whether or not that wave read a snapshot: the batcher
+    is built over a pre-filled stash, its stream opens with lookups, and
+    mixes inserts, table deletes and (adaptive) reports."""
+    ops, state, stash = _small_stack(family)
+    rng = np.random.RandomState(5)
+    fill = FilterOpBatcher(ops, state, stash=stash, wave_slots=WS,
+                           double_buffer=False)
+    live = rng.randint(1, 2 ** 62, size=WS, dtype=np.int64).astype(np.uint64)
+    fill.submit("insert", live)
+    assert int(kops.stash_occupancy(fill.stash)) > 0, \
+        "the stream must start over a pre-filled stash"
+
+    b = FilterOpBatcher(ops, fill.state, stash=fill.stash, wave_slots=WS,
+                        double_buffer=double_buffer)
+    assert b.fills() == _device_fills(b.state, b.stash)
+    kinds = ["lookup", "lookup", "delete", "lookup", "insert", "lookup",
+             "report", "delete", "lookup", "insert", "report", "lookup"]
+    if family == "static":
+        kinds = [k for k in kinds if k != "report"]
+    waves, after = [], []
+    for kind in kinds:
+        fresh = rng.randint(1, 2 ** 62, size=WS // 2,
+                            dtype=np.int64).astype(np.uint64)
+        keys = (live[rng.permutation(WS)[:WS // 2]] if kind == "delete"
+                else np.concatenate([live[:WS // 4], fresh[:WS // 4]])
+                if kind != "insert" else fresh)
+        waves.append(b.submit(kind, keys))
+        after.append(_device_fills(b.state, b.stash))
+        done = [i for i, w in enumerate(waves) if w.results is not None]
+        if done:
+            assert b.fills() == after[done[-1]], (kind, len(waves))
+    b.flush()
+    assert b.fills() == after[-1] == _device_fills(b.state, b.stash)
+
+
 # ------------------------------------------------- recorder & reports ---
 
 
