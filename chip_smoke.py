@@ -15,11 +15,12 @@ present, a false-positive rate on absent keys within 4 x 2b / 2^f (the
 bound ``scripts/bench_gate.py`` applies), and table plus stash occupancy
 equal to acknowledged inserts minus acknowledged deletes.
 
-Four chips: routed ``distributed_insert`` / ``distributed_lookup`` /
-``distributed_delete`` over a 4-shard state (2^24 buckets per shard, each
-with a stash) placed one shard per device and loaded to the same 0.85,
-against the same exact reference, with per-shard occupancy matched to the
-keys each shard owns.  Deferred lanes are resubmitted until none remain.
+Four chips: routed inserts, deletes and lookups through the served entry
+point of a sharded filter (``serving.scheduler.DeferredWritePump``) over a
+4-shard state (2^24 buckets per shard, each with a stash) placed one shard
+per device and loaded to the same 0.85, against the same exact reference,
+with per-shard occupancy matched to the keys each shard owns.  The pump
+replays deferred lanes until none remain.
 
 Without a TPU the script exits non-zero and prints no verdict.  The last
 stdout line of a passing run is
@@ -60,8 +61,8 @@ SCENARIOS = ("uniform", "zipfian", "delete_heavy")
 SWEEP_BATCH = 1 << 20         # lookup batch of the check sweeps
 SHARD_BUCKETS = 1 << 24       # four chips: 256 MiB per shard
 # Routed-write capacity per (source, owner) pair, in fair shares: at 1.0 the
-# router defers some lanes of almost every call, so the smoke drives the
-# resubmit path as well as the shard-local writes.
+# router defers some lanes of almost every write call, so the smoke drives
+# the resubmit path as well as the shard-local writes.
 ROUTE_CAPACITY = 1.0
 
 
@@ -110,11 +111,9 @@ def absent_keys(seed: int, n: int) -> np.ndarray:
     return loaded_keys(seed, _ABSENT_BASE, n)
 
 
-def _split(keys: np.ndarray, sharding=None):
+def _split(keys: np.ndarray):
     hi, lo = hashing.key_to_u32_pair_np(keys)
-    if sharding is None:
-        return jnp.asarray(hi), jnp.asarray(lo)
-    return jax.device_put(hi, sharding), jax.device_put(lo, sharding)
+    return jnp.asarray(hi), jnp.asarray(lo)
 
 
 # -------------------------------------------------------- one chip: load --
@@ -295,15 +294,16 @@ def sharded_phase(*, n_shards: int, n_buckets: int, seed: int, batch: int,
     """Routed writes and lookups over ``n_shards`` devices, checked.
 
     Inserts loaded keys until the shards reach ``target_load``, then
-    deletes the first quarter of them.  ``batch`` lanes per call (a
-    multiple of ``n_shards``).  Lanes the router defers (more than
-    ``ROUTE_CAPACITY`` fair shares for one owner) are resubmitted at the
-    head of the next call, as a write pump does, until none remain.
+    deletes the first quarter of them, all through the served entry point
+    of a sharded filter (``DeferredWritePump.call``), ``batch`` keys per
+    call.  Lanes the router defers (more than ``ROUTE_CAPACITY`` fair
+    shares for one owner) are parked and replayed by the pump.
     """
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core import distributed as dist
     from repro.distributed import elastic
+    from repro.serving.scheduler import DeferredWritePump
 
     mesh = elastic.filter_mesh(n_shards)
     lanes = NamedSharding(mesh, P("data"))
@@ -315,66 +315,38 @@ def sharded_phase(*, n_shards: int, n_buckets: int, seed: int, batch: int,
     _check_one_shard_per_device(state.stashes, mesh)
     log(f"sharded: {n_shards} shards x {n_buckets} buckets on "
         f"{[d.id for d in mesh.devices.flat]}, one shard per device")
+    pump = DeferredWritePump(mesh, "data", state, fp_bits=FP_BITS,
+                             capacity_factor=ROUTE_CAPACITY, backend=backend)
 
-    def write(op, keys):
-        """Submit ``keys`` until no lane is deferred
-        -> (acknowledged mask, lanes resubmitted)."""
-        nonlocal state
-        fn = dist.distributed_insert if op == "insert" else \
-            dist.distributed_delete
-        acked = np.zeros(keys.size, bool)
-        pend = np.zeros(0, np.int64)            # deferred: resubmit first
-        nxt = resubmitted = 0
-        while pend.size or nxt < keys.size:
-            take = min(batch - pend.size, keys.size - nxt)
-            idx = np.concatenate([pend, np.arange(nxt, nxt + take)])
-            resubmitted += pend.size
-            nxt += take
-            padded = np.zeros(batch, np.uint64)
-            padded[:idx.size] = keys[idx]
-            valid = np.zeros(batch, bool)
-            valid[:idx.size] = True
-            hi, lo = _split(padded, lanes)
-            state, ok, deferred, _ = fn(
-                mesh, "data", state, hi, lo, fp_bits=FP_BITS,
-                capacity_factor=ROUTE_CAPACITY, backend=backend,
-                donate=True, valid=jax.device_put(valid, lanes))
-            acked[idx[np.asarray(ok)[:idx.size]]] = True
-            pend = idx[np.asarray(deferred)[:idx.size]]
-        return acked, resubmitted
+    def serve(kind, keys):
+        """``keys`` in calls of ``batch`` -> answers, every write applied."""
+        calls = [pump.call(kind, keys[s:s + batch])
+                 for s in range(0, keys.size, batch)]
+        pump.run_until_drained()
+        return (np.concatenate([c.results for c in calls]) if calls
+                else np.zeros(0, bool))
 
     def lookup(keys):
-        # A lookup has no valid mask: pad with absent keys, which route
-        # like any other, rather than key 0, which would crowd one shard.
-        pad = keys_at(seed, np.arange(_PAD_BASE, _PAD_BASE + batch))
-        hits, overflow = [], 0
-        for s in range(0, keys.size, batch):
-            part = keys[s:s + batch]
-            padded = pad.copy()
-            padded[:part.size] = part
-            h, ov = dist.distributed_lookup(
-                mesh, "data", state, *_split(padded, lanes),
-                fp_bits=FP_BITS, backend=backend)
-            hits.append(np.asarray(h)[:part.size])
-            overflow += int(np.asarray(ov).sum())
-        return np.concatenate(hits), overflow
+        # Pad the last call with absent keys, so that every call has the
+        # same shape, rather than key 0, which would crowd one shard.
+        padded = keys_at(seed, np.arange(_PAD_BASE, _PAD_BASE
+                                         + (-keys.size) % batch))
+        return serve("lookup", np.concatenate([keys, padded]))[:keys.size]
 
     n_keys = batch * int(target_load * n_shards * n_buckets * BUCKET_SIZE
                          // batch)
     keys = loaded_keys(seed, 0, n_keys)
     t0 = time.perf_counter()
-    ins_ok, ins_resub = write("insert", keys)
+    ins_ok = serve("insert", keys)
     insert_s = time.perf_counter() - t0
-    stash_occ = np.asarray(jnp.sum(state.stashes[:, 0, :] != 0, axis=1))
+    stash_occ = np.asarray(jnp.sum(pump.state.stashes[:, 0, :] != 0, axis=1))
     victims = np.flatnonzero(ins_ok[:n_keys // 4])
-    del_ok, del_resub = write("delete", keys[victims])
+    del_ok = serve("delete", keys[victims])
     present = ins_ok.copy()
     present[victims[del_ok]] = False
-    hits, ov_present = lookup(keys[present])
-    fn = int((~hits).sum())
-    absent = absent_keys(seed, n_absent)
-    fhits, ov_absent = lookup(absent)
-    fp = int(fhits.sum())
+    fn = int((~lookup(keys[present])).sum())
+    fp = int(lookup(absent_keys(seed, n_absent)).sum())
+    state = pump.state
     occ = np.asarray(jnp.sum(state.tables != 0, axis=(1, 2))
                      + jnp.sum(state.stashes[:, 0, :] != 0, axis=1))
     load = n_keys / (n_shards * n_buckets * BUCKET_SIZE)
@@ -385,10 +357,10 @@ def sharded_phase(*, n_shards: int, n_buckets: int, seed: int, batch: int,
            "inserts_acked": int(ins_ok.sum()),
            "deletes_acked": int(del_ok.sum()), "insert_s": insert_s,
            "stash_after_insert": stash_occ.tolist(),
-           "resubmitted_lanes": int(ins_resub + del_resub),
+           "resubmitted_lanes": pump.stats.resubmitted,
            "false_negatives": fn, "false_positives": fp,
            "fpr": fp / max(n_absent, 1), "fpr_bound": fpr_bound(),
-           "lookup_overflow": ov_present + ov_absent,
+           "lookup_overflow": pump.stats.lanes["overflowed", "lookup"],
            "shard_occupancy": occ.tolist(), "owned": owned.tolist()}
     log(f"sharded: {out['inserts_acked']} of {n_keys} inserts (load "
         f"{load:.3f}) and {out['deletes_acked']} deletes acknowledged, "

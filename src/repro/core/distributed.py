@@ -184,8 +184,11 @@ def _lookup_fn(mesh: Mesh, axis: str, n_shards: int, cap: int, fp_bits: int,
     per static configuration, not one per call."""
     fops = FilterOps(fp_bits=fp_bits, backend=backend)
 
-    def shard_fn(tables, stashes, hi, lo):
+    # Named for the device trace: a profile tells the routed programs apart
+    # by their jit names (``jit_routed_lookup`` / ``_insert`` / ``_delete``).
+    def routed_lookup(tables, *args):
         # tables: [1, buf, b] local shard; hi/lo: [per_shard]
+        stashes, hi, lo = args if has_stash else (None, *args)
         table = tables[0]
         stash = stashes[0] if has_stash else None
         dst, rank, fits = _route(hi, lo, n_shards, cap, route=route,
@@ -206,14 +209,9 @@ def _lookup_fn(mesh: Mesh, axis: str, n_shards: int, cap: int, fp_bits: int,
         ans = jnp.where(fits, back[dst.clip(0, n_shards - 1), rank], True)
         return ans, overflow[None]
 
-    if has_stash:
-        return jax.jit(_shard_map_for(
-            backend, shard_fn, mesh=mesh,
-            in_specs=(P(axis), P(axis), P(axis), P(axis)),
-            out_specs=(P(axis), P(axis))))
     return jax.jit(_shard_map_for(
-        backend, lambda t, h, l: shard_fn(t, None, h, l), mesh=mesh,
-        in_specs=(P(axis), P(axis), P(axis)),
+        backend, routed_lookup, mesh=mesh,
+        in_specs=(P(axis),) * (4 if has_stash else 3),
         out_specs=(P(axis), P(axis))))
 
 
@@ -282,7 +280,7 @@ def _routed_write_fn(mesh: Mesh, axis: str, op: str, n_shards: int,
                      evict_rounds=evict_rounds, max_disp=max_disp,
                      schedule=schedule)
 
-    def shard_fn(tables, stashes, hi, lo, lane_valid):
+    def routed_write(tables, stashes, hi, lo, lane_valid):
         table = tables[0]
         stash = stashes[0] if has_stash else None
         dst, rank, fits = _route(hi, lo, n_shards, cap, lane_valid,
@@ -314,8 +312,10 @@ def _routed_write_fn(mesh: Mesh, axis: str, op: str, n_shards: int,
         return (new_table[None], new_stash[None], ok_lane, deferred,
                 overflow[None])
 
+    # Named for the device trace, as ``routed_lookup`` is.
+    routed_write.__name__ = routed_write.__qualname__ = f"routed_{op}"
     mapped = _shard_map_unchecked(
-        shard_fn, mesh=mesh,
+        routed_write, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(axis)),
         out_specs=(P(axis),) * 5)
     return jax.jit(mapped, donate_argnums=(0, 1) if donate else ())

@@ -21,6 +21,7 @@ load *before* it turns into decode-slot starvation.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import os
@@ -31,6 +32,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import filter as jfilter
 from repro.core import hashing
@@ -237,13 +239,14 @@ class ContinuousBatcher:
 
 # ------------------------------------------------ deferred write pump ----
 #
-# The routed distributed writes (``core.distributed.distributed_insert``)
-# return a **deferred batch**: lanes that exceeded their owner shard's
-# all_to_all capacity and were never attempted.  PR 6 left resubmission to
-# the caller; the pump below closes the loop with the SAME hysteresis
+# The routed distributed ops (``core.distributed``) return writes' lanes that
+# exceeded their owner shard's all_to_all capacity as a **deferred batch**:
+# never attempted, to be resubmitted.  The pump below is the served entry
+# point of a sharded filter: lookups, inserts and deletes go through it, and
+# it parks deferred write lanes and resubmits them with the SAME hysteresis
 # controller the request path uses — deferred keys are a write-side
-# admission queue, and resubmitting them while the shards are congested
-# just re-defers them (or worse, lands them in saturated stashes).
+# admission queue, and resubmitting them while the shards are congested just
+# re-defers them (or worse, lands them in saturated stashes).
 
 
 class ShardedFilterFills:
@@ -267,37 +270,99 @@ class ShardedFilterFills:
         return fill, stash_fill
 
 
+_NO_SPAN = contextlib.nullcontext()   # every span without a tracer
+_WRITES = ("insert", "delete")
+
+
 @dataclasses.dataclass
 class PumpStats:
-    submitted: int = 0      # lanes offered via submit()
-    inserted: int = 0       # lanes resident after their (re)attempt
-    deferred: int = 0       # lane-deferrals observed (a lane can repeat)
-    resubmitted: int = 0    # lanes re-offered by pump()
+    """Lanes by (what, kind): ``offered`` by calls, ``deferred`` (parked by
+    routing overflow or while held; a lane can repeat), ``resubmitted`` by
+    ``pump``, ``overflowed`` (over a routing capacity: a write deferred, a
+    lookup answered "maybe present"), ``acked`` and ``failed`` (a write
+    attempted and not acknowledged)."""
+    lanes: collections.Counter = dataclasses.field(
+        default_factory=collections.Counter)
     held_ticks: int = 0     # pump ticks the hysteresis gate held the queue
-    failed: int = 0         # genuine insert failures (chain + stash full)
+
+    def total(self, what: str) -> int:
+        return sum(v for (w, _kind), v in self.lanes.items() if w == what)
+
+    submitted = property(lambda self: self.total("offered"))
+    resubmitted = property(lambda self: self.total("resubmitted"))
+    failed = property(lambda self: self.total("failed"))
+    inserted = property(lambda self: self.lanes["acked", "insert"])
+
+
+@dataclasses.dataclass
+class ShardCall:
+    """One call of the pump.  ``answers`` fills in as lanes are answered;
+    ``results`` is set once all are: a lookup at its harvest, a write once
+    its deferred lanes have been replayed.  ``deferred`` marks the lanes
+    its first attempt parked."""
+    kind: str
+    n: int
+    seq: int
+    hi: np.ndarray = dataclasses.field(repr=False)
+    lo: np.ndarray = dataclasses.field(repr=False)
+    answers: np.ndarray = dataclasses.field(repr=False)
+    deferred: np.ndarray = dataclasses.field(repr=False)
+    results: Optional[np.ndarray] = None
+    unanswered: int = 0
+
+
+@dataclasses.dataclass
+class _Attempt:
+    """One routed program in flight: lanes of one kind, from one or more
+    calls (``parts``: (call, lane indices) in lane order), at ``pos`` in
+    the batch (None: its first lanes)."""
+    kind: str
+    seq: int
+    n: int
+    parts: list
+    fresh: bool
+    device: tuple
+    pos: Optional[np.ndarray] = None
 
 
 class DeferredWritePump:
-    """Hysteresis-controlled resubmission of routed-write deferred batches.
+    """The served entry point of a sharded filter: routed lookups, inserts
+    and deletes over a fixed (mesh, axis, ``ShardedFilterState``), with
+    hysteresis-controlled resubmission of deferred writes.
 
-    Wraps ``distributed_insert`` on a fixed (mesh, axis, sharded state):
-    ``submit`` runs the routed insert and parks the returned deferred batch
-    host-side; ``pump`` re-offers parked keys only while the admission
-    controller's congestion signal allows (trip at ``high_water``, resume
-    at ``low_water`` — the identical hysteresis the request scheduler
-    applies to decode admission, pointed at the write path).  Parked
-    batches are padded to the sharded batch shape with ``valid=False``
-    lanes, so resubmission never fabricates sentinel inserts.
+    ``call(kind, keys)`` takes 64-bit keys, splits them on the host, pads
+    them to the shard multiple, uploads them once, straight into the mesh's
+    ``NamedSharding`` over ``axis``, and enqueues the routed program
+    (``core.distributed``).  A call is harvested at the next call, ``flush``,
+    ``pump`` or ``run_until_drained``.  Writes' deferred lanes park on the
+    host; ``pump`` re-offers them, oldest first and one kind at a time,
+    only while the admission controller's congestion signal allows (trip at
+    ``high_water``, resume at ``low_water``).  A write never overtakes a
+    parked write: parked lanes are replayed first, and while any stay
+    parked a new write parks whole, so a delete never runs before the
+    insert it follows.  Every write is applied once ``run_until_drained``
+    has emptied the queue.  ``capacity_factor`` sizes the writes' exchanges;
+    lookups run at ``distributed_lookup``'s own capacity and are never
+    parked: an overflowed lane answers "maybe present" and is counted, so
+    a small ``capacity_factor`` that defers writes leaves lookups exact at
+    fair routing.  Parked batches are padded
+    with ``valid=False`` lanes, so resubmission never fabricates writes.
+
+    ``tracer``: a ``repro.obs.TraceRecorder``; each fresh call gets a
+    ``shard_dispatch`` span (``shard_prepare``, ``shard_upload``,
+    ``distributed.<kind>`` inside) and each harvest a ``shard_harvest``
+    (``harvest_wait``, ``harvest_fetch``); a resubmission is a
+    ``pump_resubmit``.  Every span carries ``call``, ``kind`` and ``n``.
+    ``metrics``: lane counts as ``routing_<what>_lanes{kind}`` (see
+    ``PumpStats``) and ``pump_held_ticks``.
     """
 
     def __init__(self, mesh, axis: str, state, *, fp_bits: int,
                  admission=None, capacity_factor: float = 2.0,
                  backend: str = "auto", donate: bool = True, metrics=None,
                  tracer=None, route: str = "key"):
-        from repro.core.distributed import distributed_insert
+        from repro.core import distributed as dist
         from repro.streaming.admission import AdmissionController
-        self.mesh, self.axis = mesh, axis
-        self.state = state
         self.fp_bits = fp_bits
         self.capacity_factor = capacity_factor
         self.backend = backend
@@ -305,33 +370,44 @@ class DeferredWritePump:
         self.metrics = metrics
         self.tracer = tracer
         self.route = route
-        self._insert = distributed_insert
+        self._ops = {"lookup": dist.distributed_lookup,
+                     "insert": dist.distributed_insert,
+                     "delete": dist.distributed_delete}
         self.admission = admission or AdmissionController(
             filt=ShardedFilterFills(lambda: self.state), metrics=metrics)
-        self.n_shards = mesh.shape[axis]
-        self._pend_hi = np.empty((0,), np.uint32)
-        self._pend_lo = np.empty((0,), np.uint32)
+        self._parked: deque[tuple[ShardCall, np.ndarray]] = deque()
+        self._inflight: Optional[_Attempt] = None
+        self._seq = 0
         self.stats = PumpStats()
         self.held = False
+        self.retarget(mesh, axis, state)
 
     @property
     def pending(self) -> int:
-        return int(self._pend_hi.size)
+        """Write lanes parked."""
+        return sum(lanes.size for _call, lanes in self._parked)
 
-    def _span(self, name: str, **args):
+    def _span(self, name: str, seq: int, kind: str, n: int):
         if self.tracer is None:
-            return contextlib.nullcontext()
-        return self.tracer.span(name, **args)
+            return _NO_SPAN
+        return self.tracer.span(name, call=seq, kind=kind, n=n)
+
+    def _count(self, what: str, kind: str, n: int) -> None:
+        if n:
+            self.stats.lanes[what, kind] += n
+            if self.metrics is not None:
+                self.metrics.counter(f"routing_{what}_lanes").inc(n,
+                                                                  kind=kind)
 
     # ------------------------------------------ elastic cutover hooks --
 
     def hold(self):
-        """Park ALL traffic (fresh submits included) until ``release``.
+        """Park every write (fresh calls included) until ``release``.
 
         The elastic controller brackets a migration with hold/release: a
-        routed insert issued mid-migration would race the all_to_all
+        routed write issued mid-migration would race the all_to_all
         streams (and target the wrong mesh after cutover), so during the
-        window every offered lane goes straight to the pending queue.
+        window every offered write lane goes straight to the pending queue.
         """
         self.held = True
 
@@ -348,82 +424,205 @@ class DeferredWritePump:
         self.mesh, self.axis = mesh, axis
         self.state = state
         self.n_shards = mesh.shape[axis]
+        self._lanes = NamedSharding(mesh, P(axis))
 
-    def _attempt(self, hi: np.ndarray, lo: np.ndarray):
-        """One routed insert over a host batch, padded to the shard shape."""
-        pad = (-hi.size) % self.n_shards
-        valid = np.ones(hi.size + pad, bool)
-        if pad:
-            hi = np.concatenate([hi, np.zeros(pad, np.uint32)])
-            lo = np.concatenate([lo, np.zeros(pad, np.uint32)])
-            valid[-pad:] = False
-        self.state, ok, deferred, ov = self._insert(
-            self.mesh, self.axis, self.state, jnp.asarray(hi),
-            jnp.asarray(lo), fp_bits=self.fp_bits,
-            capacity_factor=self.capacity_factor, backend=self.backend,
-            donate=self.donate, valid=jnp.asarray(valid),
-            route=self.route)
-        ok, deferred = np.asarray(ok), np.asarray(deferred)
-        self._pend_hi = np.concatenate([self._pend_hi, hi[deferred]])
-        self._pend_lo = np.concatenate([self._pend_lo, lo[deferred]])
-        self.stats.inserted += int(ok.sum())
-        self.stats.deferred += int(deferred.sum())
-        self.stats.failed += int((valid & ~ok & ~deferred).sum())
-        if self.metrics is not None:
-            # ok/deferred already forced a sync; ov rides the same fence.
-            m = self.metrics
-            m.counter("routing_inserted_lanes").inc(int(ok.sum()))
-            m.counter("routing_deferred_lanes").inc(int(deferred.sum()))
-            m.counter("routing_overflow_lanes").inc(
-                int(np.asarray(ov).sum()))
-        return ok, deferred
+    # ------------------------------------------------------------ intake --
+
+    def call(self, kind: str, keys) -> ShardCall:
+        """Offer one ``lookup`` / ``insert`` / ``delete`` of 64-bit keys ->
+        its ``ShardCall`` (answers at harvest)."""
+        return self._offer(kind, keys=np.asarray(keys, np.uint64))
 
     def submit(self, hi, lo):
-        """Routed insert of a fresh batch -> (ok[N], deferred[N]).
+        """Insert pre-split key halves and harvest at once -> (ok[N],
+        deferred[N]) of the first attempt; deferred lanes are parked for
+        ``pump``.  While ``held`` the batch parks whole."""
+        c = self._offer("insert", hi=np.asarray(hi, np.uint32),
+                        lo=np.asarray(lo, np.uint32))
+        self.flush()
+        return c.answers.copy(), c.deferred.copy()
 
-        Deferred lanes are parked for ``pump``; the batch must divide the
-        shard count (the ``distributed_insert`` contract for fresh traffic).
-        While ``held`` (elastic migration window) the batch parks whole —
-        nothing inserted, everything deferred — and replays after cutover.
-        """
-        hi = np.asarray(hi, np.uint32)
-        lo = np.asarray(lo, np.uint32)
-        self.stats.submitted += int(hi.size)
-        if self.held:
-            self._pend_hi = np.concatenate([self._pend_hi, hi])
-            self._pend_lo = np.concatenate([self._pend_lo, lo])
-            self.stats.deferred += int(hi.size)
-            return (np.zeros(hi.size, bool), np.ones(hi.size, bool))
-        return self._attempt(hi, lo)
+    def flush(self) -> None:
+        """Harvest the call in flight (one ``block_until_ready``)."""
+        if self._inflight is not None:
+            self._harvest(self._inflight)
+
+    def _offer(self, kind: str, *, keys=None, hi=None, lo=None) -> ShardCall:
+        if kind not in self._ops:
+            raise ValueError(f"unknown call kind {kind!r}")
+        self.flush()
+        write = kind in _WRITES
+        if write:
+            while self._parked and self.pump():
+                pass                      # parked writes go first
+        n = int(keys.size if keys is not None else hi.size)
+        seq, self._seq = self._seq, self._seq + 1
+        self._count("offered", kind, n)
+        with self._span("shard_dispatch", seq, kind, n):
+            with self._span("shard_prepare", seq, kind, n):
+                if keys is not None:
+                    hi, lo = hashing.key_to_u32_pair_np(keys)
+                c = ShardCall(kind, n, seq, hi, lo, np.zeros(n, bool),
+                              np.zeros(n, bool), unanswered=n)
+                lanes = np.arange(n)
+                if write and (self.held or self._parked):
+                    c.deferred[:] = True
+                    self._park(c, lanes)
+                    return c
+                padded = self._padded(hi, lo, self._shape(n))
+            self._inflight = self._enqueue(kind, seq, [(c, lanes)], padded,
+                                           fresh=True)
+        return c
+
+    def _shape(self, n: int) -> int:
+        """Lanes of a call of ``n`` keys: the next multiple of the shards."""
+        return -(-n // self.n_shards) * self.n_shards
+
+    def _park(self, c: ShardCall, lanes: np.ndarray) -> None:
+        self._parked.append((c, lanes))
+        self._count("deferred", c.kind, lanes.size)
+
+    @staticmethod
+    def _padded(hi, lo, size: int):
+        """(hi, lo, valid) of ``size`` lanes: the keys, then ``valid=False``
+        lanes of key 0."""
+        n = hi.size
+        valid = np.zeros(size, bool)
+        valid[:n] = True
+        if size == n:
+            return hi, lo, valid
+        pad = np.zeros(size - n, np.uint32)
+        return np.concatenate([hi, pad]), np.concatenate([lo, pad]), valid
+
+    # ---------------------------------------------------------- pipeline --
+
+    def _enqueue(self, kind: str, seq: int, parts, padded, *,
+                 fresh: bool) -> _Attempt:
+        """Upload one padded batch and enqueue its routed program; no host
+        sync on this path."""
+        n = sum(lanes.size for _c, lanes in parts)
+        with self._span("shard_upload", seq, kind, n):
+            # A lookup has no valid mask: its padding lanes are answered
+            # and dropped.
+            hi, lo, *valid = jax.device_put(
+                padded[:2] if kind == "lookup" else padded, self._lanes)
+        with self._span("distributed." + kind, seq, kind, n):
+            kw = dict(fp_bits=self.fp_bits, backend=self.backend,
+                      route=self.route)
+            if kind == "lookup":
+                # At the routed lookup's own capacity: ``capacity_factor``
+                # sizes the writes' exchanges, whose overflow is replayed.
+                device = self._ops[kind](self.mesh, self.axis, self.state,
+                                         hi, lo, **kw)
+            else:
+                self.state, ok, deferred, _ov = self._ops[kind](
+                    self.mesh, self.axis, self.state, hi, lo,
+                    capacity_factor=self.capacity_factor,
+                    donate=self.donate, valid=valid[0], **kw)
+                device = (ok, deferred)
+        return _Attempt(kind, seq, n, parts, fresh, device)
+
+    def _harvest(self, att: _Attempt) -> None:
+        """The only sync point: an attempt's answers to the host, deferred
+        lanes parked again in their place."""
+        with self._span("shard_harvest", att.seq, att.kind, att.n):
+            with self._span("harvest_wait", att.seq, att.kind, att.n):
+                dev = jax.block_until_ready(att.device)
+            with self._span("harvest_fetch", att.seq, att.kind, att.n):
+                first, second = jax.device_get(dev)
+                if att.kind == "lookup":
+                    (c, lanes), = att.parts
+                    c.answers[lanes] = first[:lanes.size]
+                    self._answered(c, lanes.size)
+                    self._count("overflowed", "lookup", int(second.sum()))
+                else:
+                    if att.pos is not None:
+                        first, second = first[att.pos], second[att.pos]
+                    self._book_write(att, first, second)
+        if att is self._inflight:
+            self._inflight = None
+
+    def _book_write(self, att: _Attempt, ok, deferred) -> None:
+        again, at = [], 0
+        for c, lanes in att.parts:
+            k = lanes.size
+            c_ok, c_dfr = ok[at:at + k], deferred[at:at + k]
+            at += k
+            done = ~c_dfr
+            c.answers[lanes[done]] = c_ok[done]
+            if att.fresh:
+                c.deferred[lanes] = c_dfr
+            if c_dfr.any():
+                again.append((c, lanes[c_dfr]))
+            self._answered(c, int(done.sum()))
+        ok, deferred = ok[:at], deferred[:at]
+        n_dfr = int(deferred.sum())
+        for what, n in (("deferred", n_dfr), ("overflowed", n_dfr),
+                        ("acked", int(ok.sum())),
+                        ("failed", int((~ok & ~deferred).sum()))):
+            self._count(what, att.kind, n)
+        # Back at the head, in order: a fresh write is dispatched only on an
+        # empty queue, and a resubmission took the head.
+        self._parked.extendleft(reversed(again))
+
+    @staticmethod
+    def _answered(c: ShardCall, n: int) -> None:
+        c.unanswered -= n
+        if c.unanswered == 0:
+            c.results = c.answers
 
     def pump(self) -> int:
         """One resubmission tick -> lanes re-attempted (0 while held).
 
-        Gated by the side-effect-free ``peek`` so polling does not inflate
-        the controller's per-request counters; a tripped gate holds the
-        parked batch untouched (``held_ticks``) until the congestion signal
+        Re-offers the oldest parked lanes of one kind, at most as many as
+        the oldest call holds, in that call's shape: the routed program a
+        fresh call of that shape compiled serves it, so a resubmission
+        compiles nothing.  The lanes are dealt round the shards' slices, so
+        every source shard's routing capacity takes a share.  Gated by the
+        side-effect-free ``peek`` so polling does not inflate the
+        controller's per-request counters; a tripped gate holds the parked
+        lanes untouched (``held_ticks``) until the congestion signal
         recedes past low_water.
         """
-        if not self.pending:
+        self.flush()
+        if not self._parked:
             return 0
         if self.held or not self.admission.peek():
             self.stats.held_ticks += 1
             if self.metrics is not None:
                 self.metrics.counter("pump_held_ticks").inc()
             return 0
-        hi, lo = self._pend_hi, self._pend_lo
-        self._pend_hi = np.empty((0,), np.uint32)
-        self._pend_lo = np.empty((0,), np.uint32)
-        self.stats.resubmitted += int(hi.size)
-        if self.metrics is not None:
-            self.metrics.counter("pump_resubmitted_lanes").inc(int(hi.size))
-        with self._span("pump_resubmit", lanes=int(hi.size)):
-            self._attempt(hi, lo)
-        return int(hi.size)
+        head = self._parked[0][0]
+        size = self._shape(head.n)
+        parts, n = [], 0
+        while (n < size and self._parked
+               and self._parked[0][0].kind == head.kind):
+            c, lanes = self._parked.popleft()
+            if n + lanes.size > size:
+                self._parked.appendleft((c, lanes[size - n:]))
+                lanes = lanes[:size - n]
+            parts.append((c, lanes))
+            n += lanes.size
+        self._count("resubmitted", head.kind, n)
+        with self._span("pump_resubmit", head.seq, head.kind, n):
+            i = np.arange(n)
+            pos = (i % self.n_shards) * (size // self.n_shards) \
+                + i // self.n_shards
+            hi, lo = np.zeros(size, np.uint32), np.zeros(size, np.uint32)
+            valid = np.zeros(size, bool)
+            hi[pos] = np.concatenate([c.hi[lanes] for c, lanes in parts])
+            lo[pos] = np.concatenate([c.lo[lanes] for c, lanes in parts])
+            valid[pos] = True
+            att = self._enqueue(head.kind, head.seq, parts, (hi, lo, valid),
+                                fresh=False)
+            att.pos = pos
+            self._harvest(att)
+        return n
 
     def run_until_drained(self, *, max_ticks: int = 100,
                           on_held=None) -> PumpStats:
-        """Pump until nothing is parked (or ``max_ticks``).
+        """Harvest, then pump until nothing is parked (or ``max_ticks``):
+        every write offered so far is then applied.
 
         ``on_held``: optional callback invoked on each held tick — the hook
         where a control plane relieves congestion (rotate a generation,
@@ -431,6 +630,7 @@ class DeferredWritePump:
         static filter would hold forever, so the loop stops early when
         holding makes no progress and nothing external intervenes.
         """
+        self.flush()
         for _ in range(max_ticks):
             if not self.pending:
                 break
@@ -462,8 +662,6 @@ class DeferredWritePump:
 # sync-path microbench floor through admission-parked closed-loop tails.
 LATENCY_BUCKETS_US = (50.0, 100.0, 200.0, 500.0, 1_000.0, 2_000.0,
                       5_000.0, 10_000.0, 25_000.0, 50_000.0, 100_000.0)
-
-_NO_SPAN = contextlib.nullcontext()   # every span of a batcher without tracer
 
 
 @jax.jit
