@@ -39,12 +39,15 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import filter as jfilter
 from repro.core import hashing
 from repro.core.filter_ops import FilterOps
 from repro.core.scheduling import conflict_waves
+from repro.kernels import ops as kops
+from repro.kernels.delete import live_steps
 from repro.kernels.stash import DEFAULT_STASH_SLOTS
 
 def _shard_map_for(backend: str, fn, *, mesh, in_specs, out_specs):
@@ -123,6 +126,11 @@ def sharded_occupancy(state: ShardedFilterState) -> jax.Array:
     if state.stashes is not None:
         live = live + jnp.sum(state.stashes[:, 0, :] != 0)
     return live.astype(jnp.float32) / jnp.float32(state.tables.size)
+
+
+def _capacity(n_lanes: int, n_shards: int, capacity_factor: float) -> int:
+    """Slots per (source, owner) pair of a routed call of ``n_lanes``."""
+    return int(n_lanes // n_shards * capacity_factor / n_shards + 1)
 
 
 def _route(hi, lo, n_shards: int, cap: int, valid=None, *,
@@ -239,8 +247,7 @@ def distributed_lookup(mesh: Mesh, axis: str, state: ShardedFilterState,
     routing sends keys to the wrong shard and silently false-negatives.
     """
     n_shards = mesh.shape[axis]
-    per_shard = hi.shape[0] // n_shards
-    cap = int(per_shard * capacity_factor / n_shards + 1)  # slots per (src,dst)
+    cap = _capacity(hi.shape[0], n_shards, capacity_factor)
     has_stash = state.stashes is not None
     nb = state.n_buckets
     route_nb = nb if nb is not None else state.tables.shape[1]
@@ -328,8 +335,7 @@ def _distributed_write(op: str, mesh: Mesh, axis: str,
                        schedule: bool, donate: bool, valid=None,
                        route: str = "key"):
     n_shards = mesh.shape[axis]
-    per_shard = hi.shape[0] // n_shards
-    cap = int(per_shard * capacity_factor / n_shards + 1)
+    cap = _capacity(hi.shape[0], n_shards, capacity_factor)
     has_stash = state.stashes is not None
     route_nb = (state.n_buckets if state.n_buckets is not None
                 else state.tables.shape[1])
@@ -427,6 +433,39 @@ def distributed_delete(mesh: Mesh, axis: str, state: ShardedFilterState,
                               backend=backend, evict_rounds=None,
                               max_disp=500, schedule=False, donate=donate,
                               valid=valid, route=route)
+
+
+def routed_delete_steps(state: ShardedFilterState, hi: np.ndarray,
+                        lo: np.ndarray, valid: np.ndarray, n_shards: int, *,
+                        fp_bits: int, capacity_factor: float = 2.0,
+                        backend: str = "auto", route: str = "key") -> int:
+    """Grid steps the shard-local delete of ``distributed_delete`` runs on
+    its busiest shard, worked out on the host from the call's lanes.
+
+    Each owner receives ``n_shards`` rows of ``cap`` slots, a source's keys
+    for it first in each row, and its kernel-arm delete runs the steps of
+    ``kops.delete_block`` lanes that hold one (``live_steps``).  0 when the
+    shard-local delete takes the jnp arm, which has no grid.
+    """
+    if FilterOps(fp_bits=fp_bits, backend=backend).resolve() != "pallas":
+        return 0
+    if route == "pair":
+        nb = (state.n_buckets if state.n_buckets is not None
+              else state.tables.shape[1])
+        owner = hashing.owner_shard_key_pair_np(hi, lo, nb, fp_bits, n_shards)
+    else:
+        owner = hashing.owner_shard_np(hi, lo, n_shards)
+    rows = np.where(valid, owner, n_shards).reshape(n_shards, -1)
+    cap = _capacity(hi.size, n_shards, capacity_factor)
+    sent = np.minimum(np.stack([np.bincount(r, minlength=n_shards + 1)
+                                for r in rows])[:, :n_shards], cap)
+    lanes = n_shards * cap
+    buf, bucket_size = state.tables.shape[1:]
+    block = kops.delete_block(lanes, table_bytes=buf * bucket_size * 4)
+    slot = np.arange(cap)
+    return max(int(live_steps(np.pad((slot < sent[:, d, None]).reshape(-1),
+                                     (0, -lanes % block)), block).sum())
+               for d in range(n_shards))
 
 
 # ------------------------------------------------- compat shims (host) --

@@ -81,6 +81,42 @@ def _delete_body(table, hi, lo, valid, n_buckets, *, fp_bits: int):
     return table, ok1 | ok2
 
 
+def live_steps(valid, block: int):
+    """Which grid steps of ``block`` lanes hold a valid lane -> bool[steps].
+
+    ``valid`` is a NumPy or JAX bool array whose length is a block multiple
+    (the kernel wrappers pad to one).  The emulated grid runs exactly these
+    steps: a step of padding lanes clears nothing.
+    """
+    return valid.reshape(-1, block).any(axis=1)
+
+
+def _emulated_grid(body, carry, hi, lo, valid, block: int):
+    """The kernel's sequential grid as one compiled loop -> (carry, ok[N]).
+
+    ``body(carry, hi, lo, valid) -> (carry, ok)`` runs one block; the carry
+    (the table, or the adaptive planes) passes from block to block, so
+    block b's clears are visible to block b+1, as in the pallas_call.  Only
+    the steps ``live_steps`` names run, in grid order: a step whose lanes
+    are all ``valid=False`` leaves the table as it is and answers False,
+    so skipping it changes nothing.
+    """
+    g = hi.shape[0] // block
+    hi, lo, valid = (x.reshape(g, block) for x in (hi, lo, valid))
+    live = live_steps(valid, block)
+    order = jnp.nonzero(live, size=g, fill_value=0)[0]
+
+    def step(i, state):
+        carry, ok = state
+        s = order[i]
+        carry, ok_s = body(carry, hi[s], lo[s], valid[s])
+        return carry, ok.at[s].set(ok_s)
+
+    carry, ok = jax.lax.fori_loop(0, jnp.sum(live, dtype=jnp.int32), step,
+                                  (carry, jnp.zeros((g, block), bool)))
+    return carry, ok.reshape(-1)
+
+
 def _delete_kernel(n_ref, table_in_ref, hi_ref, lo_ref, valid_ref, table_ref,
                    ok_ref, *, fp_bits: int):
     del table_in_ref  # aliased to table_ref (the output) — read/write there
@@ -105,21 +141,13 @@ def _delete_bulk_impl(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
     hi = hi.astype(jnp.uint32)
     lo = lo.astype(jnp.uint32)
     if emulate:
-        # The kernel's sequential grid as a compiled lax.scan (table carried
-        # between blocks) — bit-for-bit the pallas_call, without the
-        # interpreter (see kernels/insert.py::_emulated_insert).
-        g = n // block
-        if g == 1:
+        if n == block:
             return _delete_body(table, hi, lo, valid, n_buckets,
                                 fp_bits=fp_bits)
-
-        def step(tbl, x):
-            return _delete_body(tbl, *x, n_buckets, fp_bits=fp_bits)
-
-        table, ok = jax.lax.scan(step, table,
-                                 (hi.reshape(g, block), lo.reshape(g, block),
-                                  valid.reshape(g, block)))
-        return table, ok.reshape(-1)
+        return _emulated_grid(
+            lambda tbl, *x: _delete_body(tbl, *x, n_buckets,
+                                         fp_bits=fp_bits),
+            table, hi, lo, valid, block)
     n_arr = jnp.asarray(n_buckets, jnp.int32).reshape(1, 1)
     grid = (n // block,)
     smem_spec = pl.BlockSpec((1, 1), lambda i: (0, 0),
@@ -218,23 +246,18 @@ def _delete_adaptive_impl(table, sels, khi_t, klo_t, hi, lo, *, fp_bits: int,
     hi = hi.astype(jnp.uint32)
     lo = lo.astype(jnp.uint32)
     if emulate:
-        g = n // block
-        if g == 1:
+        if n == block:
             return _delete_adaptive_body(table, sels, khi_t, klo_t, hi, lo,
                                          valid, n_buckets, fp_bits=fp_bits)
 
-        def step(carry, x):
-            t, s, kh, kl = carry
-            t, s, kh, kl, ok = _delete_adaptive_body(t, s, kh, kl, *x,
-                                                     n_buckets,
-                                                     fp_bits=fp_bits)
-            return (t, s, kh, kl), ok
+        def step(planes, *x):
+            *planes, ok = _delete_adaptive_body(*planes, *x, n_buckets,
+                                                fp_bits=fp_bits)
+            return tuple(planes), ok
 
-        (table, sels, khi_t, klo_t), ok = jax.lax.scan(
-            step, (table, sels, khi_t, klo_t),
-            (hi.reshape(g, block), lo.reshape(g, block),
-             valid.reshape(g, block)))
-        return table, sels, khi_t, klo_t, ok.reshape(-1)
+        (table, sels, khi_t, klo_t), ok = _emulated_grid(
+            step, (table, sels, khi_t, klo_t), hi, lo, valid, block)
+        return table, sels, khi_t, klo_t, ok
     n_arr = jnp.asarray(n_buckets, jnp.int32).reshape(1, 1)
     grid = (n // block,)
     smem_spec = pl.BlockSpec((1, 1), lambda i: (0, 0),
@@ -281,11 +304,12 @@ def delete_bulk(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
     does) — like every cuckoo delete, clearing a fingerprint that was never
     inserted corrupts another key's slot.
 
-    ``emulate`` runs the identical grid as a compiled XLA scan (the off-TPU
-    fast path); ``donate`` hands the table buffer to the call so the
-    cleared table is written in place (callers must own the buffer — the
-    OCF control plane does).  Deletes are never wave-scheduled: duplicate
-    keys must clear the k-th resident copy in lane order.
+    ``emulate`` runs the identical grid as a compiled XLA loop over the
+    steps that hold a valid lane (the off-TPU fast path); ``donate`` hands
+    the table buffer to the call so the cleared table is written in place
+    (callers must own the buffer — the OCF control plane does).  Deletes
+    are never wave-scheduled: duplicate keys must clear the k-th resident
+    copy in lane order.
     """
     fn = _delete_bulk_donated if donate else _delete_bulk_jit
     return fn(table, hi, lo, fp_bits=fp_bits, n_buckets=n_buckets,

@@ -2,9 +2,10 @@
 
 Form: ``LOWERING`` below is the one statement of how each table op runs on
 a TPU — as its ``pallas_call`` lowered by Mosaic, or as its **XLA grid
-emulation** (the identical kernel body compiled as a ``lax.scan`` over the
-grid, ``emulate=True`` on every kernel entry point).  Off TPU every op runs
-its emulation, which the CPU parity tests pin bit-for-bit against the
+emulation** (the identical kernel body compiled as a loop over the grid,
+``emulate=True`` on every kernel entry point; the deletes' loop runs only
+the steps that hold a valid lane, ``kernels.delete.live_steps``).  Off TPU
+every op runs its emulation, which the CPU parity tests pin bit-for-bit against the
 Pallas interpreter.  ``use_pallas='auto'|'always'|'never'`` picks between
 that kernel arm and the pure-jnp oracle (``ref.py``): on TPU 'auto' always
 takes the kernel arm; off TPU it takes it for batches and tables the VMEM
@@ -174,6 +175,15 @@ def autotune_block(op: str, *, table_bytes: int, evict_rounds: int = 0,
         if whole:
             return whole[0]
     return fits[0]
+
+
+def delete_block(n_keys: int, *, table_bytes: int) -> int:
+    """BLOCK of a kernel-arm delete of ``n_keys`` (> 0) lanes on a table of
+    ``table_bytes``: the one statement of it, for the delete wrappers below
+    and for whoever counts the grid steps the delete runs
+    (``kernels.delete.live_steps`` over its lanes padded to the block)."""
+    return min(autotune_block("delete", table_bytes=table_bytes,
+                              n_keys=n_keys), n_keys)
 
 
 def _use_kernel(use_pallas: str, *, vmem_bytes: int, n_keys: int) -> bool:
@@ -496,8 +506,7 @@ def filter_delete(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
                                                         empty_ok)
     if valid is None:
         valid = jnp.ones(hi.shape, bool)
-    block = min(autotune_block("delete", table_bytes=table.size * 4,
-                               n_keys=hi.shape[0]), hi.shape[0])
+    block = delete_block(hi.shape[0], table_bytes=table.size * 4)
     if not _use_kernel(use_pallas,
                        vmem_bytes=kernel_vmem_bytes(
                            "delete", table_bytes=table.size * 4, block=block),
@@ -579,8 +588,7 @@ def filter_delete_tm(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
                 else (table, stash, empty_ok, tm))
     if valid is None:
         valid = jnp.ones(hi.shape, bool)
-    block = min(autotune_block("delete", table_bytes=table.size * 4,
-                               n_keys=hi.shape[0]), hi.shape[0])
+    block = delete_block(hi.shape[0], table_bytes=table.size * 4)
     hi_p, n = _pad_to(hi, block)
     lo_p, _ = _pad_to(lo, block)
     valid_p, _ = _pad_to(valid, block)   # pads False: never touches table
@@ -734,8 +742,7 @@ def adaptive_delete(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
     if valid is None:
         valid = jnp.ones(hi.shape, bool)
     table_bytes = _adaptive_plane_bytes(table)
-    block = min(autotune_block("delete", table_bytes=table_bytes,
-                               n_keys=hi.shape[0]), hi.shape[0])
+    block = delete_block(hi.shape[0], table_bytes=table_bytes)
     kernel = _use_kernel(use_pallas,
                          vmem_bytes=kernel_vmem_bytes(
                              "delete", table_bytes=table_bytes, block=block),
@@ -832,8 +839,7 @@ def adaptive_delete_tm(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
     if valid is None:
         valid = jnp.ones(hi.shape, bool)
     table_bytes = _adaptive_plane_bytes(table)
-    block = min(autotune_block("delete", table_bytes=table_bytes,
-                               n_keys=hi.shape[0]), hi.shape[0])
+    block = delete_block(hi.shape[0], table_bytes=table_bytes)
     hi_p, n = _pad_to(hi, block)
     lo_p, _ = _pad_to(lo, block)
     valid_p, _ = _pad_to(valid, block)   # pads False: never touches planes
@@ -899,7 +905,8 @@ __all__ = ["hash_keys", "filter_lookup", "filter_lookup_multi",
            "filter_insert", "filter_delete", "attention", "fingerprint_hash",
            "fingerprint_hash_family", "probe", "probe_multi", "insert_once",
            "insert_bulk", "delete_bulk", "flash_attention",
-           "kernel_vmem_bytes", "autotune_block", "VMEM_TABLE_BUDGET",
+           "kernel_vmem_bytes", "autotune_block", "delete_block",
+           "VMEM_TABLE_BUDGET",
            "LOWERING", "lowering",
            "DEFAULT_EVICT_ROUNDS", "DEFAULT_STASH_SLOTS", "make_stash",
            "stash_occupancy", "adaptive_lookup", "adaptive_insert",

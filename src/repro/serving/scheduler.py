@@ -323,6 +323,7 @@ class _Attempt:
     fresh: bool
     device: tuple
     pos: Optional[np.ndarray] = None
+    lanes: Optional[tuple] = None   # a traced delete's (hi, lo, valid)
 
 
 class DeferredWritePump:
@@ -353,6 +354,10 @@ class DeferredWritePump:
     ``distributed.<kind>`` inside) and each harvest a ``shard_harvest``
     (``harvest_wait``, ``harvest_fetch``); a resubmission is a
     ``pump_resubmit``.  Every span carries ``call``, ``kind`` and ``n``.
+    After a delete's harvest, outside any span, one ``delete_grid_step``
+    instant per grid step its shard-local kernel runs on the busiest shard,
+    modelled on the host from the call's lanes
+    (``core.distributed.routed_delete_steps``).
     ``metrics``: lane counts as ``routing_<what>_lanes{kind}`` (see
     ``PumpStats``) and ``pump_held_ticks``.
     """
@@ -373,6 +378,7 @@ class DeferredWritePump:
         self._ops = {"lookup": dist.distributed_lookup,
                      "insert": dist.distributed_insert,
                      "delete": dist.distributed_delete}
+        self._delete_steps = dist.routed_delete_steps
         self.admission = admission or AdmissionController(
             filt=ShardedFilterFills(lambda: self.state), metrics=metrics)
         self._parked: deque[tuple[ShardCall, np.ndarray]] = deque()
@@ -520,7 +526,10 @@ class DeferredWritePump:
                     capacity_factor=self.capacity_factor,
                     donate=self.donate, valid=valid[0], **kw)
                 device = (ok, deferred)
-        return _Attempt(kind, seq, n, parts, fresh, device)
+        att = _Attempt(kind, seq, n, parts, fresh, device)
+        if kind == "delete" and self.tracer is not None:
+            att.lanes = padded
+        return att
 
     def _harvest(self, att: _Attempt) -> None:
         """The only sync point: an attempt's answers to the host, deferred
@@ -539,6 +548,16 @@ class DeferredWritePump:
                     if att.pos is not None:
                         first, second = first[att.pos], second[att.pos]
                     self._book_write(att, first, second)
+        if att.lanes is not None:
+            # After the harvest, outside its span: one marker per grid step
+            # the shard-local delete runs on the busiest shard, as the host
+            # models it from the call's lanes (``delete.grid_steps``).
+            for _ in range(self._delete_steps(
+                    self.state, *att.lanes, self.n_shards,
+                    fp_bits=self.fp_bits,
+                    capacity_factor=self.capacity_factor,
+                    backend=self.backend, route=self.route)):
+                self.tracer.instant("delete_grid_step", call=att.seq)
         if att is self._inflight:
             self._inflight = None
 

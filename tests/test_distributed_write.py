@@ -422,3 +422,39 @@ def test_deferred_write_pump_subprocess():
     assert res["inserted"] == res["submitted"]
     assert res["all_present"], "every deferred key eventually lands"
     assert not res["pad_hit"], "resubmission padding lanes must stay inert"
+
+
+@pytest.mark.parametrize("route", ["key", "pair"])
+def test_routed_delete_steps_match_the_exchange(rng, route):
+    """The host's count of a routed delete's shard-local grid steps agrees
+    with the lanes the program's own routing hands each owner: the valid
+    rows of every source's send buffers, one ``live_steps`` count per owner
+    at the delete's block, the busiest owner's taken."""
+    from repro.kernels import ops as kops
+    from repro.kernels.delete import live_steps
+    n_shards, per, nb = 4, 2048, 1 << 16     # a 1 MiB shard: block 128
+    state = dist.make_sharded_state(n_shards, nb, 4, stash_slots=8)
+    hi, lo = hashing.key_to_u32_pair_np(random_keys(rng, n_shards * per))
+    valid = rng.rand(hi.size) < 0.9
+    cap = int(per * 2.0 / n_shards + 1)
+    recv = np.zeros((n_shards, n_shards, cap), bool)  # [owner, source, slot]
+    for s in range(n_shards):
+        sl = slice(s * per, (s + 1) * per)
+        dst, rank, fits = dist._route(jnp.asarray(hi[sl]), jnp.asarray(lo[sl]),
+                                      n_shards, cap, jnp.asarray(valid[sl]),
+                                      route=route, n_buckets=nb, fp_bits=16)
+        recv[:, s] = np.asarray(dist._scatter_routed(
+            dst, rank, fits, n_shards, cap, jnp.asarray(hi[sl]),
+            jnp.asarray(lo[sl]))[2])
+    block = kops.delete_block(n_shards * cap, table_bytes=nb * 4 * 4)
+    assert block == 128
+    pad = -(n_shards * cap) % block
+    want = max(int(live_steps(np.pad(r.reshape(-1), (0, pad)), block).sum())
+               for r in recv)
+    assert want < -(-n_shards * cap // block)        # padding steps skipped
+    assert dist.routed_delete_steps(state, hi, lo, valid, n_shards,
+                                    fp_bits=16, backend="pallas",
+                                    route=route) == want
+    assert dist.routed_delete_steps(state, hi, lo, valid, n_shards,
+                                    fp_bits=16, backend="jnp",
+                                    route=route) == 0

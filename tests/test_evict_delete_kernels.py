@@ -225,6 +225,74 @@ def test_filter_ops_delete_dispatch_and_count(rng, monkeypatch):
                                   np.asarray(st_j.table))
 
 
+@pytest.fixture(scope="module")
+def verified_batch():
+    """A near-full table with a stash, and one batch of verified deletes of
+    everything it holds: keys resident twice deleted twice, keys the
+    table had no room for parked in the stash, and ``valid=False`` lanes.
+    Laid out as a routed delete hands a shard its lanes: two rows of 4,096,
+    each its keys (with padding lanes among them) and then padding."""
+    rng = np.random.RandomState(7)
+    uniq = random_keys(rng, 3700)
+    ins = np.concatenate([uniq, uniq[:200]])
+    hi, lo = _pair(ins)
+    st, placed = jf.bulk_insert(jf.make_state(1024, 4), hi, lo, fp_bits=16,
+                                max_disp=16)
+    stash, spilled = kops.stash_spill_ref(kops.make_stash(512), hi, lo,
+                                          ~placed, fp_bits=16,
+                                          n_buckets=1024)
+    assert (~np.asarray(placed)).sum() > 20
+    assert np.asarray(placed | spilled).all()
+    keys = random_keys(rng, 8192)
+    valid = np.zeros(8192, bool)
+    for row, dels in enumerate(np.array_split(ins[rng.permutation(ins.size)],
+                                              2)):
+        lanes = np.sort(rng.choice(dels.size + 250, dels.size,
+                                   replace=False)) + 4096 * row
+        keys[lanes], valid[lanes] = dels, True
+    dhi, dlo = _pair(keys)
+    return st.table, stash, dhi, dlo, jnp.asarray(valid)
+
+
+def _delete_at(batch, block, monkeypatch):
+    """(ok, table) of the emulated ``delete_bulk`` and (ok, table, stash) of
+    ``filter_delete`` with the stash, both at ``block`` (None: one step)."""
+    table, stash, hi, lo, valid = batch
+    n = hi.shape[0]
+    t, ok = delete_bulk(table, hi, lo, fp_bits=16, valid=valid,
+                        block=block or n, interpret=False, emulate=True)
+    # filter_delete takes its block from ``delete_block``, pinned here.
+    monkeypatch.setattr(kops, "delete_block",
+                        lambda n_keys, table_bytes: block or n)
+    out = kops.filter_delete(table, hi, lo, fp_bits=16, valid=valid,
+                             stash=stash, use_pallas="always")
+    monkeypatch.undo()
+    return [np.asarray(x) for x in (ok, t, *out)]
+
+
+@pytest.mark.parametrize("block", [128, 1024, 4096, None])
+def test_verified_delete_outcome_independent_of_block(verified_batch, block,
+                                                      monkeypatch):
+    """Emulated deletes of one verified batch with duplicates, stash-parked
+    keys and padding lanes: answers, table and stash are bit for bit those
+    of block 128, which are those of the sequential scan."""
+    got = _delete_at(verified_batch, block, monkeypatch)
+    base = _delete_at(verified_batch, 128, monkeypatch)
+    for g, b in zip(got, base):
+        np.testing.assert_array_equal(g, b)
+    table, stash, hi, lo, valid = verified_batch
+    t_r, s_r, ok_r = kops.filter_delete(table, hi, lo, fp_bits=16,
+                                        valid=valid, stash=stash,
+                                        use_pallas="never")
+    ok, _t, t_f, s_f, ok_f = got
+    np.testing.assert_array_equal(ok_f, np.asarray(ok_r))
+    np.testing.assert_array_equal(t_f, np.asarray(t_r))
+    np.testing.assert_array_equal(s_f, np.asarray(s_r))
+    assert ok_f[np.asarray(valid)].all() and not ok_f[~np.asarray(valid)].any()
+    assert not t_f.any() and not s_f[0].any()     # everything deleted
+    assert not ok[~np.asarray(valid)].any()
+
+
 # ---------------------------------------------------------------- guards --
 
 

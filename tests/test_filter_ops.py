@@ -312,3 +312,42 @@ def test_distributed_replicated_backend_param(rng):
     tables = jnp.stack([st.table, jnp.zeros_like(st.table)])
     hits = dist.replicated_lookup(tables, hi, lo, fp_bits=16, backend="jnp")
     assert np.asarray(hits).all()
+
+
+# ------------------------------------------------------------ block rule ---
+
+GIB2 = 2 ** 31                         # one 2^27-bucket shard's table
+# Each op's BLOCK from the VMEM model: (op, table bytes, keyword arguments)
+# -> block.  At 2 GiB nothing fits the VMEM budget, so every op takes 128.
+VMEM_BLOCKS = [
+    ("probe", GIB2, {}, 128),
+    ("probe", GIB2, {"stash_slots": 1024}, 128),
+    ("insert", GIB2, {"evict_rounds": 32, "stash_slots": 1024,
+                      "n_keys": 16388}, 128),
+    ("delete", GIB2, {"n_keys": 16388}, 128),
+    ("probe", 2 ** 20, {}, 8192),
+    ("probe", 4 * 2 ** 20, {"stash_slots": 1024}, 4096),
+    ("insert", 2 ** 20, {"evict_rounds": 32, "n_keys": 512}, 512),
+    ("insert", 2 ** 20, {"evict_rounds": 32, "stash_slots": 1024,
+                         "n_keys": 16388}, 128),
+    ("delete", 2 ** 20, {"n_keys": 512}, 512),
+    ("delete", 2 ** 20, {"n_keys": 16388}, 128),
+    ("delete", 16384, {}, 128),
+]
+
+
+def test_block_pins_and_delete_live_steps():
+    """Every op's block is the VMEM model's; a delete's block is that one
+    capped at its lanes, and its emulated grid runs only the steps that
+    hold a valid lane."""
+    from repro.kernels.delete import live_steps
+    for op, tb, kw, block in VMEM_BLOCKS:
+        assert kops.autotune_block(op, table_bytes=tb, **kw) == block
+    # One shard of the four-chip cell: 4 rows of 4,097 lanes.
+    b = kops.delete_block(16388, table_bytes=GIB2)
+    assert b == 128
+    assert kops.delete_block(5, table_bytes=GIB2) == 5
+    lanes = np.pad(np.ones(16388, bool), (0, -16388 % b))
+    assert int(live_steps(lanes, b).sum()) == -(-16388 // b)
+    lanes[4097:] = False                  # one source row holds keys
+    assert int(live_steps(lanes, b).sum()) == -(-4097 // b)
