@@ -339,20 +339,20 @@ def telemetry_rows(rng, *, n_buckets=1 << 14, n=1 << 15,
                    wave_slots=512, n_waves=48):
     """Telemetry-overhead rows (observability PR), two levels:
 
-    * **raw twin rows** — each ``FilterOps`` op timed against its ``*_tm``
-      twin (arms interleaved), recording what the device counter planes
-      cost at the jit boundary.  Informational: on the CPU emulation arm
-      the per-lane depth attribution is real extra work against a ~13
-      ns/key probe, so the lookup delta here is an emulation artifact a
-      fused TPU kernel absorbs — these rows track the trajectory, they
-      are not the gate.
+    * **raw op rows** — each ``FilterOps`` op timed with ``telemetry``
+      off and on (arms interleaved), recording what the device counter
+      planes cost at the jit boundary.  Informational: on the CPU
+      emulation arm the per-lane depth attribution is real extra work
+      against a ~13 ns/key probe, so the lookup delta here is an
+      emulation artifact a fused TPU kernel absorbs — these rows track
+      the trajectory, they are not the gate.
     * **wave rows** — the serving surface the PR actually instruments: a
       fixed mixed insert/lookup/delete stream replayed through
-      ``FilterOpBatcher`` with telemetry off vs on (on = twin jits +
-      counter transfer + metrics registry fold, exactly what ``slo.py
-      --telemetry`` pays).  ``telemetry_overhead_pct`` is this arm's
+      ``FilterOpBatcher`` with telemetry off vs on (on = telemetry
+      programs + counter transfer + metrics registry fold, exactly what
+      ``slo.py --telemetry`` pays).  ``telemetry_overhead_pct`` is this arm's
       slowdown; ``scripts/bench_gate.py`` fails verify when it exceeds
-      its ceiling (default 5%) — the twin-jit design promises
+      its ceiling (default 5%) — the telemetry design promises
       observability is cheap enough to leave on in serving, and this row
       is where that promise is measured, not asserted.
     """
@@ -365,15 +365,15 @@ def telemetry_rows(rng, *, n_buckets=1 << 14, n=1 << 15,
     fns = {
         ("lookup", "off"): (functools.partial(fops.lookup, loaded, hi, lo),
                             8),
-        ("lookup", "on"): (functools.partial(fops.lookup_tm, loaded, hi, lo),
-                           8),
+        ("lookup", "on"): (functools.partial(fops.lookup, loaded, hi, lo,
+                                             telemetry=True), 8),
         ("insert", "off"): (functools.partial(fops.insert, base, hi, lo), 3),
-        ("insert", "on"): (functools.partial(fops.insert_tm, base, hi, lo),
-                           3),
+        ("insert", "on"): (functools.partial(fops.insert, base, hi, lo,
+                                             telemetry=True), 3),
         ("delete", "off"): (functools.partial(fops.delete, loaded, hi, lo),
                             2),
-        ("delete", "on"): (functools.partial(fops.delete_tm, loaded, hi, lo),
-                           2),
+        ("delete", "on"): (functools.partial(fops.delete, loaded, hi, lo,
+                                             telemetry=True), 2),
     }
     best = _interleaved_times(fns, reps=5, trials=12)
     for op in ("lookup", "insert", "delete"):

@@ -170,7 +170,7 @@ def main() -> int:
     # ``telemetry_overhead_pct`` compares the telemetry-on and -off arms of
     # the SAME mixed wave stream measured in the same run (fresh batcher per
     # arm, arms alternated per trial), so like the routed/hostloop pair the
-    # comparison is weather-free; the raw per-twin rows stay informational
+    # comparison is weather-free; the raw per-op rows stay informational
     # because the CPU emulation re-materializes gather chains the fused TPU
     # probe would not (see benchmarks/filter_bench.py::telemetry_rows).
     tel = fresh.get("telemetry_overhead_pct")

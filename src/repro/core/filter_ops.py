@@ -64,6 +64,12 @@ def evict_rounds_for_load(load: float) -> int:
     return r
 
 
+def _split_telemetry(out: tuple, telemetry: bool) -> tuple:
+    """An op's outputs -> (outputs, ()) or, with ``telemetry``, (outputs
+    less the trailing ``FilterTelemetry``, (telemetry,))."""
+    return (out[:-1], out[-1:]) if telemetry else (out, ())
+
+
 @dataclasses.dataclass(frozen=True)
 class FilterOps:
     """Backend-dispatched lookup / insert / delete / rebuild entry points.
@@ -81,6 +87,13 @@ class FilterOps:
     device-resident side table instead of failing, and lookups check it in
     the same fused pass — the streaming subsystem's burst escape hatch
     (``repro.streaming``).
+
+    Every stateful op takes ``telemetry``: when it is set, the op runs the
+    kernel arm whatever the backend and returns its usual outputs plus a
+    trailing ``kernels.telemetry.FilterTelemetry`` (kick-depth histogram,
+    probe hit-depth, spill / rollback / delete counters, stash high-water)
+    — the kernel arm's answers, bit for bit.  ``delete_table`` takes it
+    too: the serving batcher's delete on a stashed static filter runs it.
     """
 
     fp_bits: int = 16
@@ -131,20 +144,27 @@ class FilterOps:
         """The ``kernels.ops`` ``use_pallas`` arm this backend takes."""
         return "always" if self.resolve() == "pallas" else "never"
 
+    def _kernel_arm(self, telemetry: bool) -> bool:
+        """True when an op runs the kernel data plane: on the pallas
+        backend, and whenever telemetry is asked for (the counter planes
+        exist only in the kernel bodies — ``kernels.ops._use_kernel``)."""
+        return telemetry or self.resolve() == "pallas"
+
     # ------------------------------------------------------------- ops --
 
     def lookup(self, state: jfilter.FilterState, hi: jax.Array,
-               lo: jax.Array) -> jax.Array:
+               lo: jax.Array, telemetry: bool = False):
         """Membership for a batch -> bool[N]."""
-        if self.resolve() == "pallas":
+        if self._kernel_arm(telemetry):
             return kops.probe_dispatch(state.table, hi, lo,
                                        fp_bits=self.fp_bits,
-                                       n_buckets=state.n_buckets)
+                                       n_buckets=state.n_buckets,
+                                       telemetry=telemetry)
         return jfilter.bulk_lookup(state, hi, lo, fp_bits=self.fp_bits)
 
     def insert(self, state: jfilter.FilterState, hi: jax.Array,
-               lo: jax.Array, valid: Optional[jax.Array] = None
-               ) -> tuple[jfilter.FilterState, jax.Array]:
+               lo: jax.Array, valid: Optional[jax.Array] = None,
+               telemetry: bool = False):
         """Bulk insert -> (state, ok[N]).
 
         pallas: ONE fused kernel pass — optimistic rounds plus bounded
@@ -153,15 +173,16 @@ class FilterOps:
         eviction-chain-scan path.  Either way a key that exhausts its
         budget reports False with the table rolled back (never corrupted).
         """
-        if self.resolve() == "pallas":
-            table, ok = kops.filter_insert(
+        if self._kernel_arm(telemetry):
+            table, ok, *tm = kops.filter_insert(
                 state.table, hi, lo, fp_bits=self.fp_bits,
                 n_buckets=state.n_buckets, valid=valid,
                 evict_rounds=self.evict_rounds, use_pallas="always",
-                schedule=self.schedule, donate=self.donate)
+                schedule=self.schedule, donate=self.donate,
+                telemetry=telemetry)
             return jfilter.FilterState(
                 table, state.count + jnp.sum(ok, dtype=jnp.int32),
-                state.n_buckets), ok
+                state.n_buckets), ok, *tm
         # Donation is a kernel-pipeline feature: wrapping the already-jitted
         # hybrid in a donating outer jit measured ~10% SLOWER on CPU (the
         # rewrap costs more than the one table copy it saves), so the jnp
@@ -172,26 +193,26 @@ class FilterOps:
     # ------------------------------------------------- stash-aware ops --
 
     def lookup_with_stash(self, state: jfilter.FilterState,
-                          stash: jax.Array, hi: jax.Array,
-                          lo: jax.Array) -> jax.Array:
+                          stash: jax.Array, hi: jax.Array, lo: jax.Array,
+                          telemetry: bool = False):
         """Membership against table AND overflow stash -> bool[N].
 
         pallas: the probe kernel checks the stash in the same fused pass.
         jnp: table probe OR'd with the jnp stash match — identical answers.
         """
-        if self.resolve() == "pallas":
+        if self._kernel_arm(telemetry):
             return kops.probe_dispatch(state.table, hi, lo,
                                        fp_bits=self.fp_bits,
                                        n_buckets=state.n_buckets,
-                                       stash=stash)
+                                       stash=stash, telemetry=telemetry)
         return kops.filter_lookup(state.table, hi, lo, fp_bits=self.fp_bits,
                                   n_buckets=state.n_buckets, stash=stash,
                                   use_pallas="never")
 
     def insert_spill(self, state: jfilter.FilterState, stash: jax.Array,
                      hi: jax.Array, lo: jax.Array,
-                     valid: Optional[jax.Array] = None
-                     ) -> tuple[jfilter.FilterState, jax.Array, jax.Array]:
+                     valid: Optional[jax.Array] = None,
+                     telemetry: bool = False):
         """Bulk insert that spills overflow to the stash
         -> (state, stash, ok[N]).
 
@@ -202,34 +223,34 @@ class FilterOps:
         ``kops.stash_occupancy`` so occupancy math stays honest.
         """
         spilled_before = kops.stash_occupancy(stash)
-        table, new_stash, ok = kops.filter_insert(
+        table, new_stash, ok, *tm = kops.filter_insert(
             state.table, hi, lo, fp_bits=self.fp_bits,
             n_buckets=state.n_buckets, valid=valid,
             evict_rounds=self.evict_rounds, stash=stash,
             max_disp=self.max_disp, use_pallas=self._use_pallas(),
-            schedule=self.schedule, donate=self.donate)
+            schedule=self.schedule, donate=self.donate, telemetry=telemetry)
         newly_stashed = kops.stash_occupancy(new_stash) - spilled_before
         count = state.count + jnp.sum(ok, dtype=jnp.int32) - newly_stashed
         return jfilter.FilterState(table, count, state.n_buckets), \
-            new_stash, ok
+            new_stash, ok, *tm
 
     def delete(self, state: jfilter.FilterState, hi: jax.Array,
-               lo: jax.Array, valid: Optional[jax.Array] = None
-               ) -> tuple[jfilter.FilterState, jax.Array]:
+               lo: jax.Array, valid: Optional[jax.Array] = None,
+               telemetry: bool = False):
         """Verified bulk delete -> (state, ok[N]).
 
         pallas: the fused first-match-slot kernel (``kernels.delete``).
         jnp: the sequential lax.scan path.  Both rank duplicate keys so the
         k-th duplicate clears the k-th resident copy; callers pre-verify
         membership against the keystore (the OCF control plane does)."""
-        if self.resolve() == "pallas":
-            table, ok = kops.filter_delete(
+        if self._kernel_arm(telemetry):
+            table, ok, *tm = kops.filter_delete(
                 state.table, hi, lo, fp_bits=self.fp_bits,
                 n_buckets=state.n_buckets, valid=valid, use_pallas="always",
-                donate=self.donate)
+                donate=self.donate, telemetry=telemetry)
             return jfilter.FilterState(
                 table, state.count - jnp.sum(ok, dtype=jnp.int32),
-                state.n_buckets), ok
+                state.n_buckets), ok, *tm
         return jfilter.bulk_delete(state, hi, lo, fp_bits=self.fp_bits,
                                    valid=valid)
 
@@ -272,7 +293,8 @@ class FilterOps:
     # so both backends are bit-for-bit by construction.
 
     def lookup_adaptive(self, state, hi: jax.Array, lo: jax.Array,
-                        stash: Optional[jax.Array] = None) -> jax.Array:
+                        stash: Optional[jax.Array] = None,
+                        telemetry: bool = False):
         """Selector-aware membership -> bool[N].
 
         A slot answers under ITS selector, so a repaired slot no longer
@@ -282,11 +304,12 @@ class FilterOps:
         return kops.adaptive_lookup(
             state.table, state.sels, hi, lo, fp_bits=self.fp_bits,
             n_buckets=state.n_buckets, stash=stash,
-            use_pallas=self._use_pallas())
+            use_pallas=self._use_pallas(), telemetry=telemetry)
 
     def insert_adaptive(self, state, hi: jax.Array, lo: jax.Array,
                         valid: Optional[jax.Array] = None,
-                        stash: Optional[jax.Array] = None):
+                        stash: Optional[jax.Array] = None,
+                        telemetry: bool = False):
         """Bulk insert over the adaptive planes -> (state, ok[N]) or
         (state, stash, ok[N]).
 
@@ -297,26 +320,28 @@ class FilterOps:
         """
         if stash is not None:
             spilled_before = kops.stash_occupancy(stash)
-        out = kops.adaptive_insert(
+        out, tm = _split_telemetry(kops.adaptive_insert(
             state.table, state.sels, state.khi, state.klo, hi, lo,
             fp_bits=self.fp_bits, n_buckets=state.n_buckets, valid=valid,
             evict_rounds=self.evict_rounds, stash=stash,
             use_pallas=self._use_pallas(),
-            schedule=self.schedule, donate=self.donate)
+            schedule=self.schedule, donate=self.donate,
+            telemetry=telemetry), telemetry)
         ok = out[-1]
         count = state.count + jnp.sum(ok, dtype=jnp.int32)
         if stash is None:
             table, sels, khi, klo = out[:4]
             return state._replace(table=table, sels=sels, khi=khi, klo=klo,
-                                  count=count), ok
+                                  count=count), ok, *tm
         table, sels, khi, klo, new_stash = out[:5]
         count = count - (kops.stash_occupancy(new_stash) - spilled_before)
         return state._replace(table=table, sels=sels, khi=khi, klo=klo,
-                              count=count), new_stash, ok
+                              count=count), new_stash, ok, *tm
 
     def delete_adaptive(self, state, hi: jax.Array, lo: jax.Array,
                         valid: Optional[jax.Array] = None,
-                        stash: Optional[jax.Array] = None):
+                        stash: Optional[jax.Array] = None,
+                        telemetry: bool = False):
         """Verified bulk delete -> (state, ok[N]) or (state, stash, ok[N]).
 
         Slots match under THEIR selector, so adapted residents stay
@@ -324,26 +349,27 @@ class FilterOps:
         lanes that miss the table clear their selector-0 stash entry in the
         composed jnp pass, same order as the static path.
         """
-        out = kops.adaptive_delete(
+        out, tm = _split_telemetry(kops.adaptive_delete(
             state.table, state.sels, state.khi, state.klo, hi, lo,
             fp_bits=self.fp_bits, n_buckets=state.n_buckets, valid=valid,
             stash=stash, use_pallas=self._use_pallas(),
-            donate=self.donate)
+            donate=self.donate, telemetry=telemetry), telemetry)
         ok = out[-1]
         if stash is None:
             table, sels, khi, klo = out[:4]
             count = state.count - jnp.sum(ok, dtype=jnp.int32)
             return state._replace(table=table, sels=sels, khi=khi, klo=klo,
-                                  count=count), ok
+                                  count=count), ok, *tm
         table, sels, khi, klo, new_stash = out[:5]
         stash_cleared = (kops.stash_occupancy(stash)
                          - kops.stash_occupancy(new_stash))
         count = state.count - jnp.sum(ok, dtype=jnp.int32) + stash_cleared
         return state._replace(table=table, sels=sels, khi=khi, klo=klo,
-                              count=count), new_stash, ok
+                              count=count), new_stash, ok, *tm
 
     def report_false_positive(self, state, hi: jax.Array, lo: jax.Array,
-                              valid: Optional[jax.Array] = None):
+                              valid: Optional[jax.Array] = None,
+                              telemetry: bool = False):
         """Feed confirmed false positives back -> (state, adapted[N],
         resident[N]).
 
@@ -357,10 +383,12 @@ class FilterOps:
         adapt (the stash has no selector) — repeat offenders are the
         reputation tier's job (``adaptive.reputation``).
         """
-        table, sels, adapted, resident = kops.adaptive_report(
+        table, sels, adapted, resident, *tm = kops.adaptive_report(
             state.table, state.sels, state.khi, state.klo, hi, lo,
-            fp_bits=self.fp_bits, n_buckets=state.n_buckets, valid=valid)
-        return state._replace(table=table, sels=sels), adapted, resident
+            fp_bits=self.fp_bits, n_buckets=state.n_buckets, valid=valid,
+            telemetry=telemetry)
+        return state._replace(table=table, sels=sels), adapted, resident, \
+            *tm
 
     # --------------------------------------------------- raw-table ops --
     #
@@ -413,149 +441,16 @@ class FilterOps:
 
     def delete_table(self, table: jax.Array, hi: jax.Array, lo: jax.Array, *,
                      n_buckets=None, valid: Optional[jax.Array] = None,
-                     stash=None):
+                     stash=None, telemetry: bool = False):
         """Raw-table verified delete -> (table, ok[N]) or
         (table, stash, ok[N]).
 
         Fused first-match-slot clear; with a ``stash``, lanes that miss the
         table clear their spilled entry (table copies first — the
         sequential order), so a burst-parked key is deletable like any
-        other.
+        other.  ``telemetry`` as on the stateful ops.
         """
         return kops.filter_delete(table, hi, lo, fp_bits=self.fp_bits,
                                   n_buckets=n_buckets, valid=valid,
-                                  stash=stash, use_pallas=self._use_pallas())
-
-    # --------------------------------------------------- telemetry twins --
-    #
-    # Each ``*_tm`` method is the corresponding op plus a device-computed
-    # ``kernels.telemetry.FilterTelemetry`` (kick-depth histogram, probe
-    # hit-depth, spill / rollback / delete counters, stash high-water).
-    # The twins pin the KERNEL arm (the XLA emulation of the kernel
-    # schedule — bit-for-bit the pallas_call by the PR-5 parity contract)
-    # and compile as separate jits, so:
-    #   * answers never depend on whether counters are on, and
-    #   * the telemetry-off methods above keep their exact dispatch —
-    #     nothing here runs unless a caller asks for telemetry.
-    # They are NOT in the hot path's method bodies on purpose: the
-    # dispatch-spy tier-1 test pins the off path to the pre-telemetry
-    # device-call sequence.
-
-    def lookup_tm(self, state: jfilter.FilterState, hi: jax.Array,
-                  lo: jax.Array):
-        """``lookup`` + telemetry -> (hit[N], FilterTelemetry)."""
-        return kops.probe_dispatch_tm(state.table, hi, lo,
-                                      fp_bits=self.fp_bits,
-                                      n_buckets=state.n_buckets)
-
-    def lookup_with_stash_tm(self, state: jfilter.FilterState,
-                             stash: jax.Array, hi: jax.Array, lo: jax.Array):
-        """``lookup_with_stash`` + telemetry -> (hit[N], FilterTelemetry)."""
-        return kops.probe_dispatch_tm(state.table, hi, lo,
-                                      fp_bits=self.fp_bits,
-                                      n_buckets=state.n_buckets, stash=stash)
-
-    def insert_tm(self, state: jfilter.FilterState, hi: jax.Array,
-                  lo: jax.Array, valid: Optional[jax.Array] = None):
-        """``insert`` + telemetry -> (state, ok[N], FilterTelemetry)."""
-        table, ok, tm = kops.filter_insert_tm(
-            state.table, hi, lo, fp_bits=self.fp_bits,
-            n_buckets=state.n_buckets, valid=valid,
-            evict_rounds=self.evict_rounds, schedule=self.schedule,
-            donate=self.donate)
-        return jfilter.FilterState(
-            table, state.count + jnp.sum(ok, dtype=jnp.int32),
-            state.n_buckets), ok, tm
-
-    def insert_spill_tm(self, state: jfilter.FilterState, stash: jax.Array,
-                        hi: jax.Array, lo: jax.Array,
-                        valid: Optional[jax.Array] = None):
-        """``insert_spill`` + telemetry -> (state, stash, ok[N], tm)."""
-        spilled_before = kops.stash_occupancy(stash)
-        table, new_stash, ok, tm = kops.filter_insert_tm(
-            state.table, hi, lo, fp_bits=self.fp_bits,
-            n_buckets=state.n_buckets, valid=valid,
-            evict_rounds=self.evict_rounds, stash=stash,
-            schedule=self.schedule, donate=self.donate)
-        newly_stashed = kops.stash_occupancy(new_stash) - spilled_before
-        count = state.count + jnp.sum(ok, dtype=jnp.int32) - newly_stashed
-        return jfilter.FilterState(table, count, state.n_buckets), \
-            new_stash, ok, tm
-
-    def delete_tm(self, state: jfilter.FilterState, hi: jax.Array,
-                  lo: jax.Array, valid: Optional[jax.Array] = None):
-        """``delete`` + telemetry -> (state, ok[N], FilterTelemetry)."""
-        table, ok, tm = kops.filter_delete_tm(
-            state.table, hi, lo, fp_bits=self.fp_bits,
-            n_buckets=state.n_buckets, valid=valid, donate=self.donate)
-        return jfilter.FilterState(
-            table, state.count - jnp.sum(ok, dtype=jnp.int32),
-            state.n_buckets), ok, tm
-
-    def delete_table_tm(self, table: jax.Array, hi: jax.Array,
-                        lo: jax.Array, *, n_buckets=None,
-                        valid: Optional[jax.Array] = None, stash=None):
-        """``delete_table`` + telemetry -> (..., ok[N], FilterTelemetry)."""
-        return kops.filter_delete_tm(table, hi, lo, fp_bits=self.fp_bits,
-                                     n_buckets=n_buckets, valid=valid,
-                                     stash=stash)
-
-    def lookup_adaptive_tm(self, state, hi: jax.Array, lo: jax.Array,
-                           stash: Optional[jax.Array] = None):
-        """``lookup_adaptive`` + telemetry -> (hit[N], FilterTelemetry)."""
-        return kops.adaptive_lookup_tm(
-            state.table, state.sels, hi, lo, fp_bits=self.fp_bits,
-            n_buckets=state.n_buckets, stash=stash)
-
-    def insert_adaptive_tm(self, state, hi: jax.Array, lo: jax.Array,
-                           valid: Optional[jax.Array] = None,
-                           stash: Optional[jax.Array] = None):
-        """``insert_adaptive`` + telemetry -> (..., ok[N], tm)."""
-        if stash is not None:
-            spilled_before = kops.stash_occupancy(stash)
-        out = kops.adaptive_insert_tm(
-            state.table, state.sels, state.khi, state.klo, hi, lo,
-            fp_bits=self.fp_bits, n_buckets=state.n_buckets, valid=valid,
-            evict_rounds=self.evict_rounds, stash=stash,
-            schedule=self.schedule, donate=self.donate)
-        tm = out[-1]
-        ok = out[-2]
-        count = state.count + jnp.sum(ok, dtype=jnp.int32)
-        if stash is None:
-            table, sels, khi, klo = out[:4]
-            return state._replace(table=table, sels=sels, khi=khi, klo=klo,
-                                  count=count), ok, tm
-        table, sels, khi, klo, new_stash = out[:5]
-        count = count - (kops.stash_occupancy(new_stash) - spilled_before)
-        return state._replace(table=table, sels=sels, khi=khi, klo=klo,
-                              count=count), new_stash, ok, tm
-
-    def delete_adaptive_tm(self, state, hi: jax.Array, lo: jax.Array,
-                           valid: Optional[jax.Array] = None,
-                           stash: Optional[jax.Array] = None):
-        """``delete_adaptive`` + telemetry -> (..., ok[N], tm)."""
-        out = kops.adaptive_delete_tm(
-            state.table, state.sels, state.khi, state.klo, hi, lo,
-            fp_bits=self.fp_bits, n_buckets=state.n_buckets, valid=valid,
-            stash=stash, donate=self.donate)
-        tm = out[-1]
-        ok = out[-2]
-        if stash is None:
-            table, sels, khi, klo = out[:4]
-            count = state.count - jnp.sum(ok, dtype=jnp.int32)
-            return state._replace(table=table, sels=sels, khi=khi, klo=klo,
-                                  count=count), ok, tm
-        table, sels, khi, klo, new_stash = out[:5]
-        stash_cleared = (kops.stash_occupancy(stash)
-                         - kops.stash_occupancy(new_stash))
-        count = state.count - jnp.sum(ok, dtype=jnp.int32) + stash_cleared
-        return state._replace(table=table, sels=sels, khi=khi, klo=klo,
-                              count=count), new_stash, ok, tm
-
-    def report_false_positive_tm(self, state, hi: jax.Array, lo: jax.Array,
-                                 valid: Optional[jax.Array] = None):
-        """``report_false_positive`` + telemetry (``selector_bumps``)."""
-        table, sels, adapted, resident, tm = kops.adaptive_report_tm(
-            state.table, state.sels, state.khi, state.klo, hi, lo,
-            fp_bits=self.fp_bits, n_buckets=state.n_buckets, valid=valid)
-        return state._replace(table=table, sels=sels), adapted, resident, tm
+                                  stash=stash, use_pallas=self._use_pallas(),
+                                  telemetry=telemetry)

@@ -239,7 +239,7 @@ def _evict_rounds(table, owner, base, fp, start_bucket, residue, n_buckets,
         jnp.any(failed),
         lambda tc: jax.lax.fori_loop(0, rounds, rb_body, tc),
         lambda tc: tc, (table, carried))
-    # Telemetry-twin extras: per-lane chain length + spill/rollback masks
+    # Telemetry extras: per-lane chain length + spill/rollback masks
     # (the raw material the dispatch layer folds into FilterTelemetry).
     stats = (steps, spilled, failed) if want_stats else None
     return table, owner, stash, residue & ~failed, stats
@@ -401,11 +401,12 @@ def _insert_bulk_impl(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
         perm, inv = dispatch_order(hi, lo, valid, n_buckets=n_buckets)
         hi, lo, valid = hi[perm], lo[perm], valid[perm]
     if telemetry:
-        # Telemetry twin: always the XLA-emulation arm (same bits as the
-        # kernel by the PR-5 parity contract).  ``ops.LOWERING`` gives every
-        # insert the "xla" form, so on a TPU the twin and the telemetry-off
-        # path run the same body; a port of the insert to Mosaic has to
-        # bring this counter plane along.  The per-lane stats are
+        # The counter plane exists only in the XLA-emulation arm (same bits
+        # as the kernel by the PR-5 parity contract), so telemetry takes it
+        # whatever ``emulate`` says.  ``ops.LOWERING`` gives every insert
+        # the "xla" form, so on a TPU telemetry on and off run the same
+        # body; a port of the insert to Mosaic has to bring this counter
+        # plane along.  The per-lane stats are
         # permutation-invariant sums/histograms, so the schedule pre-pass
         # needs no inverse scatter on the telemetry, only on ``ok``.
         new_table, new_stash, ok, tm = _emulated_insert(
@@ -464,7 +465,7 @@ def _insert_bulk_impl(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
 
 
 _INSERT_STATICS = ("fp_bits", "evict_rounds", "block", "interpret",
-                   "emulate", "schedule")
+                   "emulate", "schedule", "telemetry")
 _insert_bulk_jit = jax.jit(_insert_bulk_impl, static_argnames=_INSERT_STATICS)
 # Donating twin: the caller hands over the table (and stash) buffers, so
 # XLA writes the output state into them instead of copying the pow2 buffer
@@ -475,15 +476,6 @@ _insert_bulk_jit = jax.jit(_insert_bulk_impl, static_argnames=_INSERT_STATICS)
 _insert_bulk_donated = jax.jit(_insert_bulk_impl,
                                static_argnames=_INSERT_STATICS,
                                donate_argnames=("table", "stash"))
-# Telemetry twins: separate jit objects, so the telemetry-off entry above
-# keeps its exact cache keys and dispatch path — enabling counters never
-# recompiles or re-routes the hot path.
-_INSERT_TM_STATICS = _INSERT_STATICS + ("telemetry",)
-_insert_bulk_tm_jit = jax.jit(_insert_bulk_impl,
-                              static_argnames=_INSERT_TM_STATICS)
-_insert_bulk_tm_donated = jax.jit(_insert_bulk_impl,
-                                  static_argnames=_INSERT_TM_STATICS,
-                                  donate_argnames=("table", "stash"))
 
 
 def insert_bulk(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
@@ -491,7 +483,7 @@ def insert_bulk(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
                 evict_rounds: int = DEFAULT_EVICT_ROUNDS, stash=None,
                 block: int = DEFAULT_BLOCK, interpret: bool = True,
                 emulate: bool = False, schedule: bool = False,
-                donate: bool = False):
+                donate: bool = False, telemetry: bool = False):
     """Full bulk insert (optimistic rounds + bounded eviction rounds)
     -> (new_table, placed bool[N]), or (new_table, new_stash, placed) when
     an overflow ``stash`` (``kernels.stash.make_stash``) is attached.
@@ -515,35 +507,19 @@ def insert_bulk(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
         scatter ``placed`` back, cutting intra-batch rank races and
         eviction rounds for contended batches;
       * ``donate``   — donate the table/stash buffers to the call (zero-copy
-        update; the caller's input arrays are consumed).
+        update; the caller's input arrays are consumed);
+      * ``telemetry`` — append a ``FilterTelemetry`` (kick-depth histogram,
+        spill / rollback counts, stash fill high-water) to the results.
+        It runs the emulation arm whatever ``emulate`` says, with the same
+        placement bits, so answers never depend on whether counters are
+        on.  Being static, each value is its own program: the one for
+        ``telemetry=False`` has no counter plane.
     """
     fn = _insert_bulk_donated if donate else _insert_bulk_jit
     return fn(table, hi, lo, fp_bits=fp_bits, n_buckets=n_buckets,
               valid=valid, evict_rounds=evict_rounds, stash=stash,
               block=block, interpret=interpret, emulate=emulate,
-              schedule=schedule)
-
-
-def insert_bulk_tm(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
-                   fp_bits: int, n_buckets=None, valid=None,
-                   evict_rounds: int = DEFAULT_EVICT_ROUNDS, stash=None,
-                   block: int = DEFAULT_BLOCK, schedule: bool = False,
-                   donate: bool = False):
-    """Telemetry twin of ``insert_bulk`` -> the same results plus a
-    ``FilterTelemetry`` (kick-depth histogram, spill / rollback counts,
-    stash fill high-water).
-
-    Same placement bits as ``insert_bulk`` — the twin runs the XLA
-    emulation arm of the kernel schedule (bit-for-bit by the PR-5 parity
-    contract), so answers never depend on whether counters are on.
-    Compiled as its own jit: calling this never touches the telemetry-off
-    entry's cache or dispatch.
-    """
-    fn = _insert_bulk_tm_donated if donate else _insert_bulk_tm_jit
-    return fn(table, hi, lo, fp_bits=fp_bits, n_buckets=n_buckets,
-              valid=valid, evict_rounds=evict_rounds, stash=stash,
-              block=block, interpret=False, emulate=True, schedule=schedule,
-              telemetry=True)
+              schedule=schedule, telemetry=telemetry)
 
 
 # ------------------------------------------- selector-aware (adaptive) -----
@@ -843,7 +819,7 @@ def _insert_adaptive_impl(table, sels, khi_t, klo_t, hi, lo, *, fp_bits: int,
         perm, inv = dispatch_order(hi, lo, valid, n_buckets=n_buckets)
         hi, lo, valid = hi[perm], lo[perm], valid[perm]
     if telemetry:
-        # Telemetry twin — emulation arm, same bits (see _insert_bulk_impl).
+        # Emulation arm, same bits (see _insert_bulk_impl).
         table, sels, khi_t, klo_t, stash, ok, tm = _emulated_insert_adaptive(
             table, sels, khi_t, klo_t, stash, hi, lo, valid, n_buckets,
             fp_bits=fp_bits, evict_rounds=evict_rounds, block=block,
@@ -913,11 +889,6 @@ _insert_adaptive_jit = jax.jit(_insert_adaptive_impl,
 _insert_adaptive_donated = jax.jit(
     _insert_adaptive_impl, static_argnames=_INSERT_STATICS,
     donate_argnames=("table", "sels", "khi_t", "klo_t", "stash"))
-_insert_adaptive_tm_jit = jax.jit(_insert_adaptive_impl,
-                                  static_argnames=_INSERT_TM_STATICS)
-_insert_adaptive_tm_donated = jax.jit(
-    _insert_adaptive_impl, static_argnames=_INSERT_TM_STATICS,
-    donate_argnames=("table", "sels", "khi_t", "klo_t", "stash"))
 
 
 def insert_bulk_adaptive(table, sels, khi_t, klo_t, hi, lo, *, fp_bits: int,
@@ -925,7 +896,7 @@ def insert_bulk_adaptive(table, sels, khi_t, klo_t, hi, lo, *, fp_bits: int,
                          evict_rounds: int = DEFAULT_EVICT_ROUNDS, stash=None,
                          block: int = DEFAULT_BLOCK, interpret: bool = True,
                          emulate: bool = False, schedule: bool = False,
-                         donate: bool = False):
+                         donate: bool = False, telemetry: bool = False):
     """Selector-aware bulk insert over the four adaptive planes
     -> (table, sels, khi, klo, placed) or (..., stash, placed).
 
@@ -938,21 +909,7 @@ def insert_bulk_adaptive(table, sels, khi_t, klo_t, hi, lo, *, fp_bits: int,
     return fn(table, sels, khi_t, klo_t, hi, lo, fp_bits=fp_bits,
               n_buckets=n_buckets, valid=valid, evict_rounds=evict_rounds,
               stash=stash, block=block, interpret=interpret, emulate=emulate,
-              schedule=schedule)
-
-
-def insert_bulk_adaptive_tm(table, sels, khi_t, klo_t, hi, lo, *,
-                            fp_bits: int, n_buckets=None, valid=None,
-                            evict_rounds: int = DEFAULT_EVICT_ROUNDS,
-                            stash=None, block: int = DEFAULT_BLOCK,
-                            schedule: bool = False, donate: bool = False):
-    """Telemetry twin of ``insert_bulk_adaptive`` — same results plus a
-    ``FilterTelemetry``; own jit, emulation arm (see ``insert_bulk_tm``)."""
-    fn = _insert_adaptive_tm_donated if donate else _insert_adaptive_tm_jit
-    return fn(table, sels, khi_t, klo_t, hi, lo, fp_bits=fp_bits,
-              n_buckets=n_buckets, valid=valid, evict_rounds=evict_rounds,
-              stash=stash, block=block, interpret=False, emulate=True,
-              schedule=schedule, telemetry=True)
+              schedule=schedule, telemetry=telemetry)
 
 
 def insert_once(table: jax.Array, hi: jax.Array, lo: jax.Array, *,

@@ -11,6 +11,13 @@ that kernel arm and the pure-jnp oracle (``ref.py``): on TPU 'auto' always
 takes the kernel arm; off TPU it takes it for batches and tables the VMEM
 model admits.
 
+Telemetry: each table-op entry takes a ``telemetry`` flag (trace-time, so
+each value is its own program) and, when it is set, returns its usual
+outputs plus a trailing ``FilterTelemetry``.  ``telemetry=True`` takes the
+kernel arm whatever ``use_pallas`` says, in its emulated form: the counter
+planes exist only in the kernel bodies (``_use_kernel`` is where that rule
+lives).
+
 Per-op BLOCK sizes come from ``autotune_block`` — the same VMEM footprint
 model ``kernel_vmem_bytes`` gives the 'auto' dispatch, inverted: pick the
 block that balances the [BLOCK, BLOCK] rank working set (cost grows with
@@ -35,12 +42,10 @@ from repro.kernels.delete import delete_bulk, delete_bulk_adaptive
 from repro.kernels.fingerprint import fingerprint_hash, fingerprint_hash_family
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.insert import (DEFAULT_EVICT_ROUNDS, insert_bulk,
-                                  insert_bulk_adaptive, insert_bulk_adaptive_tm,
-                                  insert_bulk_tm, insert_once)
+                                  insert_bulk_adaptive, insert_once)
 from repro.kernels.probe import (probe, probe_adaptive,
-                                 probe_adaptive_emulated,
-                                 probe_adaptive_emulated_tm, probe_emulated,
-                                 probe_emulated_tm, probe_multi)
+                                 probe_adaptive_emulated, probe_emulated,
+                                 probe_multi)
 from repro.kernels.selector import (make_key_planes, make_sel_plane,
                                     report_adapt)
 from repro.kernels.stash import (DEFAULT_STASH_SLOTS, make_stash,
@@ -186,9 +191,12 @@ def delete_block(n_keys: int, *, table_bytes: int) -> int:
                               n_keys=n_keys), n_keys)
 
 
-def _use_kernel(use_pallas: str, *, vmem_bytes: int, n_keys: int) -> bool:
+def _use_kernel(use_pallas: str, *, vmem_bytes: int, n_keys: int,
+                telemetry: bool = False) -> bool:
     """True when the kernel arm should run (vs the pure-jnp ref path).
 
+    ``telemetry`` -> kernel arm, unconditionally: the counter planes exist
+                only in the kernel bodies.
     'always' -> kernel arm, unconditionally.
     'never'  -> ref path, unconditionally.
     'auto'   -> kernel arm on TPU for every size (its form comes from
@@ -197,6 +205,8 @@ def _use_kernel(use_pallas: str, *, vmem_bytes: int, n_keys: int) -> bool:
                 ``kernel_vmem_bytes``) fits the budget and the batch has at
                 most 65,536 keys.
     """
+    if telemetry:
+        return True
     if use_pallas == "never":
         return False
     if use_pallas == "always" or _on_tpu():
@@ -216,6 +226,27 @@ def _unpad(x: jax.Array, n: int):
     # Skip the slice when the batch needed no padding: an eager x[:n] is a
     # dispatched device op, and on the hot lookup path it is pure overhead.
     return x if x.shape[0] == n else x[:n]
+
+
+def _unpad_ok(out: tuple, n: int, telemetry: bool) -> tuple:
+    """A mutating kernel's outputs with its per-lane ``ok`` (the last
+    output, or the one before the trailing ``FilterTelemetry``) unpadded."""
+    if telemetry:
+        return (*out[:-2], _unpad(out[-2], n), out[-1])
+    return (*out[:-1], _unpad(out[-1], n))
+
+
+def _empty_result(state: tuple, telemetry: bool) -> tuple:
+    """An empty batch's outputs: ``state`` unchanged, a zero-lane ``ok``,
+    and an all-zero ``FilterTelemetry`` when asked for."""
+    out = (*state, jnp.zeros((0,), jnp.bool_))
+    return (*out, empty_telemetry()) if telemetry else out
+
+
+def _probe_telemetry(out) -> tuple:
+    """(hit, probe_depth) from an emulated probe -> (hit, FilterTelemetry)."""
+    hit, depth = out
+    return hit, empty_telemetry()._replace(probe_depth=depth)
 
 
 def hash_keys(hi: jax.Array, lo: jax.Array, *, fp_bits: int, n_buckets: int,
@@ -322,18 +353,25 @@ def _probe_plan(fp_bits: int, table_shape: tuple, stash_slots: int):
 
 
 def probe_dispatch(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
-                   fp_bits: int, n_buckets=None, stash=None) -> jax.Array:
+                   fp_bits: int, n_buckets=None, stash=None,
+                   telemetry: bool = False):
     """``filter_lookup`` with the kernel arm pinned (use_pallas='always'),
     skipping the per-call block/VMEM re-derivation — the one-jit-dispatch
-    fast path ``FilterOps.lookup`` takes on the pallas backend."""
+    fast path ``FilterOps.lookup`` takes on the pallas backend.
+    -> hit[N], or (hit, FilterTelemetry) with the probe-depth counts."""
     if hi.shape[0] == 0:
-        return jnp.zeros((0,), jnp.bool_)
+        hit = jnp.zeros((0,), jnp.bool_)
+        return (hit, empty_telemetry()) if telemetry else hit
     stash_slots = 0 if stash is None else stash.shape[1]
     block, emul = _probe_plan(fp_bits, table.shape, stash_slots)
-    if emul:
+    if emul or telemetry:
         # No padding needed: the emulated body is gridless.
         if n_buckets is None:
             n_buckets = table.shape[0]
+        if telemetry:
+            return _probe_telemetry(probe_emulated(
+                table, hi, lo, n_buckets, stash, fp_bits=fp_bits,
+                telemetry=True))
         return probe_emulated(table, hi, lo, n_buckets, stash,
                               fp_bits=fp_bits)
     b = min(block, hi.shape[0])
@@ -342,24 +380,6 @@ def probe_dispatch(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
     hit = probe(table, hi_p, lo_p, fp_bits=fp_bits, n_buckets=n_buckets,
                 stash=stash, block=b, interpret=False)
     return _unpad(hit, n)
-
-
-def probe_dispatch_tm(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
-                      fp_bits: int, n_buckets=None, stash=None):
-    """Telemetry twin of ``probe_dispatch`` -> (hit, FilterTelemetry).
-
-    Runs the gridless emulated probe body (bit-for-bit the kernel's
-    answers — the PR-5 parity contract) plus the probe-depth counter
-    plane.  Separate jit under the hood (``probe_emulated_tm``), so the
-    telemetry-off lookup's dispatch is untouched.
-    """
-    if hi.shape[0] == 0:
-        return jnp.zeros((0,), jnp.bool_), empty_telemetry()
-    if n_buckets is None:
-        n_buckets = table.shape[0]
-    hit, depth = probe_emulated_tm(table, hi, lo, n_buckets, stash,
-                                   fp_bits=fp_bits)
-    return hit, empty_telemetry()._replace(probe_depth=depth)
 
 
 def multi_prober(tables: jax.Array, *, fp_bits: int, n_buckets=None,
@@ -410,9 +430,12 @@ def filter_insert(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
                   fp_bits: int, n_buckets=None, valid=None,
                   evict_rounds: int = 0, stash=None, max_disp: int = 500,
                   use_pallas: str = "auto", schedule: bool = False,
-                  donate: bool = False):
+                  donate: bool = False, telemetry: bool = False):
     """Fused bulk insert -> (new_table, placed bool[N]), or
-    (new_table, new_stash, placed) when an overflow ``stash`` is attached.
+    (new_table, new_stash, placed) when an overflow ``stash`` is attached;
+    ``telemetry`` appends a ``FilterTelemetry`` (kick-depth histogram,
+    spill/rollback counts, stash fill high-water; padding lanes ride
+    ``valid=False`` and are in no counter).
 
     With ``evict_rounds=0`` this is the PR-1 optimistic single round — the
     fast path for ~95% of a batch, with the caller sweeping the residue.
@@ -432,9 +455,8 @@ def filter_insert(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
     fingerprint of an exhausted chain physically sits in the stash.
     """
     if hi.shape[0] == 0:
-        empty_ok = jnp.zeros((0,), jnp.bool_)
-        return (table, empty_ok) if stash is None else (table, stash,
-                                                        empty_ok)
+        return _empty_result((table,) if stash is None else (table, stash),
+                             telemetry)
     if valid is None:
         valid = jnp.ones(hi.shape, bool)
     stash_slots = 0 if stash is None else stash.shape[1]
@@ -447,7 +469,7 @@ def filter_insert(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
                            "insert", table_bytes=table.size * 4, block=block,
                            evict_rounds=evict_rounds,
                            stash_slots=stash_slots),
-                       n_keys=hi.shape[0]):
+                       n_keys=hi.shape[0], telemetry=telemetry):
         table, placed = ref.insert_once_ref(table, hi, lo, fp_bits=fp_bits,
                                             n_buckets=n_buckets, valid=valid)
         if evict_rounds > 0:
@@ -466,27 +488,22 @@ def filter_insert(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
     hi_p, n = _pad_to(hi, block)
     lo_p, _ = _pad_to(lo, block)
     valid_p, _ = _pad_to(valid, block)   # pads False: never touches the table
-    if stash is None:
-        new_table, ok = insert_bulk(table, hi_p, lo_p, fp_bits=fp_bits,
-                                    n_buckets=n_buckets, valid=valid_p,
-                                    evict_rounds=evict_rounds,
-                                    block=block, interpret=False,
-                                    emulate=lowering("insert") == "xla",
-                                    schedule=schedule, donate=donate)
-        return new_table, _unpad(ok, n)
-    new_table, new_stash, ok = insert_bulk(
-        table, hi_p, lo_p, fp_bits=fp_bits, n_buckets=n_buckets,
-        valid=valid_p, evict_rounds=evict_rounds, stash=stash, block=block,
-        interpret=False, emulate=lowering("insert_stash") == "xla",
-        schedule=schedule, donate=donate)
-    return new_table, new_stash, _unpad(ok, n)
+    form = lowering("insert" if stash is None else "insert_stash")
+    out = insert_bulk(table, hi_p, lo_p, fp_bits=fp_bits, n_buckets=n_buckets,
+                      valid=valid_p, evict_rounds=evict_rounds, stash=stash,
+                      block=block, interpret=False, emulate=form == "xla",
+                      schedule=schedule, donate=donate, telemetry=telemetry)
+    return _unpad_ok(out, n, telemetry)
 
 
 def filter_delete(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
                   fp_bits: int, n_buckets=None, valid=None, stash=None,
-                  use_pallas: str = "auto", donate: bool = False):
+                  use_pallas: str = "auto", donate: bool = False,
+                  telemetry: bool = False):
     """Fused bulk delete -> (new_table, deleted bool[N]), or
-    (new_table, new_stash, deleted) when an overflow ``stash`` is attached.
+    (new_table, new_stash, deleted) when an overflow ``stash`` is attached;
+    ``telemetry`` appends a ``FilterTelemetry`` counting table- vs
+    stash-resolved deletes.
 
     Device-side first-match-slot clearing via ``kernels.delete``; the
     non-kernel path falls back to the sequential ``lax.scan`` oracle
@@ -501,16 +518,15 @@ def filter_delete(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
     included.
     """
     if hi.shape[0] == 0:
-        empty_ok = jnp.zeros((0,), jnp.bool_)
-        return (table, empty_ok) if stash is None else (table, stash,
-                                                        empty_ok)
+        return _empty_result((table,) if stash is None else (table, stash),
+                             telemetry)
     if valid is None:
         valid = jnp.ones(hi.shape, bool)
     block = delete_block(hi.shape[0], table_bytes=table.size * 4)
     if not _use_kernel(use_pallas,
                        vmem_bytes=kernel_vmem_bytes(
                            "delete", table_bytes=table.size * 4, block=block),
-                       n_keys=hi.shape[0]):
+                       n_keys=hi.shape[0], telemetry=telemetry):
         new_table, ok = ref.delete_ref(table, hi, lo, fp_bits=fp_bits,
                                        n_buckets=n_buckets, valid=valid)
     else:
@@ -523,88 +539,26 @@ def filter_delete(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
                                     emulate=lowering("delete") == "xla",
                                     donate=donate)
         ok = _unpad(ok, n)
+    return _delete_result((new_table,), ok, hi, lo, valid, stash,
+                          fp_bits=fp_bits, n_buckets=n_buckets,
+                          telemetry=telemetry)
+
+
+def _delete_result(planes: tuple, ok, hi, lo, valid, stash, *, fp_bits: int,
+                   n_buckets, telemetry: bool) -> tuple:
+    """A delete's outputs from its cleared ``planes`` and table ``ok``:
+    with a ``stash``, lanes that missed the table clear their stash entry
+    (the composed jnp pass); with ``telemetry``, the counter plane."""
     if stash is None:
-        return new_table, ok
-    nb = table.shape[0] if n_buckets is None else n_buckets
+        out = (*planes, ok)
+        return (*out, _delete_tm_plane(ok)) if telemetry else out
+    nb = planes[0].shape[0] if n_buckets is None else n_buckets
     stash, cleared = stash_delete_ref(stash, hi, lo, valid & ~ok,
                                       fp_bits=fp_bits, n_buckets=nb)
-    return new_table, stash, ok | cleared
-
-
-def filter_insert_tm(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
-                     fp_bits: int, n_buckets=None, valid=None,
-                     evict_rounds: int = 0, stash=None,
-                     schedule: bool = False, donate: bool = False):
-    """Telemetry twin of ``filter_insert`` (kernel arm pinned) -> the same
-    results plus a ``FilterTelemetry`` with the kick-depth histogram,
-    spill/rollback counts, and stash fill high-water.
-
-    Padding lanes ride ``valid=False`` and are excluded from every counter
-    (the histogram masks on ``valid``), so the counters describe exactly
-    the caller's batch.
-    """
-    if hi.shape[0] == 0:
-        empty_ok = jnp.zeros((0,), jnp.bool_)
-        tm = empty_telemetry()
-        return ((table, empty_ok, tm) if stash is None
-                else (table, stash, empty_ok, tm))
-    if valid is None:
-        valid = jnp.ones(hi.shape, bool)
-    stash_slots = 0 if stash is None else stash.shape[1]
-    block = min(autotune_block("insert", table_bytes=table.size * 4,
-                               evict_rounds=evict_rounds,
-                               stash_slots=stash_slots,
-                               n_keys=hi.shape[0]), hi.shape[0])
-    hi_p, n = _pad_to(hi, block)
-    lo_p, _ = _pad_to(lo, block)
-    valid_p, _ = _pad_to(valid, block)   # pads False: never touches the table
-    if stash is None:
-        new_table, ok, tm = insert_bulk_tm(
-            table, hi_p, lo_p, fp_bits=fp_bits, n_buckets=n_buckets,
-            valid=valid_p, evict_rounds=evict_rounds, block=block,
-            schedule=schedule, donate=donate)
-        return new_table, _unpad(ok, n), tm
-    new_table, new_stash, ok, tm = insert_bulk_tm(
-        table, hi_p, lo_p, fp_bits=fp_bits, n_buckets=n_buckets,
-        valid=valid_p, evict_rounds=evict_rounds, stash=stash, block=block,
-        schedule=schedule, donate=donate)
-    return new_table, new_stash, _unpad(ok, n), tm
-
-
-def filter_delete_tm(table: jax.Array, hi: jax.Array, lo: jax.Array, *,
-                     fp_bits: int, n_buckets=None, valid=None, stash=None,
-                     donate: bool = False):
-    """Telemetry twin of ``filter_delete`` -> the same results plus a
-    ``FilterTelemetry`` counting table- vs stash-resolved deletes.
-
-    The delete kernels already return everything the counters need, so
-    this twin is pure ops-level assembly — same kernel calls, two extra
-    reductions.
-    """
-    if hi.shape[0] == 0:
-        empty_ok = jnp.zeros((0,), jnp.bool_)
-        tm = empty_telemetry()
-        return ((table, empty_ok, tm) if stash is None
-                else (table, stash, empty_ok, tm))
-    if valid is None:
-        valid = jnp.ones(hi.shape, bool)
-    block = delete_block(hi.shape[0], table_bytes=table.size * 4)
-    hi_p, n = _pad_to(hi, block)
-    lo_p, _ = _pad_to(lo, block)
-    valid_p, _ = _pad_to(valid, block)   # pads False: never touches table
-    new_table, ok = delete_bulk(table, hi_p, lo_p, fp_bits=fp_bits,
-                                n_buckets=n_buckets, valid=valid_p,
-                                block=block, interpret=False,
-                                emulate=lowering("delete") == "xla",
-                                donate=donate)
-    ok = _unpad(ok, n)
-    if stash is None:
-        return new_table, ok, _delete_tm_plane(ok)
-    nb = table.shape[0] if n_buckets is None else n_buckets
-    stash, cleared = stash_delete_ref(stash, hi, lo, valid & ~ok,
-                                      fp_bits=fp_bits, n_buckets=nb)
-    return (new_table, stash, ok | cleared,
-            _delete_tm_plane_stash(ok, cleared, stash))
+    out = (*planes, stash, ok | cleared)
+    if telemetry:
+        return (*out, _delete_tm_plane_stash(ok, cleared, stash))
+    return out
 
 
 @jax.jit
@@ -643,15 +597,18 @@ def _adaptive_plane_bytes(table: jax.Array) -> int:
 
 def adaptive_lookup(table: jax.Array, sels: jax.Array, hi: jax.Array,
                     lo: jax.Array, *, fp_bits: int, n_buckets=None,
-                    stash=None, use_pallas: str = "auto") -> jax.Array:
-    """Selector-aware bulk membership -> bool[N].
+                    stash=None, use_pallas: str = "auto",
+                    telemetry: bool = False):
+    """Selector-aware bulk membership -> bool[N], or (hit,
+    FilterTelemetry) with the probe-depth counts.
 
     A slot hits when its stored fingerprint equals the query's family
     fingerprint **under that slot's selector**; stash entries always hold
     selector-0 fingerprints and are matched in the same pass.
     """
     if hi.shape[0] == 0:
-        return jnp.zeros((0,), jnp.bool_)
+        hit = jnp.zeros((0,), jnp.bool_)
+        return (hit, empty_telemetry()) if telemetry else hit
     table_bytes = _adaptive_plane_bytes(table)
     stash_slots = 0 if stash is None else stash.shape[1]
     block = min(autotune_block("probe", table_bytes=table_bytes,
@@ -661,11 +618,15 @@ def adaptive_lookup(table: jax.Array, sels: jax.Array, hi: jax.Array,
                              "probe", table_bytes=table_bytes, block=block,
                              stash_slots=stash_slots),
                          n_keys=hi.shape[0])
-    if not kernel or lowering("probe_adaptive") == "xla":
+    if telemetry or not kernel or lowering("probe_adaptive") == "xla":
         if n_buckets is None:
             n_buckets = table.shape[0]
-        return probe_adaptive_emulated(table, sels, hi.astype(jnp.uint32),
-                                       lo.astype(jnp.uint32), n_buckets,
+        hi, lo = hi.astype(jnp.uint32), lo.astype(jnp.uint32)
+        if telemetry:
+            return _probe_telemetry(probe_adaptive_emulated(
+                table, sels, hi, lo, n_buckets, stash, fp_bits=fp_bits,
+                telemetry=True))
+        return probe_adaptive_emulated(table, sels, hi, lo, n_buckets,
                                        stash, fp_bits=fp_bits)
     hi_p, n = _pad_to(hi, block)
     lo_p, _ = _pad_to(lo, block)
@@ -680,19 +641,20 @@ def adaptive_insert(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
                     fp_bits: int, n_buckets=None, valid=None,
                     evict_rounds: int = 0, stash=None,
                     use_pallas: str = "auto", schedule: bool = False,
-                    donate: bool = False):
+                    donate: bool = False, telemetry: bool = False):
     """Fused bulk insert over the adaptive planes
-    -> (table, sels, khi, klo, placed) or (..., stash, placed).
+    -> (table, sels, khi, klo, placed) or (..., stash, placed), plus a
+    trailing ``FilterTelemetry`` with ``telemetry``.
 
     Same contract as ``filter_insert``; placements and kicks write
     selector-0 entries with the key mirrored into khi/klo, so eviction
     chains re-derive victim geometry exactly and rollback restores all
     four planes verbatim.
     """
+    planes = (table, sels, khi_t, klo_t)
     if hi.shape[0] == 0:
-        empty_ok = jnp.zeros((0,), jnp.bool_)
-        return ((table, sels, khi_t, klo_t, empty_ok) if stash is None
-                else (table, sels, khi_t, klo_t, stash, empty_ok))
+        return _empty_result(planes if stash is None else (*planes, stash),
+                             telemetry)
     if valid is None:
         valid = jnp.ones(hi.shape, bool)
     table_bytes = _adaptive_plane_bytes(table)
@@ -709,36 +671,38 @@ def adaptive_insert(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
                              "insert", table_bytes=table_bytes, block=block,
                              evict_rounds=2 * evict_rounds,
                              stash_slots=stash_slots),
-                         n_keys=hi.shape[0])
+                         n_keys=hi.shape[0], telemetry=telemetry)
     emul = (not kernel) or lowering("insert_adaptive") == "xla"
     hi_p, n = _pad_to(hi, block)
     lo_p, _ = _pad_to(lo, block)
     valid_p, _ = _pad_to(valid, block)   # pads False: never touches planes
-    out = insert_bulk_adaptive(table, sels, khi_t, klo_t, hi_p, lo_p,
-                               fp_bits=fp_bits, n_buckets=n_buckets,
-                               valid=valid_p, evict_rounds=evict_rounds,
-                               stash=stash, block=block,
-                               interpret=False, emulate=emul,
-                               schedule=schedule, donate=donate)
-    return (*out[:-1], _unpad(out[-1], n))
+    out = insert_bulk_adaptive(*planes, hi_p, lo_p, fp_bits=fp_bits,
+                               n_buckets=n_buckets, valid=valid_p,
+                               evict_rounds=evict_rounds, stash=stash,
+                               block=block, interpret=False, emulate=emul,
+                               schedule=schedule, donate=donate,
+                               telemetry=telemetry)
+    return _unpad_ok(out, n, telemetry)
 
 
 def adaptive_delete(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
                     klo_t: jax.Array, hi: jax.Array, lo: jax.Array, *,
                     fp_bits: int, n_buckets=None, valid=None, stash=None,
-                    use_pallas: str = "auto", donate: bool = False):
+                    use_pallas: str = "auto", donate: bool = False,
+                    telemetry: bool = False):
     """Fused bulk delete over the adaptive planes
-    -> (table, sels, khi, klo, deleted) or (..., stash, deleted).
+    -> (table, sels, khi, klo, deleted) or (..., stash, deleted), plus a
+    trailing ``FilterTelemetry`` with ``telemetry``.
 
     Slots are matched under THEIR selector (adapted residents stay
     deletable); clearing zeroes all four planes.  Stash entries hold
     selector-0 fingerprints, so lanes that miss the table compose the same
     jnp ``stash_delete_ref`` pass as the static path.
     """
+    planes = (table, sels, khi_t, klo_t)
     if hi.shape[0] == 0:
-        empty_ok = jnp.zeros((0,), jnp.bool_)
-        return ((table, sels, khi_t, klo_t, empty_ok) if stash is None
-                else (table, sels, khi_t, klo_t, stash, empty_ok))
+        return _empty_result(planes if stash is None else (*planes, stash),
+                             telemetry)
     if valid is None:
         valid = jnp.ones(hi.shape, bool)
     table_bytes = _adaptive_plane_bytes(table)
@@ -746,132 +710,42 @@ def adaptive_delete(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
     kernel = _use_kernel(use_pallas,
                          vmem_bytes=kernel_vmem_bytes(
                              "delete", table_bytes=table_bytes, block=block),
-                         n_keys=hi.shape[0])
+                         n_keys=hi.shape[0], telemetry=telemetry)
     emul = (not kernel) or lowering("delete_adaptive") == "xla"
     hi_p, n = _pad_to(hi, block)
     lo_p, _ = _pad_to(lo, block)
     valid_p, _ = _pad_to(valid, block)   # pads False: never touches planes
-    table, sels, khi_t, klo_t, ok = delete_bulk_adaptive(
-        table, sels, khi_t, klo_t, hi_p, lo_p, fp_bits=fp_bits,
-        n_buckets=n_buckets, valid=valid_p, block=block,
-        interpret=False, emulate=emul, donate=donate)
-    ok = _unpad(ok, n)
-    if stash is None:
-        return table, sels, khi_t, klo_t, ok
-    nb = table.shape[0] if n_buckets is None else n_buckets
-    stash, cleared = stash_delete_ref(stash, hi, lo, valid & ~ok,
-                                      fp_bits=fp_bits, n_buckets=nb)
-    return table, sels, khi_t, klo_t, stash, ok | cleared
+    out = delete_bulk_adaptive(*planes, hi_p, lo_p, fp_bits=fp_bits,
+                               n_buckets=n_buckets, valid=valid_p,
+                               block=block, interpret=False, emulate=emul,
+                               donate=donate)
+    return _delete_result(out[:-1], _unpad(out[-1], n), hi, lo, valid, stash,
+                          fp_bits=fp_bits, n_buckets=n_buckets,
+                          telemetry=telemetry)
 
 
-@functools.partial(jax.jit, static_argnames=("fp_bits",))
+@functools.partial(jax.jit, static_argnames=("fp_bits", "telemetry"))
 def adaptive_report(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
                     klo_t: jax.Array, hi: jax.Array, lo: jax.Array, *,
-                    fp_bits: int, n_buckets, valid=None):
+                    fp_bits: int, n_buckets, valid=None,
+                    telemetry: bool = False):
     """Jitted confirmed-false-positive feedback pass
-    -> (table, sels, adapted bool[N], resident bool[N]).
+    -> (table, sels, adapted bool[N], resident bool[N]), plus a trailing
+    ``FilterTelemetry`` with ``telemetry`` (``selector_bumps`` counts the
+    slots whose selector advanced this pass).
 
     Reports are rare control-plane events; the sequential ``report_adapt``
     scan (exact python-oracle semantics) needs no kernel arm.
     """
     if valid is None:
         valid = jnp.ones(hi.shape, bool)
-    return report_adapt(table, sels, khi_t, klo_t, hi.astype(jnp.uint32),
-                        lo.astype(jnp.uint32), valid, fp_bits=fp_bits,
-                        n_buckets=n_buckets)
-
-
-def adaptive_lookup_tm(table: jax.Array, sels: jax.Array, hi: jax.Array,
-                       lo: jax.Array, *, fp_bits: int, n_buckets=None,
-                       stash=None):
-    """Telemetry twin of ``adaptive_lookup`` -> (hit, FilterTelemetry)."""
-    if hi.shape[0] == 0:
-        return jnp.zeros((0,), jnp.bool_), empty_telemetry()
-    if n_buckets is None:
-        n_buckets = table.shape[0]
-    hit, depth = probe_adaptive_emulated_tm(
-        table, sels, hi.astype(jnp.uint32), lo.astype(jnp.uint32), n_buckets,
-        stash, fp_bits=fp_bits)
-    return hit, empty_telemetry()._replace(probe_depth=depth)
-
-
-def adaptive_insert_tm(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
-                       klo_t: jax.Array, hi: jax.Array, lo: jax.Array, *,
-                       fp_bits: int, n_buckets=None, valid=None,
-                       evict_rounds: int = 0, stash=None,
-                       schedule: bool = False, donate: bool = False):
-    """Telemetry twin of ``adaptive_insert`` -> same results + telemetry."""
-    if hi.shape[0] == 0:
-        empty_ok = jnp.zeros((0,), jnp.bool_)
-        tm = empty_telemetry()
-        return ((table, sels, khi_t, klo_t, empty_ok, tm) if stash is None
-                else (table, sels, khi_t, klo_t, stash, empty_ok, tm))
-    if valid is None:
-        valid = jnp.ones(hi.shape, bool)
-    table_bytes = _adaptive_plane_bytes(table)
-    stash_slots = 0 if stash is None else stash.shape[1]
-    block = min(autotune_block("insert", table_bytes=table_bytes,
-                               evict_rounds=2 * evict_rounds,
-                               stash_slots=stash_slots,
-                               n_keys=hi.shape[0]), hi.shape[0])
-    hi_p, n = _pad_to(hi, block)
-    lo_p, _ = _pad_to(lo, block)
-    valid_p, _ = _pad_to(valid, block)   # pads False: never touches planes
-    out = insert_bulk_adaptive_tm(table, sels, khi_t, klo_t, hi_p, lo_p,
-                                  fp_bits=fp_bits, n_buckets=n_buckets,
-                                  valid=valid_p, evict_rounds=evict_rounds,
-                                  stash=stash, block=block,
-                                  schedule=schedule, donate=donate)
-    tm = out[-1]
-    return (*out[:-2], _unpad(out[-2], n), tm)
-
-
-def adaptive_delete_tm(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
-                       klo_t: jax.Array, hi: jax.Array, lo: jax.Array, *,
-                       fp_bits: int, n_buckets=None, valid=None, stash=None,
-                       donate: bool = False):
-    """Telemetry twin of ``adaptive_delete`` -> same results + telemetry."""
-    if hi.shape[0] == 0:
-        empty_ok = jnp.zeros((0,), jnp.bool_)
-        tm = empty_telemetry()
-        return ((table, sels, khi_t, klo_t, empty_ok, tm) if stash is None
-                else (table, sels, khi_t, klo_t, stash, empty_ok, tm))
-    if valid is None:
-        valid = jnp.ones(hi.shape, bool)
-    table_bytes = _adaptive_plane_bytes(table)
-    block = delete_block(hi.shape[0], table_bytes=table_bytes)
-    hi_p, n = _pad_to(hi, block)
-    lo_p, _ = _pad_to(lo, block)
-    valid_p, _ = _pad_to(valid, block)   # pads False: never touches planes
-    table, sels, khi_t, klo_t, ok = delete_bulk_adaptive(
-        table, sels, khi_t, klo_t, hi_p, lo_p, fp_bits=fp_bits,
-        n_buckets=n_buckets, valid=valid_p, block=block,
-        interpret=False, emulate=lowering("delete_adaptive") == "xla",
-        donate=donate)
-    ok = _unpad(ok, n)
-    tm = empty_telemetry()._replace(
-        table_deletes=jnp.sum(ok).astype(jnp.uint32))
-    if stash is None:
-        return table, sels, khi_t, klo_t, ok, tm
-    nb = table.shape[0] if n_buckets is None else n_buckets
-    stash, cleared = stash_delete_ref(stash, hi, lo, valid & ~ok,
-                                      fp_bits=fp_bits, n_buckets=nb)
-    tm = tm._replace(stash_deletes=jnp.sum(cleared).astype(jnp.uint32),
-                     stash_fill_hw=stash_occupancy(stash).astype(jnp.uint32))
-    return table, sels, khi_t, klo_t, stash, ok | cleared, tm
-
-
-def adaptive_report_tm(table: jax.Array, sels: jax.Array, khi_t: jax.Array,
-                       klo_t: jax.Array, hi: jax.Array, lo: jax.Array, *,
-                       fp_bits: int, n_buckets, valid=None):
-    """Telemetry twin of ``adaptive_report`` — ``selector_bumps`` counts
-    the slots whose selector actually advanced this pass."""
-    table, sels, adapted, resident = adaptive_report(
-        table, sels, khi_t, klo_t, hi, lo, fp_bits=fp_bits,
-        n_buckets=n_buckets, valid=valid)
-    tm = empty_telemetry()._replace(
-        selector_bumps=jnp.sum(adapted).astype(jnp.uint32))
-    return table, sels, adapted, resident, tm
+    out = report_adapt(table, sels, khi_t, klo_t, hi.astype(jnp.uint32),
+                       lo.astype(jnp.uint32), valid, fp_bits=fp_bits,
+                       n_buckets=n_buckets)
+    if not telemetry:
+        return out
+    return (*out, empty_telemetry()._replace(
+        selector_bumps=jnp.sum(out[2]).astype(jnp.uint32)))
 
 
 def attention(q, k, v, *, causal: bool = True, window: int | None = None,
@@ -911,7 +785,4 @@ __all__ = ["hash_keys", "filter_lookup", "filter_lookup_multi",
            "DEFAULT_EVICT_ROUNDS", "DEFAULT_STASH_SLOTS", "make_stash",
            "stash_occupancy", "adaptive_lookup", "adaptive_insert",
            "adaptive_delete", "adaptive_report", "make_sel_plane",
-           "make_key_planes", "FilterTelemetry", "empty_telemetry",
-           "probe_dispatch_tm", "filter_insert_tm", "filter_delete_tm",
-           "adaptive_lookup_tm", "adaptive_insert_tm", "adaptive_delete_tm",
-           "adaptive_report_tm"]
+           "make_key_planes", "FilterTelemetry", "empty_telemetry"]

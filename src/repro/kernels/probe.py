@@ -138,34 +138,30 @@ def probe(table: jax.Array, hi: jax.Array, lo: jax.Array, *, fp_bits: int,
     )(n_arr, table, stash, hi.astype(jnp.uint32), lo.astype(jnp.uint32))
 
 
-@functools.partial(jax.jit, static_argnames=("fp_bits",))
+@functools.partial(jax.jit, static_argnames=("fp_bits", "telemetry"))
 def probe_emulated(table: jax.Array, hi: jax.Array, lo: jax.Array,
-                   n_buckets, stash, *, fp_bits: int) -> jax.Array:
+                   n_buckets, stash, *, fp_bits: int,
+                   telemetry: bool = False):
     """The emulated probe body behind a minimal positional-arg jit.
 
     Same function as ``probe(..., emulate=True)``; exists because the hot
     serving lookup is dispatch-bound enough on CPU that the keyword-arg
     jit entry with five statics costs a measurable slice of the call
-    (``ops.probe_dispatch`` uses this one).
+    (``ops.probe_dispatch`` uses this one).  ``telemetry=True`` returns
+    ``(hit, probe_depth uint32[4])``: lanes counted by shallowest hit
+    location — first bucket, second bucket, stash, miss
+    (``kernels.telemetry.probe_depth_counts``).
     """
-    return _probe_body(table, stash, hi, lo, n_buckets, fp_bits=fp_bits,
-                       array_table=True)
+    out = _probe_body(table, stash, hi, lo, n_buckets, fp_bits=fp_bits,
+                      array_table=True, want_stats=telemetry)
+    return _with_depth(out) if telemetry else out
 
 
-@functools.partial(jax.jit, static_argnames=("fp_bits",))
-def probe_emulated_tm(table: jax.Array, hi: jax.Array, lo: jax.Array,
-                      n_buckets, stash, *, fp_bits: int):
-    """Telemetry twin of ``probe_emulated`` -> (hit, probe_depth uint32[4]).
-
-    ``probe_depth`` counts lanes by shallowest hit location — first bucket,
-    second bucket, stash, miss (``kernels.telemetry.probe_depth_counts``).
-    Its own jit: the telemetry-off lookup keeps its cache and dispatch.
-    """
-    hit, (h1, h2, hs) = _probe_body(table, stash, hi, lo, n_buckets,
-                                    fp_bits=fp_bits, array_table=True,
-                                    want_stats=True)
-    valid = jnp.ones_like(hit)
-    return hit, probe_depth_counts(h1, h2, hs, valid)
+def _with_depth(out):
+    """(hit, (h1, h2, hs)) from a body run with ``want_stats`` -> (hit,
+    probe-depth counts)."""
+    hit, (h1, h2, hs) = out
+    return hit, probe_depth_counts(h1, h2, hs, jnp.ones_like(hit))
 
 
 # --------------------------------------------- selector-aware probe ---------
@@ -282,26 +278,17 @@ def probe_adaptive(table: jax.Array, sels: jax.Array, hi: jax.Array,
       lo.astype(jnp.uint32))
 
 
-@functools.partial(jax.jit, static_argnames=("fp_bits",))
+@functools.partial(jax.jit, static_argnames=("fp_bits", "telemetry"))
 def probe_adaptive_emulated(table: jax.Array, sels: jax.Array, hi: jax.Array,
                             lo: jax.Array, n_buckets, stash, *,
-                            fp_bits: int) -> jax.Array:
+                            fp_bits: int, telemetry: bool = False):
     """Positional-arg fast path for the emulated adaptive probe (the
-    adaptive serving lookup's analogue of ``probe_emulated``)."""
-    return _probe_adaptive_body(table, sels, stash, hi, lo, n_buckets,
-                                fp_bits=fp_bits, array_table=True)
-
-
-@functools.partial(jax.jit, static_argnames=("fp_bits",))
-def probe_adaptive_emulated_tm(table: jax.Array, sels: jax.Array,
-                               hi: jax.Array, lo: jax.Array, n_buckets,
-                               stash, *, fp_bits: int):
-    """Telemetry twin of ``probe_adaptive_emulated`` -> (hit, depth[4])."""
-    hit, (h1, h2, hs) = _probe_adaptive_body(
-        table, sels, stash, hi, lo, n_buckets, fp_bits=fp_bits,
-        array_table=True, want_stats=True)
-    valid = jnp.ones_like(hit)
-    return hit, probe_depth_counts(h1, h2, hs, valid)
+    adaptive serving lookup's analogue of ``probe_emulated``, telemetry
+    included)."""
+    out = _probe_adaptive_body(table, sels, stash, hi, lo, n_buckets,
+                               fp_bits=fp_bits, array_table=True,
+                               want_stats=telemetry)
+    return _with_depth(out) if telemetry else out
 
 
 # ----------------------------------------------- multi-generation probe ----

@@ -1,11 +1,10 @@
 """Device-resident telemetry planes for the filter kernels.
 
-The hot path never pays for observability: every kernel entry point keeps
-its existing signature and jit, and a *twin* jit (selected by a static
-``telemetry`` flag at the dispatch layer) returns a ``FilterTelemetry``
-alongside the normal results.  The telemetry twin is a separate compiled
-trace, so the telemetry-off path is dispatch-identical to a build without
-this module.
+The hot path never pays for observability: every table-op entry point
+takes a static ``telemetry`` flag and, when it is set, returns a
+``FilterTelemetry`` alongside the normal results.  Each value of the flag
+is its own compiled program, so the telemetry-off program is the one a
+build without this module would run.
 
 All fields are fixed-shape ``uint32`` vectors/scalars so a wave's counters
 ride back to the host in the same transfer as its results and merge across
@@ -52,10 +51,10 @@ _EMPTY: Optional[FilterTelemetry] = None
 
 def empty_telemetry() -> FilterTelemetry:
     """All-zero counter plane, cached once built outside a trace: jax
-    arrays are immutable and none of the tm paths donate telemetry
+    arrays are immutable and no telemetry path donates telemetry
     buffers, so every host-side dispatch can share one instance — 9 fresh
     device_puts per call otherwise dominate the host side of the cheap
-    twins (measured ~0.5 ms on the CPU lookup).  Inside a jit trace
+    telemetry calls (measured ~0.5 ms on the CPU lookup).  Inside a jit trace
     ``jnp.zeros`` yields tracers, which must never be cached — those
     calls build (and discard) a fresh instance."""
     global _EMPTY

@@ -789,11 +789,12 @@ class FilterOpBatcher:
         """Observability kwargs (all default-off; the off path issues the
         identical device-call sequence as a batcher built without them):
 
-        ``telemetry``: dispatch through the ``FilterOps`` ``*_tm`` twins so
-        every wave also returns a device-computed ``FilterTelemetry``
-        (kick-depth histogram, probe hit-depth, spill/rollback counters);
-        the counters ride ``wave._device`` and materialize in the SAME
-        single ``block_until_ready`` as the results.  ``metrics``: a
+        ``telemetry``: call the ``FilterOps`` entries with
+        ``telemetry=True`` so every wave also returns a device-computed
+        ``FilterTelemetry`` (kick-depth histogram, probe hit-depth,
+        spill/rollback counters); the counters ride ``wave._device`` and
+        materialize in the SAME single ``block_until_ready`` as the
+        results.  ``metrics``: a
         ``repro.obs.MetricsRegistry`` receiving wave timings + counters
         (auto-created when ``telemetry`` is on and none is given).
         ``tracer``: a ``repro.obs.TraceRecorder``; dispatch and harvest
@@ -943,15 +944,16 @@ class FilterOpBatcher:
             return jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(valid)
 
     def _op(self, wave: OpWave, entry: str, *args, **kwargs):
-        """One ``FilterOps`` call: ``entry``, or its ``*_tm`` twin when
+        """One ``FilterOps`` call: ``entry``, with ``telemetry=True`` when
         telemetry is on -> (the entry's outputs, ``FilterTelemetry`` or
-        None).  The twin's telemetry rides ``wave._device`` so the harvest
+        None).  The telemetry rides ``wave._device`` so the harvest
         materializes counters and results in the SAME single
         ``block_until_ready`` — telemetry adds no extra sync points."""
         with self._span("filterops." + entry, wave):
             if not self.telemetry:
                 return getattr(self.ops, entry)(*args, **kwargs), None
-            *out, tm = getattr(self.ops, entry + "_tm")(*args, **kwargs)
+            *out, tm = getattr(self.ops, entry)(*args, telemetry=True,
+                                                **kwargs)
         return (out[0] if len(out) == 1 else tuple(out)), tm
 
     def _dispatch(self, wave: OpWave, keys: np.ndarray) -> None:

@@ -358,9 +358,9 @@ def run_scenario(name: str, *, seed: int = 0, backend: str = "pallas",
     Everything downstream of (``name``, ``seed``, ``backend``,
     ``double_buffer``) is deterministic; the sync/async parity test and
     the committed bench rows both lean on that.  ``telemetry`` routes the
-    waves through the device counter planes (answers unchanged — the twin
-    jits are parity-pinned); ``metrics``/``tracer`` receive the counters
-    and spans.
+    waves through the device counter planes (answers unchanged — the
+    telemetry arm is parity-pinned); ``metrics``/``tracer`` receive the
+    counters and spans.
     """
     stream = scenario_stream(name, seed,
                              wave_slots=wave_slots,

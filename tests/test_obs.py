@@ -3,9 +3,10 @@
 The contracts pinned here:
 
   * **dispatch identity**: a batcher with telemetry OFF issues exactly
-    the pre-telemetry device-call sequence (never a ``*_tm`` entry
-    point), and its results and final filter state are bit-for-bit those
-    of a batcher built without any observability kwargs at all —
+    the pre-telemetry device-call sequence (no entry point called with
+    ``telemetry=True``), and its results and final filter state are
+    bit-for-bit those of a batcher built without any observability
+    kwargs at all —
     attaching a registry or tracer must not change the device work;
   * **telemetry parity**: turning the counter planes ON changes the
     counters, never the answers — results, tables, stashes and counts
@@ -45,20 +46,19 @@ WS = 64
 # every device entry point the batcher can reach, off and on
 _SPIED = ("probe_dispatch", "filter_insert", "filter_delete",
           "adaptive_lookup", "adaptive_insert", "adaptive_delete",
-          "adaptive_report", "probe_dispatch_tm", "filter_insert_tm",
-          "filter_delete_tm", "adaptive_lookup_tm", "adaptive_insert_tm",
-          "adaptive_delete_tm", "adaptive_report_tm")
+          "adaptive_report")
 
 
 def _spy_kops(monkeypatch):
-    """Record the name of every kops entry point the batcher dispatches."""
+    """Record (name, telemetry) of every kops entry point the batcher
+    dispatches."""
     calls = []
 
     def wrap(name):
         orig = getattr(kops_mod, name)
 
         def wrapped(*a, **k):
-            calls.append(name)
+            calls.append((name, k.get("telemetry", False)))
             return orig(*a, **k)
 
         return wrapped
@@ -104,7 +104,7 @@ def test_telemetry_off_dispatch_identical(monkeypatch):
     seq_obs = list(calls)
 
     assert seq_obs == seq_plain
-    assert not any(name.endswith("_tm") for name in seq_obs)
+    assert seq_obs and not any(tm for _name, tm in seq_obs)
     for a, b in zip(res_plain, res_obs):
         np.testing.assert_array_equal(a, b)
     assert jnp.array_equal(plain.state.table, observed.state.table)
@@ -125,8 +125,8 @@ def test_telemetry_on_counters_change_answers_dont(monkeypatch):
     on = _mk_batcher(telemetry=True, metrics=m)
     res_on = _replay(on, np.random.RandomState(5))
 
-    # the telemetry arm dispatches ONLY through the twin entry points
-    assert calls and all(n.endswith("_tm") for n in calls)
+    # the telemetry arm asks EVERY entry point for its counter plane
+    assert calls and all(tm for _name, tm in calls)
     for a, b in zip(res_plain, res_on):
         np.testing.assert_array_equal(a, b)
     assert jnp.array_equal(plain.state.table, on.state.table)
@@ -175,6 +175,204 @@ def test_adaptive_telemetry_parity():
     assert depth == WS
     assert snap.get("filter_table_deletes", 0) + snap.get(
         "filter_stash_deletes", 0) >= 1
+
+
+def _pair(rng, n):
+    import jax.numpy as jnp
+
+    from repro.core import hashing
+    keys = rng.randint(1, 2 ** 62, size=n, dtype=np.int64).astype(np.uint64)
+    hi, lo = hashing.key_to_u32_pair_np(keys)
+    return jnp.asarray(hi), jnp.asarray(lo)
+
+
+@pytest.fixture(scope="module")
+def tm_world():
+    """Static and adaptive filters at load ~0.8 of 64 buckets with a part
+    filled stash, the keys they hold and 40 they do not, and a batch of
+    the adaptive filter's false positives."""
+    from repro.adaptive.state import make_adaptive_state
+
+    rng = np.random.RandomState(21)
+    hi, lo = _pair(rng, 240)
+    ops = FilterOps(backend="pallas", evict_rounds=8)
+    static, static_stash, _ = ops.insert_spill(
+        jfilter.make_state(64, buffer_buckets=64), kops.make_stash(16),
+        hi[:200], lo[:200])
+    aops = FilterOps(fp_bits=8, backend="pallas", evict_rounds=8)
+    adaptive, adaptive_stash, _ = aops.insert_adaptive(
+        make_adaptive_state(64), hi[:200], lo[:200],
+        stash=kops.make_stash(16))
+    phi, plo = _pair(rng, 4096)
+    fp = np.asarray(aops.lookup_adaptive(adaptive, phi, plo))
+    return dict(ops=ops, static=static, static_stash=static_stash,
+                aops=aops, adaptive=adaptive, adaptive_stash=adaptive_stash,
+                hi=hi, lo=lo, fp_hi=phi[fp], fp_lo=plo[fp])
+
+
+# entry -> (kind of counters it fills, call(world, telemetry))
+_TM_ENTRIES = {
+    "lookup": ("lookup", lambda w, t: w["ops"].lookup(
+        w["static"], w["hi"], w["lo"], telemetry=t)),
+    "lookup_with_stash": ("lookup", lambda w, t: w["ops"].lookup_with_stash(
+        w["static"], w["static_stash"], w["hi"], w["lo"], telemetry=t)),
+    "insert": ("insert", lambda w, t: w["ops"].insert(
+        w["static"], w["hi"][200:], w["lo"][200:], telemetry=t)),
+    "insert_spill": ("insert", lambda w, t: w["ops"].insert_spill(
+        w["static"], w["static_stash"], w["hi"][200:], w["lo"][200:],
+        telemetry=t)),
+    "delete": ("delete", lambda w, t: w["ops"].delete(
+        w["static"], w["hi"][:100], w["lo"][:100], telemetry=t)),
+    "delete_table": ("delete", lambda w, t: w["ops"].delete_table(
+        w["static"].table, w["hi"][:200], w["lo"][:200],
+        n_buckets=w["static"].n_buckets, stash=w["static_stash"],
+        telemetry=t)),
+    "lookup_adaptive": ("lookup", lambda w, t: w["aops"].lookup_adaptive(
+        w["adaptive"], w["hi"], w["lo"], telemetry=t)),
+    "lookup_adaptive_stash": ("lookup", lambda w, t:
+                              w["aops"].lookup_adaptive(
+                                  w["adaptive"], w["hi"], w["lo"],
+                                  stash=w["adaptive_stash"], telemetry=t)),
+    "insert_adaptive": ("insert", lambda w, t: w["aops"].insert_adaptive(
+        w["adaptive"], w["hi"][200:], w["lo"][200:], telemetry=t)),
+    "insert_adaptive_stash": ("insert", lambda w, t:
+                              w["aops"].insert_adaptive(
+                                  w["adaptive"], w["hi"][200:],
+                                  w["lo"][200:], stash=w["adaptive_stash"],
+                                  telemetry=t)),
+    "delete_adaptive": ("delete", lambda w, t: w["aops"].delete_adaptive(
+        w["adaptive"], w["hi"][:100], w["lo"][:100], telemetry=t)),
+    "delete_adaptive_stash": ("delete", lambda w, t:
+                              w["aops"].delete_adaptive(
+                                  w["adaptive"], w["hi"][:200],
+                                  w["lo"][:200], stash=w["adaptive_stash"],
+                                  telemetry=t)),
+    "report_false_positive": ("report", lambda w, t:
+                              w["aops"].report_false_positive(
+                                  w["adaptive"], w["fp_hi"], w["fp_lo"],
+                                  telemetry=t)),
+}
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("entry", list(_TM_ENTRIES))
+def test_telemetry_arg_matches_plain_entry(tm_world, entry):
+    """``telemetry=True`` returns the plain entry's answers, tables, stash
+    and count bit for bit, plus a ``FilterTelemetry`` whose counters
+    account for the batch."""
+    import jax
+
+    kind, call = _TM_ENTRIES[entry]
+    off = call(tm_world, False)
+    off = off if isinstance(off, tuple) else (off,)
+    *on, tm = call(tm_world, True)
+    assert isinstance(tm, kops.FilterTelemetry)
+    leaves_off = jax.tree_util.tree_leaves(off)
+    leaves_on = jax.tree_util.tree_leaves(tuple(on))
+    assert len(leaves_on) == len(leaves_off)
+    for a, b in zip(leaves_off, leaves_on):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if kind == "lookup":
+        # every lane lands at one depth; the 40 absent keys mostly miss
+        assert int(tm.probe_depth.sum()) == 240
+        assert int(tm.probe_depth[3]) > 0
+    elif kind == "insert":
+        assert int(tm.kick_hist.sum()) == 40   # every lane binned once
+    elif kind == "delete":
+        deleted = int(np.asarray(off[-1]).sum())
+        assert deleted > 0
+        assert int(tm.table_deletes) + int(tm.stash_deletes) == deleted
+    else:
+        adapted = int(np.asarray(off[1]).sum())
+        assert adapted > 0
+        assert int(tm.selector_bumps) == adapted
+
+
+_PARENT_INSERT_STATICS = ("fp_bits", "evict_rounds", "block", "interpret",
+                          "emulate", "schedule")
+
+
+def _lowered_insert(monkeypatch, jit_name, impl, entry, *args, **kwargs):
+    """(HLO of the jit ``entry`` dispatches to, with the arguments it
+    passes; HLO of ``impl`` jitted with the parent's static argnames)."""
+    import jax
+
+    from repro.kernels import insert as kinsert
+
+    orig = getattr(kinsert, jit_name)
+    seen = {}
+    monkeypatch.setattr(kinsert, jit_name,
+                        lambda *a, **k: seen.update(a=a, k=k))
+    entry(*args, **kwargs)
+    a, k = seen["a"], seen["k"]
+    assert k.pop("telemetry") is False
+    parent = jax.jit(impl, static_argnames=_PARENT_INSERT_STATICS)
+    return (orig.lower(*a, telemetry=False, **k).as_text(),
+            parent.lower(*a, **k).as_text())
+
+
+@pytest.mark.tier1
+@pytest.mark.parametrize("stashed", [False, True])
+@pytest.mark.parametrize("entry", ["insert_bulk", "insert_bulk_adaptive",
+                                   "probe_emulated",
+                                   "probe_adaptive_emulated"])
+def test_telemetry_off_program_is_the_parents(monkeypatch, entry, stashed):
+    """With ``telemetry=False`` each kernel entry lowers to the program its
+    telemetry-free jit lowered to: the body jitted with the static
+    argnames it had before ``telemetry`` was one of them."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import insert as kinsert
+    from repro.kernels.probe import (_probe_adaptive_body, _probe_body,
+                                     probe_adaptive_emulated, probe_emulated)
+    from repro.kernels.selector import make_key_planes, make_sel_plane
+
+    hi, lo = _pair(np.random.RandomState(2), 256)
+    table = jnp.zeros((64, 4), jnp.uint32)
+    sels = make_sel_plane(64)
+    khi, klo = make_key_planes(64, 4)
+    stash = kops.make_stash(16) if stashed else None
+    n = jnp.asarray(64, jnp.int32)
+    kw = dict(fp_bits=16, valid=jnp.ones(256, bool), evict_rounds=8,
+              stash=stash, block=128, interpret=False, emulate=True,
+              schedule=True)
+    if entry == "insert_bulk":
+        new, old = _lowered_insert(monkeypatch, "_insert_bulk_jit",
+                                   kinsert._insert_bulk_impl,
+                                   kinsert.insert_bulk, table, hi, lo, **kw)
+    elif entry == "insert_bulk_adaptive":
+        new, old = _lowered_insert(monkeypatch, "_insert_adaptive_jit",
+                                   kinsert._insert_adaptive_impl,
+                                   kinsert.insert_bulk_adaptive, table,
+                                   sels, khi, klo, hi, lo, **kw)
+    else:
+        # the parent's jits, body for body, under the same names
+        def parent_probe_emulated(table, hi, lo, n_buckets, stash, *,
+                                  fp_bits):
+            return _probe_body(table, stash, hi, lo, n_buckets,
+                               fp_bits=fp_bits, array_table=True)
+
+        def parent_probe_adaptive_emulated(table, sels, hi, lo, n_buckets,
+                                           stash, *, fp_bits):
+            return _probe_adaptive_body(table, sels, stash, hi, lo,
+                                        n_buckets, fp_bits=fp_bits,
+                                        array_table=True)
+
+        if entry == "probe_emulated":
+            fn, body, args = (probe_emulated, parent_probe_emulated,
+                              (table, hi, lo, n, stash))
+        else:
+            fn, body, args = (probe_adaptive_emulated,
+                              parent_probe_adaptive_emulated,
+                              (table, sels, hi, lo, n, stash))
+        body.__name__ = entry
+        new = fn.lower(*args, fp_bits=16).as_text()
+        assert fn.lower(*args, fp_bits=16,
+                        telemetry=False).as_text() == new
+        old = jax.jit(body, static_argnames=("fp_bits",)).lower(
+            *args, fp_bits=16).as_text()
+    assert new == old
 
 
 @pytest.mark.tier1
