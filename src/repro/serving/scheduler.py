@@ -24,6 +24,7 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
+import functools
 import os
 import time
 from collections import deque
@@ -667,14 +668,15 @@ class DeferredWritePump:
 # harness's scenario replay) arrives as *waves* of homogeneous membership
 # ops.  The batcher below is the wave-granular submit path: one wave is
 # prepared host-side (pad to a fixed shape, hash split, optional lookup
-# dedup), dispatched to the device through ``FilterOps``, and harvested —
-# ``jax.block_until_ready`` ONLY at harvest.  In double-buffered mode the
-# harvest of wave k happens *after* wave k+1 has been prepared and
-# dispatched, so host prep overlaps device execution and the scheduler,
-# not host sync, sets the latency floor.  Both modes issue the identical
-# device-call sequence in the identical order, so their results (and the
-# filter state they leave behind) are bit-for-bit equal — the oracle
-# parity tests in tests/test_slo.py pin this.
+# dedup), uploaded in one copy, run as one jitted program around its
+# ``FilterOps`` entry, and harvested — ``jax.block_until_ready`` ONLY at
+# harvest.  In double-buffered mode the harvest of wave k happens *after*
+# wave k+1 has been prepared and dispatched, so host prep overlaps device
+# execution and the scheduler, not host sync, sets the latency floor.
+# Both modes issue the identical device-call sequence in the identical
+# order, so their results (and the filter state they leave behind) are
+# bit-for-bit equal — the oracle parity tests in tests/test_slo.py pin
+# this.
 
 
 # Wave latency histogram edges (µs) for the metrics registry — spans the
@@ -699,6 +701,113 @@ def _table_delete_fill(count, stash_before, stash, ok):
     count = (count - jnp.sum(ok, dtype=jnp.int32)
              + (kops.stash_occupancy(stash_before) - occ))
     return count, jnp.stack([count, occ])
+
+
+# State fields a wave's host path may read after the op (the table delete
+# reads the pre-wave count): never donated, and handed back by a wave
+# program only when its entry replaced them.
+_KEPT = ("count", "n_buckets")
+
+
+def _wave_entry(kind: str, adaptive: bool, stashed: bool) -> str:
+    """The ``FilterOps`` entry a wave of ``kind`` runs on a state family
+    (adaptive or static), with or without a stash."""
+    if kind == "lookup":
+        return ("lookup_adaptive" if adaptive
+                else "lookup_with_stash" if stashed else "lookup")
+    if kind == "insert":
+        return ("insert_adaptive" if adaptive
+                else "insert_spill" if stashed else "insert")
+    if kind == "delete":
+        return ("delete_adaptive" if adaptive
+                else "delete_table" if stashed else "delete")
+    if kind == "report":
+        if not adaptive:
+            raise ValueError("'report' waves need an AdaptiveState")
+        return "report_false_positive"
+    raise ValueError(f"unknown wave kind {kind!r}")
+
+
+def _run_entry(call, entry: str, state, stash, hi, lo, valid,
+               telemetry: bool):
+    """``call``, the bound ``FilterOps`` entry called ``entry``, on a
+    wave's arrays -> (state, stash, answers, ``FilterTelemetry`` or
+    None)."""
+    if telemetry:
+        call = functools.partial(call, telemetry=True)
+    if entry == "lookup":
+        out = call(state, hi, lo)
+    elif entry == "lookup_with_stash":
+        out = call(state, stash, hi, lo)
+    elif entry == "lookup_adaptive":
+        out = call(state, hi, lo, stash=stash)
+    elif entry == "insert_spill":
+        out = call(state, stash, hi, lo, valid=valid)
+    elif entry == "delete_table":
+        out = call(state.table, hi, lo, n_buckets=state.n_buckets,
+                   valid=valid, stash=stash)
+    elif entry in ("insert_adaptive", "delete_adaptive"):
+        out = call(state, hi, lo, valid=valid, stash=stash)
+    else:   # insert, delete, report_false_positive
+        out = call(state, hi, lo, valid=valid)
+    out = out if isinstance(out, tuple) else (out,)
+    tm = out[-1] if telemetry else None
+    out = out[:-1] if telemetry else out
+    if entry.startswith("lookup"):
+        return state, stash, out[0], tm
+    if entry == "report_false_positive":       # (state, adapted, resident)
+        return out[0], stash, out[1], tm
+    if entry == "delete_table":                # (table, stash, ok)
+        return state._replace(table=out[0]), out[1], out[2], tm
+    if len(out) == 3:                          # (state, stash, ok)
+        return (*out, tm)
+    return out[0], stash, out[1], tm
+
+
+@functools.lru_cache(maxsize=None)
+def _wave_program(ops, entry: str, impl, telemetry: bool):
+    """The one jitted program of a wave that runs ``ops.<entry>``, whose
+    function is ``impl`` (``getattr(type(ops), entry)``): it splits the
+    packed upload (hi, lo and, for writes, valid as 0/1, one after the
+    other) and calls the entry, whose jitted kernel bodies inline into it.
+
+    ``program(planes, kept, stash, packed)`` takes the state as its
+    planes (its ``_KEPT`` fields None) and the kept fields, and returns
+    (answers, the state fields the entry replaced, the new stash or None
+    where the entry left it, ``FilterTelemetry`` or None).  A field the
+    entry left alone is not handed back, so the caller's array stays the
+    same object: a lookup returns no state at all.  With ``ops.donate`` a
+    write donates its planes (a nested jit's donation is ignored, so this
+    is what keeps the table update in place) and hands every plane back;
+    the stash and the kept fields are never donated.
+
+    Programs are cached per (ops, entry, impl, telemetry): batchers over
+    equal ``FilterOps`` share their compiles, and an entry replaced on
+    the class gets a program of its own.  The lookup programs are
+    named for the probe program they carry on a TPU (``probe_emulated``,
+    ``probe_adaptive_emulated``): the probe's device time is found in a
+    trace by that name (``bench/metrics/probe_roofline.py``)."""
+    lookup = entry.startswith("lookup")
+    donate = ops.donate and not lookup
+
+    def program(planes, kept, stash, packed):
+        state = planes._replace(**dict(zip(_KEPT, kept)))
+        hi, lo, *valid = jnp.split(packed, 2 if lookup else 3)
+        new, new_stash, res, tm = _run_entry(functools.partial(impl, ops),
+                                             entry, state, stash, hi, lo,
+                                             valid[0] != 0 if valid else None,
+                                             telemetry)
+        changed = {k: v for k, v in new._asdict().items()
+                   if v is not getattr(state, k)
+                   or (donate and k not in _KEPT)}
+        return res, changed, None if new_stash is stash else new_stash, tm
+
+    program.__name__ = program.__qualname__ = {
+        "lookup": "probe_emulated_wave",
+        "lookup_with_stash": "probe_emulated_wave",
+        "lookup_adaptive": "probe_adaptive_emulated_wave",
+    }.get(entry, entry + "_wave")
+    return jax.jit(program, donate_argnums=(0,) if donate else ())
 
 
 @dataclasses.dataclass
@@ -736,6 +845,7 @@ class BatcherStats:
     shed_ops: int = 0             # lanes still parked when drain gave up
     deduped_lanes: int = 0        # lookup lanes collapsed by dedup
     fill_reads: int = 0           # waves that read a new fill snapshot
+    uploads: int = 0              # host->device copies, one per launched wave
 
 
 class FilterOpBatcher:
@@ -750,7 +860,9 @@ class FilterOpBatcher:
         feeding confirmed false positives back.
 
     Waves are padded to ``wave_slots`` (key 0, ``valid=False``) so every
-    (kind, state-family) pair compiles exactly once.  ``submit`` returns
+    (kind, state-family) pair compiles exactly once.  A wave is one
+    host->device copy of its packed keys and one program launch
+    (``_wave_program``), plus the fill read of a write.  ``submit`` returns
     the ``OpWave`` immediately; ``wave.results`` is populated at harvest —
     the next submit (double-buffered) or before submit returns (sync).
     Call ``flush()`` to force the in-flight wave out (the closed-loop
@@ -926,7 +1038,13 @@ class FilterOpBatcher:
             self._harvest(wave)
 
     def _prepare(self, wave: OpWave, keys: np.ndarray):
-        """Host-side wave prep: dedup (lookups), pad, hash split, upload."""
+        """Host-side wave prep: dedup (lookups), pad, hash split, and ONE
+        upload of the packed wave: ``uint32[2 * wave_slots]`` (hi, then
+        lo) for a lookup, which reads no mask, and ``uint32[3 *
+        wave_slots]`` (valid as 0/1 after them) for a write.  Flat, not
+        ``[rows, wave_slots]``: a TPU tiles such an array (2, 128), and
+        splitting its rows inside the program costs the probe two more
+        fusions."""
         with self._span("wave_prepare", wave):
             if wave.kind == "lookup" and self.dedupe_lookups:
                 keys, wave._inverse = dedupe_keys(keys)
@@ -935,84 +1053,38 @@ class FilterOpBatcher:
             n = keys.size
             assert n <= self.wave_slots, (n, self.wave_slots)
             wave._n_probe = n
-            padded = np.zeros(self.wave_slots, np.uint64)
-            padded[:n] = keys
-            hi, lo = hashing.key_to_u32_pair_np(padded)
-            valid = np.zeros(self.wave_slots, bool)
-            valid[:n] = True
+            # Padding lanes are key 0 (hi = lo = 0) and, on writes, invalid.
+            packed = np.zeros((2 if wave.kind == "lookup" else 3,
+                               self.wave_slots), np.uint32)
+            packed[0, :n], packed[1, :n] = hashing.key_to_u32_pair_np(keys)
+            packed[2:, :n] = 1
         with self._span("wave_upload", wave):
-            return jnp.asarray(hi), jnp.asarray(lo), jnp.asarray(valid)
-
-    def _op(self, wave: OpWave, entry: str, *args, **kwargs):
-        """One ``FilterOps`` call: ``entry``, with ``telemetry=True`` when
-        telemetry is on -> (the entry's outputs, ``FilterTelemetry`` or
-        None).  The telemetry rides ``wave._device`` so the harvest
-        materializes counters and results in the SAME single
-        ``block_until_ready`` — telemetry adds no extra sync points."""
-        with self._span("filterops." + entry, wave):
-            if not self.telemetry:
-                return getattr(self.ops, entry)(*args, **kwargs), None
-            *out, tm = getattr(self.ops, entry)(*args, telemetry=True,
-                                                **kwargs)
-        return (out[0] if len(out) == 1 else tuple(out)), tm
+            self.stats.uploads += 1
+            return jax.device_put(packed.reshape(-1))
 
     def _dispatch(self, wave: OpWave, keys: np.ndarray) -> None:
         """Queue the wave's device work; grab (results, fill snapshot or
         None [, telemetry]) refs for the harvest.  No host sync on this
-        path."""
-        hi, lo, valid = self._prepare(wave, keys)
+        path.  With ``telemetry`` the entry also returns a
+        ``FilterTelemetry``, which rides ``wave._device`` so the harvest
+        materializes counters and results in the SAME single
+        ``block_until_ready``."""
         state, stash = self.state, self.stash
-        table_delete = False
-        if wave.kind == "lookup":
-            if self._adaptive:
-                res, tm = self._op(wave, "lookup_adaptive", state, hi, lo,
-                                   stash=stash)
-            elif stash is not None:
-                res, tm = self._op(wave, "lookup_with_stash", state, stash,
-                                   hi, lo)
-            else:
-                res, tm = self._op(wave, "lookup", state, hi, lo)
-        elif wave.kind == "insert":
-            if self._adaptive and stash is not None:
-                (self.state, self.stash, res), tm = self._op(
-                    wave, "insert_adaptive", state, hi, lo, valid=valid,
-                    stash=stash)
-            elif self._adaptive:
-                (self.state, res), tm = self._op(
-                    wave, "insert_adaptive", state, hi, lo, valid=valid)
-            elif stash is not None:
-                (self.state, self.stash, res), tm = self._op(
-                    wave, "insert_spill", state, stash, hi, lo, valid=valid)
-            else:
-                (self.state, res), tm = self._op(wave, "insert", state, hi,
-                                                 lo, valid=valid)
-        elif wave.kind == "delete":
-            if self._adaptive:
-                out, tm = self._op(wave, "delete_adaptive", state, hi, lo,
-                                   valid=valid, stash=stash)
-                if stash is not None:
-                    self.state, self.stash, res = out
-                else:
-                    self.state, res = out
-            elif stash is not None:
-                (table, self.stash, res), tm = self._op(
-                    wave, "delete_table", state.table, hi, lo,
-                    n_buckets=state.n_buckets, valid=valid, stash=stash)
-                self.state = state._replace(table=table)
-                table_delete = True
-            else:
-                (self.state, res), tm = self._op(wave, "delete", state, hi,
-                                                 lo, valid=valid)
-        elif wave.kind == "report":
-            if not self._adaptive:
-                raise ValueError("'report' waves need an AdaptiveState")
-            (self.state, res, _resident), tm = self._op(
-                wave, "report_false_positive", state, hi, lo, valid=valid)
-        else:
-            raise ValueError(f"unknown wave kind {wave.kind!r}")
+        entry = _wave_entry(wave.kind, self._adaptive, stash is not None)
+        packed = self._prepare(wave, keys)
+        program = _wave_program(self.ops, entry,
+                                getattr(type(self.ops), entry),
+                                self.telemetry)
+        with self._span("filterops." + entry, wave):
+            res, changed, new_stash, tm = program(
+                state._replace(**dict.fromkeys(_KEPT)),
+                tuple(getattr(state, k) for k in _KEPT), stash, packed)
+        self.state = state._replace(**changed)
+        if new_stash is not None:
+            self.stash = new_stash
         with self._span("wave_occupancy", wave):
             fill = None
-            if table_delete:
+            if entry == "delete_table":
                 count, fill = _table_delete_fill(state.count, stash,
                                                  self.stash, res)
                 self.state = self.state._replace(count=count)

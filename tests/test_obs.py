@@ -36,6 +36,7 @@ from repro.core.ocf import OCF, OcfConfig
 from repro.kernels import ops as kops
 from repro.obs import MetricsRegistry, TraceRecorder
 from repro.serving.engine import BackpressureConfig, BackpressureController
+from repro.serving import scheduler
 from repro.serving.scheduler import FilterOpBatcher
 from repro.streaming.admission import AdmissionConfig, AdmissionController
 
@@ -50,8 +51,10 @@ _SPIED = ("probe_dispatch", "filter_insert", "filter_delete",
 
 
 def _spy_kops(monkeypatch):
-    """Record (name, telemetry) of every kops entry point the batcher
-    dispatches."""
+    """Record (name, telemetry) of every kops entry point the batcher's
+    wave programs call.  The entry points run while a program is traced,
+    so ``_mk_batcher`` drops the cached programs and each batcher traces
+    its own."""
     calls = []
 
     def wrap(name):
@@ -69,6 +72,7 @@ def _spy_kops(monkeypatch):
 
 
 def _mk_batcher(**obs_kwargs):
+    scheduler._wave_program.cache_clear()
     ops = FilterOps(backend="pallas", evict_rounds=16)
     state = jfilter.make_state(256, buffer_buckets=256)
     stash = kops.make_stash(16)
@@ -382,7 +386,6 @@ def test_lookup_waves_read_no_fill_snapshot(monkeypatch, adaptive):
     program, no eager ``stash_occupancy``) and fetches none at harvest;
     ``fill_reads`` and its registry counter count the mutating waves."""
     from repro.adaptive.state import make_adaptive_state
-    from repro.serving import scheduler
 
     calls = []
 

@@ -8,6 +8,10 @@ The contracts pinned here:
   * single-lane waves through ``FilterOpBatcher`` reproduce the
     sequential oracles (``PyStashFilter`` / ``PyAdaptiveFilter``) op for
     op AND state for state — the batcher adds pipelining, not semantics;
+  * a wave is one host->device copy and one program: its answers, the
+    planes, stash and fills are the ``FilterOps`` entries' own on
+    separate (hi, lo, valid) arrays; a write donates the table when
+    ``donate`` is set, and a lookup hands back no state;
   * scenario streams are byte-reproducible from one seed (the bench
     gate's comparability requirement, satellite of ISSUE 8);
   * admission coupling under a burst train: the hysteresis gate defers
@@ -16,13 +20,17 @@ The contracts pinned here:
   * the latency recorder's op-weighted percentiles are the numbers the
     bench rows claim they are.
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import filter as jfilter
+from repro.core import hashing
 from repro.core.filter_ops import FilterOps
 from repro.adaptive.state import make_adaptive_state
 from repro.kernels import ops as kops
+from repro.serving import scheduler
 from repro.serving.scheduler import FilterOpBatcher
 from repro.serving.slo import (LatencyRecorder, SloHarness, make_batcher,
                                run_scenario)
@@ -280,37 +288,88 @@ def test_double_buffer_auto_resolves_per_host(monkeypatch):
 # ------------------------------------------------- fill snapshot --------
 
 
-def _small_stack(family):
-    """A 16 x 4 filter with an 8-slot stash, static or adaptive."""
+def _small_stack(family, stash=True):
+    """A 16 x 4 filter, static or adaptive, with an 8-slot stash or none."""
     ops = FilterOps(fp_bits=12, backend="pallas", evict_rounds=8)
     state = (make_adaptive_state(16, 4) if family == "adaptive"
              else jfilter.make_state(16, 4))
-    return ops, state, kops.make_stash(8)
+    return ops, state, kops.make_stash(8) if stash else None
 
 
 def _device_fills(state, stash):
     """(table fill, stash fill) read straight from the device arrays."""
     capacity = int(state.n_buckets) * state.table.shape[1]
     return (int(state.count) / capacity,
-            int(kops.stash_occupancy(stash)) / stash.shape[1])
+            0.0 if stash is None
+            else int(kops.stash_occupancy(stash)) / stash.shape[1])
+
+
+def _entry_wave(ops, state, stash, kind, keys):
+    """One wave as the ``FilterOps`` entries run it on separate (hi, lo,
+    valid) arrays padded to ``WS`` -> (state, stash, answers)."""
+    padded = np.zeros(WS, np.uint64)
+    padded[:keys.size] = keys
+    hi, lo = (jnp.asarray(a) for a in hashing.key_to_u32_pair_np(padded))
+    valid = jnp.asarray(np.arange(WS) < keys.size)
+    if kind == "lookup":
+        if hasattr(state, "sels"):
+            res = ops.lookup_adaptive(state, hi, lo, stash=stash)
+        elif stash is None:
+            res = ops.lookup(state, hi, lo)
+        else:
+            res = ops.lookup_with_stash(state, stash, hi, lo)
+    elif kind == "report":
+        state, res, _resident = ops.report_false_positive(state, hi, lo,
+                                                          valid=valid)
+    elif hasattr(state, "sels"):
+        write = ops.insert_adaptive if kind == "insert" \
+            else ops.delete_adaptive
+        out = write(state, hi, lo, valid=valid, stash=stash)
+        state, res = out[0], out[-1]
+        stash = out[1] if stash is not None else None
+    elif stash is None:
+        write = ops.insert if kind == "insert" else ops.delete
+        state, res = write(state, hi, lo, valid=valid)
+    elif kind == "insert":
+        state, stash, res = ops.insert_spill(state, stash, hi, lo,
+                                             valid=valid)
+    else:
+        table, new_stash, res = ops.delete_table(
+            state.table, hi, lo, n_buckets=state.n_buckets, valid=valid,
+            stash=stash)
+        count = (state.count - jnp.sum(res, dtype=jnp.int32)
+                 + kops.stash_occupancy(stash)
+                 - kops.stash_occupancy(new_stash))
+        state, stash = state._replace(table=table, count=count), new_stash
+    return state, stash, np.asarray(res)[:keys.size]
 
 
 @pytest.mark.parametrize("family", ["static", "adaptive"])
 @pytest.mark.parametrize("double_buffer", [True, False])
-def test_fills_after_every_harvest_match_the_device(family, double_buffer):
+@pytest.mark.parametrize("with_stash", [True, False])
+def test_fills_after_every_harvest_match_the_device(family, double_buffer,
+                                                    with_stash, monkeypatch):
     """``fills()`` is the device's (count, stash occupancy) as of the last
     harvested wave, whether or not that wave read a snapshot: the batcher
-    is built over a pre-filled stash, its stream opens with lookups, and
-    mixes inserts, table deletes and (adaptive) reports."""
-    ops, state, stash = _small_stack(family)
+    is built over a pre-filled stash (or none), its stream opens with
+    lookups, and mixes inserts, table deletes and (adaptive) reports.
+    Every wave is one host->device copy (``stats.uploads``, and one
+    ``jax.device_put``), and its answers, the planes, stash and fills are
+    those of the same ``FilterOps`` entries called on separate (hi, lo,
+    valid) arrays."""
+    ops, state, stash = _small_stack(family, with_stash)
     rng = np.random.RandomState(5)
     fill = FilterOpBatcher(ops, state, stash=stash, wave_slots=WS,
                            double_buffer=False)
     live = rng.randint(1, 2 ** 62, size=WS, dtype=np.int64).astype(np.uint64)
     fill.submit("insert", live)
-    assert int(kops.stash_occupancy(fill.stash)) > 0, \
+    assert stash is None or int(kops.stash_occupancy(fill.stash)) > 0, \
         "the stream must start over a pre-filled stash"
 
+    puts = []
+    monkeypatch.setattr(scheduler.jax, "device_put",
+                        lambda x, *a, _put=jax.device_put, **k:
+                        puts.append(x) or _put(x, *a, **k))
     b = FilterOpBatcher(ops, fill.state, stash=fill.stash, wave_slots=WS,
                         double_buffer=double_buffer)
     assert b.fills() == _device_fills(b.state, b.stash)
@@ -318,13 +377,14 @@ def test_fills_after_every_harvest_match_the_device(family, double_buffer):
              "report", "delete", "lookup", "insert", "report", "lookup"]
     if family == "static":
         kinds = [k for k in kinds if k != "report"]
-    waves, after = [], []
+    waves, after, offered = [], [], []
     for kind in kinds:
         fresh = rng.randint(1, 2 ** 62, size=WS // 2,
                             dtype=np.int64).astype(np.uint64)
         keys = (live[rng.permutation(WS)[:WS // 2]] if kind == "delete"
                 else np.concatenate([live[:WS // 4], fresh[:WS // 4]])
                 if kind != "insert" else fresh)
+        offered.append(keys)
         waves.append(b.submit(kind, keys))
         after.append(_device_fills(b.state, b.stash))
         done = [i for i, w in enumerate(waves) if w.results is not None]
@@ -332,6 +392,67 @@ def test_fills_after_every_harvest_match_the_device(family, double_buffer):
             assert b.fills() == after[done[-1]], (kind, len(waves))
     b.flush()
     assert b.fills() == after[-1] == _device_fills(b.state, b.stash)
+    assert b.stats.uploads == len(kinds) == len(puts)
+    assert [p.shape for p in puts] == [
+        ((2 if kind == "lookup" else 3) * WS,) for kind in kinds]
+
+    ref_state, ref_stash = fill.state, fill.stash
+    for i, (kind, keys) in enumerate(zip(kinds, offered)):
+        ref_state, ref_stash, res = _entry_wave(ops, ref_state, ref_stash,
+                                                kind, keys)
+        np.testing.assert_array_equal(waves[i].results, res)
+        assert after[i] == _device_fills(ref_state, ref_stash), (kind, i)
+    for got, want in zip(b.state, ref_state):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (b.stash is None) == (ref_stash is None)
+    if with_stash:
+        np.testing.assert_array_equal(np.asarray(b.stash),
+                                      np.asarray(ref_stash))
+
+
+@pytest.mark.parametrize("double_buffer", [True, False])
+def test_write_waves_donate_and_lookup_waves_keep_the_count(double_buffer):
+    """With ``FilterOps(donate=True)`` an insert wave and a table delete
+    consume the pre-wave table (updated in place, not copied), while a
+    lookup wave hands back no state: ``state.count`` stays the same
+    object, so the wave reads no fill snapshot."""
+    ops = FilterOps(fp_bits=12, backend="pallas", evict_rounds=8,
+                    donate=True)
+    b = FilterOpBatcher(ops, jfilter.make_state(16, 4),
+                        stash=kops.make_stash(8), wave_slots=WS,
+                        double_buffer=double_buffer)
+    keys = np.arange(1, WS // 2 + 1, dtype=np.uint64)
+    for kind in ("insert", "delete"):
+        table = b.state.table
+        b.submit(kind, keys)
+        b.flush()
+        assert table.is_deleted(), kind
+    count, table, reads = b.state.count, b.state.table, b.stats.fill_reads
+    w = b.submit("lookup", keys)
+    b.flush()
+    assert b.state.count is count and b.state.table is table
+    assert b.stats.fill_reads == reads
+    assert not table.is_deleted() and w.results.shape == keys.shape
+
+
+@pytest.mark.parametrize("with_stash", [True, False])
+def test_lookup_wave_program_keeps_the_probe_name(with_stash):
+    """The lookup wave's program is named so that ``probe_roofline``'s
+    pattern finds its device time: the probe inlines into it, and a trace
+    shows only the outer program's name."""
+    import re
+
+    from bench.metrics.probe_roofline import PROGRAM
+    ops, state, stash = _small_stack("static", with_stash)
+    entry = scheduler._wave_entry("lookup", False, stash is not None)
+    program = scheduler._wave_program(ops, entry, getattr(FilterOps, entry),
+                                      False)
+    text = program.lower(
+        state._replace(count=None, n_buckets=None),
+        (state.count, state.n_buckets), stash,
+        jnp.zeros(2 * WS, jnp.uint32)).as_text()
+    module = re.search(r"module @(\S+)", text).group(1)
+    assert re.search(PROGRAM, module), module
 
 
 # ------------------------------------------------- recorder & reports ---
